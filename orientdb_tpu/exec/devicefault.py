@@ -13,8 +13,8 @@ the overlay poison machinery):
 
 1. **classify** — every exception crossing a device boundary becomes
    ``oom`` / ``transient`` / ``persistent`` (``device.fault.*``
-   counters; ``SimulatedCrash`` and the engines' own control-flow
-   exceptions pass through untouched);
+   counters; ``SimulatedCrash``, the engines' own control-flow
+   exceptions and trace-time program errors pass through untouched);
 2. **retry** — transients re-dispatch under the PR-3
    :class:`~orientdb_tpu.parallel.resilience.RetryPolicy` (bounded
    attempts + budget);
@@ -65,6 +65,10 @@ PERSISTENT = "persistent"
 #: parity conviction (exec/audit): the plan ran fine but served rows
 #: the shadow oracle disagrees with — wrong answers, not crashes
 PARITY = "parity"
+#: not a device fault at all: an error raised while TRACING or LOWERING
+#: the program (a bug in the program). The guard re-raises it as is —
+#: never retried, quarantined or served from the oracle.
+PROGRAM = "program"
 
 
 class DeviceFaultError(OSError):
@@ -137,13 +141,21 @@ _PERSISTENT_MARKERS = (
 )
 
 
+#: what JAX raises while tracing or lowering (``jax.errors.JAXTypeError``
+#: is a ``TypeError``); the runtime's own errors are ``RuntimeError``s
+_TRACE_ERRORS = (TypeError, ValueError, NotImplementedError)
+
+
 def classify(exc: BaseException) -> str:
-    """``oom`` / ``persistent`` / ``transient`` for an exception caught
-    at a device dispatch/fetch boundary. Callers only hand this
-    exceptions that crossed such a boundary — position, not type, is
-    what makes them device-side — so the default is ``transient``:
-    retry is the cheapest rung, and a persistent conviction also
-    arrives via retry exhaustion."""
+    """``oom`` / ``persistent`` / ``transient`` / ``program`` for an
+    exception caught at a device dispatch/fetch boundary. An exception
+    that carries XLA status text is classified by it; otherwise a
+    Python ``TypeError`` / ``ValueError`` / ``NotImplementedError`` was
+    raised while tracing or lowering and is a bug in the program
+    (``program``: the guard re-raises it untouched). Anything else that
+    crossed the boundary is device-side, and the default is
+    ``transient``: retry is the cheapest rung, and a persistent
+    conviction also arrives via retry exhaustion."""
     if isinstance(exc, DeviceFaultError):
         return exc.kind
     msg = f"{type(exc).__name__}: {exc}".lower()
@@ -151,6 +163,8 @@ def classify(exc: BaseException) -> str:
         return OOM
     if any(m in msg for m in _PERSISTENT_MARKERS):
         return PERSISTENT
+    if isinstance(exc, _TRACE_ERRORS):
+        return PROGRAM
     return TRANSIENT
 
 
@@ -281,7 +295,8 @@ class DeviceFaultDomain:
         """Run one device dispatch/fetch section under the escalation
         ladder. ``passthrough`` names the caller's control-flow
         exceptions (``ScheduleOverflow``); ``Uncompilable`` and
-        ``SimulatedCrash`` always pass through. Exhaustion raises
+        ``SimulatedCrash`` always pass through, and so does a trace-time
+        program error (``classify`` → ``program``). Exhaustion raises
         :class:`DeviceQuarantined` (an ``Uncompilable``) — zero
         unclassified device exceptions escape."""
         import time as _time
@@ -314,6 +329,8 @@ class DeviceFaultDomain:
                 # SimulatedCrash is a BaseException: it unwinds through
                 # here untouched, like a real SIGKILL would
                 kind = classify(e)
+                if kind == PROGRAM:
+                    raise
                 self._record_fault(kind, stage, e)
                 if kind == OOM and not relief_done:
                     # relief BEFORE the retry, once per guarded section

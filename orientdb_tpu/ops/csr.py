@@ -97,11 +97,11 @@ _CS_BLOCK = 256
 def mask_cumsum(mask: jnp.ndarray) -> jnp.ndarray:
     """Inclusive prefix sum of a boolean mask, MXU-shaped.
 
-    XLA's plain cumsum over 1M elements costs ~14 ms on TPU (log-depth
-    reduce-window passes); a [n/256, 256] reshape turns the intra-block
+    XLA's plain cumsum lowers to log-depth reduce-window passes on a
+    TPU; a [n/256, 256] reshape turns the intra-block
     scan into ONE triangular matmul on the systolic array (values ≤ 256
-    are exact in f32), leaving only a tiny 4k-element cumsum for the
-    block offsets — sub-millisecond at graph scale."""
+    are exact in f32), leaving only the block totals' own (recursively
+    blocked) prefix sum for the offsets."""
     n = mask.shape[0]
     B = _CS_BLOCK
     if n < 2 * B or n % B:
@@ -119,20 +119,26 @@ def _block_scan_f32(vals_f32: jnp.ndarray) -> jnp.ndarray:
     """[n/B, B] per-block inclusive scans as ONE triangular matmul on
     the systolic array. Exact while every block-local partial stays
     under 2^24 (callers arrange that); cross-block offsets are the
-    caller's job — f32 cannot carry graph-scale totals exactly."""
+    caller's job — f32 cannot carry graph-scale totals exactly.
+
+    ``Precision.HIGHEST`` is what makes "exact" true on a TPU: at the
+    default precision the MXU rounds f32 operands to bf16 (8 significant
+    bits), so any input above 256 — a 16-bit half in ``value_cumsum``,
+    or the second-level block totals of ``mask_cumsum`` past ~16 M
+    elements — came back wrong on the v5e. The CPU computes f32 dots in
+    f32 either way, which is why only a chip run could show it."""
     B = _CS_BLOCK
     rows = vals_f32.reshape(-1, B)
     tri = jnp.triu(jnp.ones((B, B), jnp.float32))
-    return jnp.dot(rows, tri)
+    return jnp.dot(rows, tri, precision=jax.lax.Precision.HIGHEST)
 
 
 def value_cumsum(vals: jnp.ndarray, force_blocked: bool = False) -> jnp.ndarray:
     """Inclusive prefix sum of int32/f32 VALUES, MXU-shaped like
     :func:`mask_cumsum` — the COUNT-pushdown weight chain runs this
     over the whole edge list (80M rows at SF100 shape), where XLA's
-    log-depth plain cumsum was the measured per-query floor (~14 ms
-    per 1M elements → seconds per pass; the r04 16.8 q/s two-hop
-    cliff).
+    log-depth plain cumsum costs about twice the blocked form (PERF.md,
+    PR 22).
 
     int32 stays EXACT on the f32 systolic array by scanning the low
     and high 16-bit halves separately: per-block partials are

@@ -37,13 +37,20 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from orientdb_tpu.parallel.shard_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from orientdb_tpu.ops import csr as K
 from orientdb_tpu.utils.config import config
 from orientdb_tpu.utils.metrics import metrics
 
+
+def _varying_zeros(shape, dtype, axes):
+    """Zeros typed as varying over the manual mesh ``axes``: the skip
+    branch of a per-shard ``lax.cond`` must carry the same varying axes
+    as its live branch, and fresh zeros inside ``shard_map`` are typed
+    unvarying."""
+    return jax.lax.pcast(jnp.zeros(shape, dtype), axes, to="varying")
 
 
 class ShardedEdgeArrays:
@@ -312,7 +319,7 @@ def _build_expand_gather(
             # frontier-sparse: a shard owning NO live sources skips its
             # gather/scatter entirely (the cond predicate varies per
             # shard; the branches carry no collective)
-            z = jnp.zeros(cap_total, jnp.int32)
+            z = _varying_zeros(cap_total, jnp.int32, ax)
             return z, z, z
 
         seg_row, seg_eid, seg_nbr = jax.lax.cond(
@@ -396,7 +403,7 @@ def _build_bitmap_hop(mesh: Mesh, ax: str):
             # slices see arbitrary sources, so per-shard frontier
             # locality does not exist here — the row-sharded BFS in
             # parallel/sharded.py owns that case).
-            return jnp.zeros(frontier_rep.shape, cdtype)
+            return _varying_zeros(frontier_rep.shape, cdtype, ax)
 
         contrib = jax.lax.cond(
             em.any() & frontier_rep.any(), hop, skip, jnp.int32(0)
@@ -453,7 +460,7 @@ def _build_weight_pass(mesh: Mesh, ax: str):
 
         def skip(_):
             # padding-only edge slice (E < S·W rounding): nothing to sum
-            return jnp.zeros(vb, w_rep.dtype)
+            return _varying_zeros(vb, w_rep.dtype, ax)
 
         part = jax.lax.cond((seg_l >= 0).any(), wpass, skip, jnp.int32(0))
         return jax.lax.psum(part, ax)

@@ -25,7 +25,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from orientdb_tpu.server.server import Server
+    from orientdb_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     srv = Server(
         admin_password=args.admin_password,
         http_port=args.http_port,

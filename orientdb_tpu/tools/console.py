@@ -708,6 +708,9 @@ class Console(cmd.Cmd):
 
 
 def main() -> None:  # pragma: no cover - interactive entry
+    from orientdb_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     Console().cmdloop()
 
 

@@ -147,6 +147,9 @@ def sweep(shard_counts, as_json: bool) -> int:
 
 
 if __name__ == "__main__":
+    from orientdb_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     argv = sys.argv[1:]
     if "--sweep" in argv:
         i = argv.index("--sweep")

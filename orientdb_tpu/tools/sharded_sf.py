@@ -83,6 +83,9 @@ def main(shards: int, n_persons: int) -> None:
 
 
 if __name__ == "__main__":
+    from orientdb_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main(
         int(sys.argv[1]) if len(sys.argv) > 1 else 8,
         int(sys.argv[2]) if len(sys.argv) > 2 else 1_000_000,

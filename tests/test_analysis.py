@@ -1192,7 +1192,7 @@ class TestJaxlintMutations:
 
     def test_shard_map_local_fn_is_a_region(self):
         src = (
-            "from orientdb_tpu.parallel.shard_compat import shard_map\n"
+            "from jax import shard_map\n"
             "from orientdb_tpu.utils.metrics import metrics\n"
             "def outer(mesh, data):\n"
             "    def local(x):\n"

@@ -658,8 +658,8 @@ class TestObservability:
 class TestHttpLaneRoute:
     def test_http_query_verb_rides_the_coalescer(self):
         """The HTTP GET query verb submits to the same lanes the binary
-        `query` op uses — zero HTTP sessions pay the lone-dispatch
-        tunnel anymore."""
+        `query` op uses — no HTTP session pays a lone dispatch
+        anymore."""
         import base64
         import json
         import urllib.request

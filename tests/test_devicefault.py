@@ -17,6 +17,7 @@ from orientdb_tpu.exec import devicefault
 from orientdb_tpu.exec.devicefault import (
     OOM,
     PERSISTENT,
+    PROGRAM,
     TRANSIENT,
     DeviceFaultError,
     DeviceOomError,
@@ -103,6 +104,25 @@ class TestClassification:
             DeviceFaultError("x", kind=TRANSIENT)
         ) == TRANSIENT
 
+    def test_trace_time_errors_are_program_bugs(self):
+        """What JAX raises while tracing or lowering is a bug in the
+        program, not a device fault — unless it carries XLA status
+        text, which classifies as before."""
+        import jax
+        import jax.numpy as jnp
+
+        with pytest.raises(TypeError) as ei:
+            jax.jit(
+                lambda x: jax.lax.cond(
+                    x > 0, lambda: jnp.int8(1), lambda: jnp.int32(1)
+                )
+            )(1)
+        assert classify(ei.value) == PROGRAM
+        assert classify(ValueError("incompatible shapes")) == PROGRAM
+        assert classify(NotImplementedError("no rule")) == PROGRAM
+        assert classify(ValueError("INVALID_ARGUMENT: x")) == PERSISTENT
+        assert classify(TypeError("RESOURCE_EXHAUSTED: hbm")) == OOM
+
     def test_new_points_in_catalog(self):
         assert {"tpu.dispatch", "tpu.transfer", "tpu.oom"} <= POINTS
 
@@ -122,6 +142,32 @@ class TestGuard:
         assert s["classified"].get("transient") == 1
         assert s["retries"] == 1
         assert s["quarantines_total"] == 0
+
+    def test_trace_time_typeerror_raises_to_caller(self):
+        """A TypeError raised while tracing inside a guarded dispatch
+        reaches the caller as itself: no retry, no fault record, no
+        quarantine, no DeviceQuarantined("... serving oracle")."""
+        import jax
+        import jax.numpy as jnp
+
+        calls = {"n": 0}
+
+        def fn():
+            calls["n"] += 1
+            return jax.jit(
+                lambda x: jax.lax.cond(
+                    x > 0, lambda: jnp.zeros(3), lambda: jnp.zeros(4)
+                )
+            )(1)
+
+        sql = "SELECT 1 FROM TraceBug"
+        with pytest.raises(TypeError) as ei:
+            domain.run(fn, sql=sql, stage="t")
+        assert not isinstance(ei.value, Uncompilable)
+        assert calls["n"] == 1
+        s = domain.snapshot()
+        assert s["classified"] == {} and s["retries"] == 0
+        assert s["quarantines_total"] == 0 and domain.admit(sql) is None
 
     def test_persistent_skips_retry_and_quarantines(self):
         calls = {"n": 0}
