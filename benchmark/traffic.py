@@ -1,0 +1,184 @@
+"""One general traffic generator, driven by a file under
+``benchmark/traffic/``.
+
+A mix is a closed loop of ``sessions`` clients over a list of statement
+shapes with whole-number weights. The weights are realised as ONE
+repeating block in a fixed interleaving, which every session walks from
+its own fixed offset: the order of shapes never depends on ``--seed``.
+With ``"walk": "pinned"`` a session does not walk: it stays at its
+offset's shape, one client with one statement of its own, so that with
+as many sessions as the block is long every shape has one session and
+every lane of the server one client.
+The seed draws the parameters only, and for a rooted shape it draws them
+from a stated band (quantiles of that graph's own distribution of the
+count that sets the answer's size), as LDBC's parameter curation does,
+so that one template's run time is a narrow distribution.
+
+A parameter of a shape is one of
+
+- ``{"const": 30}``
+- ``{"int": [lo, hi]}`` uniform whole numbers, both ends included;
+  with ``"lead": v`` the pool's first tuple carries ``v`` (the value of
+  the largest answer), see ``draw_pool``
+- ``{"root": "<measure>", "band": [q_lo, q_hi]}`` a person whose measure
+  lies between the two quantiles of the persons' measures; the measures
+  are the methods of ``Measures`` below.
+
+No (statement, parameters) pair is drawn twice while the domain lasts.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import zlib
+from typing import Dict, List
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+
+def load_json(kind: str, name: str, root: str = HERE) -> dict:
+    """``benchmark/<kind>/<name>.json``: configurations and mixes are
+    found by the name ``BENCHMARK.json`` gives them."""
+    path = os.path.join(root, kind, name + ".json")
+    with open(path) as f:
+        return json.load(f)
+
+
+def realise_block(weights: List[int]) -> List[int]:
+    """Shape indices of one block: ``weights[i]`` occurrences of ``i``,
+    spread evenly (smooth weighted round-robin, ties to the lower index).
+    A pure function of the weights."""
+    if not weights or any(int(w) != w or w < 1 for w in weights):
+        raise ValueError(f"weights must be whole numbers >= 1: {weights}")
+    total = sum(weights)
+    credit = [0] * len(weights)
+    block = []
+    for _ in range(total):
+        credit = [c + w for c, w in zip(credit, weights)]
+        i = max(range(len(weights)), key=lambda j: (credit[j], -j))
+        credit[i] -= total
+        block.append(i)
+    return block
+
+
+def session_offsets(sessions: int, block_len: int) -> List[int]:
+    """Where in the block each session starts: spread over the block, so
+    that at any moment the sessions stand at different shapes."""
+    return [(s * block_len) // sessions % block_len for s in range(sessions)]
+
+
+class Measures:
+    """Per-person counts a root may be curated by, from the reference's
+    arrays (never from the program's)."""
+
+    def __init__(self, ref) -> None:
+        self.ref = ref
+
+    def degree_both(self) -> np.ndarray:
+        return self.ref.degree_both()
+
+
+def band_members(values: np.ndarray, band) -> np.ndarray:
+    """Indices whose value lies within the band's two quantiles of
+    ``values`` (both ends included)."""
+    q_lo, q_hi = float(band[0]), float(band[1])
+    if not 0.0 <= q_lo <= q_hi <= 1.0:
+        raise ValueError(f"band must be 0 <= lo <= hi <= 1: {band}")
+    lo, hi = np.quantile(values, [q_lo, q_hi])
+    return np.flatnonzero((values >= lo) & (values <= hi))
+
+
+def draw_pool(shape: dict, ref, seed: int, want: int) -> Dict:
+    """Up to ``want`` distinct parameter tuples for one shape, as
+    ``{"names": [...], "rows": [[...], ...]}``. Seeded by ``seed`` and
+    the shape's name, so that adding a shape to a mix moves no other
+    shape's parameters.
+
+    The first tuple is the one warm-up records the shape's plan with, and
+    a plan keeps the buffer sizes of the answer it was recorded on
+    (``exec/tpu_engine.SizeSchedule``): it carries each parameter's
+    ``lead`` value and the band's largest root, the largest answer of the
+    pool, so that every seed records the same sizes and no later request
+    outgrows them."""
+    rng = np.random.default_rng(
+        [int(seed) & 0xFFFFFFFF, int(seed) >> 32, zlib.crc32(shape["name"].encode())]
+    )
+    measures = Measures(ref)
+    names = list(shape["params"])
+    cols = []
+    for name in names:
+        spec = shape["params"][name]
+        if "const" in spec:
+            cols.append(np.full(want, int(spec["const"]), np.int64))
+        elif "int" in spec:
+            lo, hi = spec["int"]
+            col = rng.integers(int(lo), int(hi) + 1, want)
+            if "lead" in spec:
+                col[0] = int(spec["lead"])
+            cols.append(col)
+        elif "root" in spec:
+            measure = getattr(measures, spec["root"], None)
+            if measure is None or spec["root"].startswith("_"):
+                raise KeyError(f"no root measure {spec['root']!r}")
+            values = measure()
+            members = band_members(values, spec["band"])
+            if members.size == 0:
+                raise ValueError(f"{shape['name']}: empty band {spec}")
+            picks = rng.permutation(members)
+            # the band's largest member leads the pool
+            top = int(np.argmax(values[picks]))
+            picks[[0, top]] = picks[[top, 0]]
+            reps = -(-want // picks.size)
+            cols.append(np.tile(picks, reps)[:want])
+        else:
+            raise ValueError(f"{shape['name']}.{name}: unknown draw {spec}")
+    rows = np.stack(cols, axis=1) if cols else np.zeros((want, 0), np.int64)
+    # keep the first occurrence of every tuple, in drawn order
+    _, first = np.unique(rows, axis=0, return_index=True)
+    rows = rows[np.sort(first)]
+    return {"names": names, "rows": rows.tolist()}
+
+
+def build_plan(mix: dict, ref, seed: int, pool_size: int) -> dict:
+    """Everything the load generator needs for one cell and seed: the
+    statements, the block, each session's offset and each shape's pool
+    of parameters."""
+    shapes = mix["shapes"]
+    if mix.get("loop", "closed") != "closed":
+        raise ValueError("only closed loops are generated so far")
+    walk = mix.get("walk", "block")
+    if walk not in ("block", "pinned"):
+        raise ValueError(f"walk must be 'block' or 'pinned': {walk!r}")
+    block = realise_block([int(s["weight"]) for s in shapes])
+    sessions = int(mix["sessions"])
+    offsets = session_offsets(sessions, len(block))
+    everyone = list(range(sessions))
+    if walk == "pinned" and {block[o] for o in offsets} != set(block):
+        raise ValueError(f"mix {mix.get('name')}: a pinned shape has no session")
+    return {
+        "sessions": sessions,
+        "think_ms": float(mix.get("think_ms", 0)),
+        "block": block,
+        "offsets": offsets,
+        "stride": 1 if walk == "block" else 0,
+        # the sessions that can have shape i in flight at one moment: the
+        # largest batch its lane can meet
+        "shape_sessions": [
+            everyone if walk == "block" else [s for s in everyone if block[offsets[s]] == i]
+            for i in range(len(shapes))
+        ],
+        "shapes": [
+            {
+                "name": s["name"],
+                "sql": s["sql"],
+                "columns": s["columns"],
+                "ordered": bool(s.get("ordered", False)),
+                "reference": s["reference"],
+                "pool": draw_pool(s, ref, seed, pool_size),
+            }
+            for s in shapes
+        ],
+    }
