@@ -1525,6 +1525,7 @@ class TpuMatchSolver:
                 w = self._pushdown_weight_step(step, w, univ, mg, vb, dtype)
         return w
 
+    @jax.named_scope("count.weight_pass")
     def _pushdown_weight_step(self, step, w, univ, mg, vb, dtype):
         item = step.edge.item
         direction = item.direction
@@ -2831,6 +2832,7 @@ class _CompiledTraverse(_AotWarmup):
         # (lazy class-id/edge uploads) while jit flattens the pytree here
         return self.jitted(self._arg_subset())
 
+    @jax.named_scope("traverse.replay")
     def _replay(self, arrays):
         dg = self.solver.dg
         saved = dg.arrays
@@ -2992,11 +2994,18 @@ class _CompiledPlan(_AotWarmup):
         self.tier_footprint = frozenset(solver.tier_touched)
         self.jitted = jax.jit(self._replay)
 
+    @jax.named_scope("match.core")
     def _replay_core(self, arrays, dyn):
         """Shared replay body: run the recorded solve and front-pack the
         result columns. Returns ``(count_dev, overflow, data)`` where
         ``data`` is the [C, width] int32 column stack (None for
-        count-only / column-less plans)."""
+        count-only / column-less plans).
+
+        The traced bodies carry name scopes (``match.replay`` or
+        ``match.replay_group``, then ``match.core``, then the kernel's
+        own ``csr.<name>``, ``ops/csr``): an operation's HLO ``op_name``
+        says which plan entry and which kernel it came from. Trace time
+        only."""
         # swap the tracer pytree into the device graph for the trace so the
         # graph buffers become jit ARGUMENTS (shared across every cached
         # plan) rather than per-executable HLO constants; same for the
@@ -3046,6 +3055,7 @@ class _CompiledPlan(_AotWarmup):
             (jnp.max(masked) < 32767) & (jnp.min(masked) > -32768)
         ).astype(jnp.int32)
 
+    @jax.named_scope("match.replay_group")
     def _replay_group(self, arrays, dyn):
         """Group-mode replay for row-returning plans: ``(meta, data)``
         with the FULL int32 column stack and no page ladder — the group
@@ -3151,6 +3161,7 @@ class _CompiledPlan(_AotWarmup):
             return best[1](data_dev)
         return data_dev  # nothing compiled yet: ship the raw stack once
 
+    @jax.named_scope("match.replay")
     def _replay(self, arrays, dyn):
         count_dev, overflow, data = self._replay_core(arrays, dyn)
         if data is None:
@@ -4521,7 +4532,14 @@ def _finish_pending(db, items, pending, out, fresh) -> None:
         # page drain is the transfer tail that didn't hide behind it
         metrics.observe("tpu.device_s", t1 - t0)
         metrics.observe("tpu.transfer_s", t2 - t1)
-        metrics.incr("tpu.bytes_fetched", nbytes)
+        # the part of the caller's turn (a lane worker's lane.finish
+        # span) in which the host only waits for the device
+        metrics.incr_many(
+            {
+                "tpu.fetch_wait_us": round((t2 - t0) * 1e6),
+                "tpu.bytes_fetched": nbytes,
+            }
+        )
         # per-fingerprint attribution (obs/stats): a no-op without an
         # active accumulator (the query_batch front door deliberately
         # skips per-item device fiction), but the coalesce lane wraps

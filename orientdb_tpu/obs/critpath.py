@@ -17,6 +17,8 @@ submitting sessions).
 Aggregation (all at :func:`commit`, never mid-request):
 
 - a bounded ring of recent decompositions (``critpath_capacity``);
+- process counters ``critpath.<segment>_us`` and ``critpath.requests``
+  (``utils/metrics``), so a window's decomposition is a counter delta;
 - per-fingerprint cumulative segment columns riding the PR-4 stats
   table (:meth:`obs.stats.QueryStats.record_segments`);
 - per-``SloClass`` cumulative breakdowns with a dominant-bottleneck
@@ -52,6 +54,7 @@ from collections import OrderedDict, deque
 from typing import Dict, Iterable, List, Optional
 
 from orientdb_tpu.utils.config import config
+from orientdb_tpu.utils.metrics import metrics
 
 #: segment name -> what it measures. The decomposition vocabulary in
 #: one place: ``critpathlint`` cross-checks every literal stamp site
@@ -70,8 +73,9 @@ SEGMENT_CATALOG: Dict[str, str] = {
     "of the dynamic args; a ParamRing miss)",
     "ring_hit": "device-resident ParamRing slot match — parameters "
     "reused in place, ~zero host bytes shipped",
-    "device_compute": "on-device execution (the dispatch's device "
-    "sync share from the profiled fetch waves)",
+    "device_compute": "the host's wait for the device's result (the "
+    "dispatch's sync share of the profiled fetch waves): device "
+    "execution plus whatever ran on the device ahead of it",
     "host_compute": "host-side execution: the oracle interpreter, "
     "plus any request wall time no other segment claimed",
     "result_transfer": "device->host result fetch (the profiled "
@@ -419,6 +423,15 @@ class CritPathPlane:
             # begin_request — record_segments must not thin it again)
             if not cp.stats_recorded:
                 stats.record_segments(cp.sql, cp.segs)
+        # the same numbers as window counters (whole microseconds): a
+        # reader of utils.metrics sees the decomposition of any window
+        # by difference, with no handle on this plane
+        fold = {
+            f"critpath.{k}_us": round(v * 1e6)
+            for k, v in cp.segs.items()
+        }
+        fold["critpath.requests"] = 1
+        metrics.incr_many(fold)
         cap = self._cap()
         with self._lock:
             self._committed += 1

@@ -248,13 +248,26 @@ def unregister_gauge_provider(fn: Callable[[], None]) -> None:
 
 
 def run_gauge_providers() -> None:
+    """Run every provider, timing each into the counter
+    ``obs.provider_us.<its __name__>``: providers run inside every
+    scrape and every watchdog tick, on the serving process's
+    interpreter, and one that grows is found by name."""
+    from orientdb_tpu.utils.metrics import metrics
+
     with _providers_lock:
         fns = list(_providers)
+    spent: Dict[str, int] = {}
     for fn in fns:
+        t0 = time.perf_counter()
         try:
             fn()
         except Exception:
             pass
+        key = f"obs.provider_us.{getattr(fn, '__name__', 'provider')}"
+        spent[key] = spent.get(key, 0) + round(
+            (time.perf_counter() - t0) * 1e6
+        )
+    metrics.incr_many(spent)
 
 
 def _rss_bytes() -> Optional[int]:
@@ -361,6 +374,8 @@ def database_telemetry(dbs_fn: Callable[[], List]) -> Callable[[], None]:
         metrics.gauge("snapshot.edge_column_bytes", ecols)
         metrics.gauge("wal.segment_bytes", wal)
 
+    # the name run_gauge_providers times it under
+    provider.__name__ = "database_telemetry"
     return provider
 
 

@@ -57,6 +57,12 @@ SPAN_CATALOG: Dict[str, str] = {
     "its fingerprint lane, enqueue through result (submitter side)",
     "coalesce.dispatch": "one lane micro-batch executed on the lane "
     "worker (continues the first submitter's trace; lane/batch attrs)",
+    "lane.stage": "the lane worker's first half of a turn, on its own "
+    "thread: plan pick, dynamic args, ring slot or device_put, launch "
+    "(server/coalesce; n = riders; continues the first rider's trace)",
+    "lane.finish": "the lane worker's second half of a turn: the wait "
+    "for the device (counter tpu.fetch_wait_us), result fetch, "
+    "materialize, to_dicts, deliver; parent of coalesce.dispatch",
     "snapshot.delta.apply": "one CDC delta batch applied device-side "
     "to a maintained snapshot (storage/deltas: packed scatter "
     "segments, no re-upload)",

@@ -23,9 +23,11 @@ numbers the counters cannot express:
   query costs one comparison per hook, and the tier-1 overhead guard
   pins the whole plane under 1.35x.
 - **overlap accounting** (:meth:`FlightRecorder.overlap`) — the
-  derived metrics: *device-idle fraction* (1 − merged device-busy time
-  over the window span — how much of the wall the device sat idle
-  between dispatches), *transfer-hidden fraction* (bytes whose copy
+  derived metrics: *device-idle fraction* (1 − merged "device" time
+  over the window span; a record's "device" interval is the HOST's
+  wait for the result (``tpu.device_s``), so this is the share of the
+  wall in which no host thread waited on the device, not a device
+  clock's reading), *transfer-hidden fraction* (bytes whose copy
   interval overlapped device compute vs serialized after it — the
   number that proves or refutes the PR-13 prefetch and PR-12 double
   buffer), *lane queue/window vs service decomposition*, and *ring
@@ -52,6 +54,7 @@ from __future__ import annotations
 import itertools
 import threading
 import time
+from bisect import bisect_right
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
@@ -69,7 +72,9 @@ EVENTS = (
     "prefetch_start",   # speculative result-page copy started
     "kernel_build",     # mesh shard_map kernel built (sharded path)
     "device_dispatch",  # replay enqueued on device
-    "compute_done",     # device sync returned
+    "compute_done",     # the host's wait for the result returned (no
+                        # device clock: the interval it closes is the
+                        # host's wait, not the kernels' time)
     "transfer_start",   # blocking device→host drain began
     "transfer_done",    # bytes on host
     "result_delivered", # record committed (rows marshalled)
@@ -123,7 +128,10 @@ class DispatchRecord:
         self.t_done: Optional[float] = None
         #: [(event name, monotonic ts)]
         self.events: List[Tuple[str, float]] = []
-        #: device-busy intervals [(t_start, t_end)]
+        #: "device" intervals [(t_start, t_end)]: the HOST's wait for
+        #: the dispatch's result, fetch wave by fetch wave — device
+        #: execution plus whatever was queued on the device ahead of
+        #: it. Device time proper is a profiler trace's (PERF.md)
         self.device: List[Tuple[float, float]] = []
         #: transfer intervals [(t_start, t_end, nbytes, kind)] — kind
         #: "fetch" (blocking drain) or "prefetch" (copy started at
@@ -245,16 +253,28 @@ def _merge_intervals(
 
 
 def _overlap_s(
-    a0: float, a1: float, merged: List[Tuple[float, float]]
+    a0: float,
+    a1: float,
+    merged: List[Tuple[float, float]],
+    ends: List[float],
 ) -> float:
-    """Seconds of ``[a0, a1]`` covered by the merged interval union."""
+    """Seconds of ``[a0, a1]`` covered by the merged interval union.
+    ``ends`` are the union's interval ends (ascending, as the disjoint
+    sorted intervals are): the walk starts by bisection at the first
+    interval that reaches past ``a0`` — the ones before it cover
+    nothing of ``[a0, a1]`` — so a pass over a full ring is records x
+    log intervals, not records x intervals: it runs inside every
+    watchdog tick, on the serving process's interpreter."""
     total = 0.0
-    for b0, b1 in merged:
+    k, n = bisect_right(ends, a0), len(merged)
+    while k < n:
+        b0, b1 = merged[k]
         if b0 >= a1:
             break
         lo, hi = max(a0, b0), min(a1, b1)
         if hi > lo:
             total += hi - lo
+        k += 1
     return total
 
 
@@ -394,11 +414,13 @@ class FlightRecorder:
         busy = _merge_intervals(
             [iv for r in recs for iv in r.device]
         )
+        busy_ends = [b for _a, b in busy]
         busy_s = sum(b - a for a, b in busy)
         out["span_s"] = round(span_s, 6)
         out["device_busy_s"] = round(busy_s, 6)
         # device-idle fraction BETWEEN dispatches: of the window span,
-        # how much had no device work in flight at all
+        # how much had no host thread waiting on a result — an upper
+        # bound on how idle the device was, from the host's side
         out["device_idle_fraction"] = round(
             max(0.0, 1.0 - busy_s / span_s), 6
         )
@@ -414,7 +436,8 @@ class FlightRecorder:
                 if kind == "prefetch":
                     pf_bytes += nb
                 if b > a:
-                    h_bytes += int(nb * _overlap_s(a, b, busy) / (b - a))
+                    ov = _overlap_s(a, b, busy, busy_ends)
+                    h_bytes += int(nb * ov / (b - a))
                 elif kind == "prefetch":
                     h_bytes += nb
         out["transfer"] = {
@@ -504,7 +527,8 @@ class FlightRecorder:
                 for a, b, nb, kind in r.transfers:
                     tb += nb
                     if b > a:
-                        hb += int(nb * _overlap_s(a, b, busy) / (b - a))
+                        ov = _overlap_s(a, b, busy, busy_ends)
+                        hb += int(nb * ov / (b - a))
                     elif kind == "prefetch":
                         hb += nb
             fps[fid] = {

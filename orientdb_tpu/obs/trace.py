@@ -13,6 +13,15 @@ spans land in a process-wide bounded ring (:data:`tracer`), cheap
 enough to leave on permanently. PROFILE and tests read the ring back
 by trace id; nothing is ever written to disk here.
 
+Two things outlive the ring. Every finished span folds into the
+metrics registry as ``span.<name>.us`` (whole microseconds) and
+``span.<name>.n``, so a window's spans are counter deltas however
+many requests it held (the ring keeps 4 096). And where ``jax`` is
+already imported, a span is also a ``jax.profiler.TraceAnnotation``:
+in a profiler trace it lies on its own thread's host line, on the
+device operations' clock. A process that never imported JAX (the
+remote client) never does on a span's account.
+
 Usage::
 
     with span("tx.commit", creates=3) as sp:
@@ -25,6 +34,7 @@ Usage::
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 import uuid
@@ -32,6 +42,7 @@ from collections import deque
 from typing import Dict, List, Optional
 
 from orientdb_tpu.utils.config import config
+from orientdb_tpu.utils.metrics import metrics
 
 _ids = itertools.count(1)
 #: process-unique id prefix: trace/span ids cross process boundaries
@@ -40,6 +51,24 @@ _ids = itertools.count(1)
 #: counters must never mint the same id
 _PROC = uuid.uuid4().hex[:8]
 _local = threading.local()
+
+
+#: ``jax.profiler.TraceAnnotation`` once JAX is loaded, else None
+_annotation = None
+
+
+def _find_annotation():
+    """Look for the profiler's annotation class without importing JAX:
+    the client and the load generator never load it and must stay so.
+    ``getattr`` twice, because another thread may be half way through
+    ``import jax`` (the watchdog's first tick is, in a server whose
+    first query has not run yet)."""
+    global _annotation
+    jax = sys.modules.get("jax")
+    _annotation = getattr(
+        getattr(jax, "profiler", None), "TraceAnnotation", None
+    )
+    return _annotation
 
 
 def _stack() -> list:
@@ -81,6 +110,7 @@ class span:
         "duration_us",
         "error",
         "_t0",
+        "_ann",
     )
 
     def __init__(self, name: str, **attrs) -> None:
@@ -93,6 +123,7 @@ class span:
         self.duration_us: Optional[float] = None
         self.error: Optional[str] = None
         self._t0 = 0.0
+        self._ann = None
 
     def set(self, key: str, value) -> None:
         self.attrs[key] = value
@@ -107,14 +138,21 @@ class span:
             self.trace_id = f"t{_PROC}{next(_ids):08x}"
         self.span_id = f"s{_PROC}{next(_ids):08x}"
         self.start_ts = time.time()
-        self._t0 = time.perf_counter()
         st.append(self)
+        annotation = _annotation or _find_annotation()
+        if annotation is not None:
+            self._ann = annotation(self.name)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, _tb):
         self.duration_us = round(
             (time.perf_counter() - self._t0) * 1e6, 1
         )
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, _tb)
+            self._ann = None
         if exc_type is not None:
             self.error = f"{exc_type.__name__}: {exc}"
         st = _stack()
@@ -145,12 +183,21 @@ class span:
         return out
 
 
+#: distinct span names that get counters of their own; any further
+#: name shares ``span._other``. The catalog holds ~50, but a name can
+#: come off the wire (``binary.<op>`` is the client's word), and the
+#: registry must not grow by what a client sends
+_FOLD_NAMES_MAX = 256
+
+
 class Tracer:
     """Process-wide bounded ring of finished spans (thread-safe)."""
 
     def __init__(self, capacity: int) -> None:
         self._lock = threading.Lock()
         self._spans: deque = deque(maxlen=max(capacity, 16))
+        #: span names with counters of their own (the fold, below)
+        self._folded: set = set()
         #: finished-span listeners (obs/profile's aggregator); called
         #: OUTSIDE the ring lock, on the finishing span's own thread
         self._listeners: list = []
@@ -166,8 +213,20 @@ class Tracer:
             pass
 
     def record(self, sp: span) -> None:
+        name = sp.name
         with self._lock:
             self._spans.append(sp)
+            if name not in self._folded:
+                if len(self._folded) < _FOLD_NAMES_MAX:
+                    self._folded.add(name)
+                else:
+                    name = "_other"
+        metrics.incr_many(
+            {
+                f"span.{name}.us": round(sp.duration_us or 0.0),
+                f"span.{name}.n": 1,
+            }
+        )
         for fn in self._listeners:
             try:
                 fn(sp)

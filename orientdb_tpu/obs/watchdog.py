@@ -10,8 +10,11 @@ databases and cluster. Evaluation happens ONLY here (and in explicit
 for it; the PR-4-style overhead guard in ``tests/test_alerts.py``
 asserts that.
 
-Each tick runs under a ``watchdog.tick`` span, so the watchdog's own
-cost shows up in the profile plane like any other stage.
+Each tick's rule evaluation runs under a ``watchdog.tick`` span (the
+scrub before it under ``scrub.sweep``), so the watchdog's own cost
+shows up in the profile plane like any other stage and, through the
+span fold (``obs/trace``), as the counters ``span.watchdog.tick.us``
+and ``span.scrub.sweep.us`` beside ``watchdog.ticks``.
 """
 
 from __future__ import annotations
@@ -23,6 +26,7 @@ from orientdb_tpu.obs.alerts import engine
 from orientdb_tpu.obs.trace import span
 from orientdb_tpu.utils.config import config
 from orientdb_tpu.utils.logging import get_logger
+from orientdb_tpu.utils.metrics import metrics
 
 log = get_logger("watchdog")
 
@@ -90,6 +94,7 @@ class HealthWatchdog:
             from orientdb_tpu.storage.scrub import scrubber
 
             scrubber.sweep_all(dbs)
+        metrics.incr("watchdog.ticks")
         with span("watchdog.tick") as sp:
             out = engine.evaluate(dbs=dbs, cluster=cluster)
             sp.set("fired", out["fired"])

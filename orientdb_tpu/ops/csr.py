@@ -11,6 +11,13 @@ Shape discipline (XLA wants static shapes): every kernel takes sizes that
 are **bucketed to powers of two** (`bucket()`), padding rows carry src=-1
 and are masked out, so the jit cache holds O(log n) specializations per
 kernel instead of one per distinct frontier size.
+
+Every kernel traces under ``jax.named_scope("csr.<its name>")`` (inside
+its ``jit``, so an eager call pays nothing): the operations it becomes
+carry ``.../csr.gather_expand/...`` in their HLO ``op_name`` metadata,
+under the plan's own scope (``match.replay``, ``exec/tpu_engine``), and
+a profiler trace can say which kernel a fusion came from. Trace time
+only; the compiled program is the same.
 """
 
 from __future__ import annotations
@@ -36,6 +43,7 @@ def bucket(n: int, minimum: int = 0) -> int:
 
 
 @jax.jit
+@jax.named_scope("csr.degree_counts")
 def degree_counts(indptr: jnp.ndarray, srcs: jnp.ndarray) -> jnp.ndarray:
     """Per-source neighbor counts; padding (src=-1) counts 0."""
     valid = srcs >= 0
@@ -44,6 +52,7 @@ def degree_counts(indptr: jnp.ndarray, srcs: jnp.ndarray) -> jnp.ndarray:
 
 
 @jax.jit
+@jax.named_scope("csr.exclusive_cumsum")
 def exclusive_cumsum(counts: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate(
         [jnp.zeros(1, counts.dtype), value_cumsum(counts)[:-1]]
@@ -51,6 +60,7 @@ def exclusive_cumsum(counts: jnp.ndarray) -> jnp.ndarray:
 
 
 @partial(jax.jit, static_argnames=("out_size",))
+@jax.named_scope("csr.gather_expand")
 def gather_expand(
     indptr: jnp.ndarray,
     neighbors: jnp.ndarray,
@@ -94,6 +104,7 @@ def gather_expand(
 _CS_BLOCK = 256
 
 
+@jax.named_scope("csr.mask_cumsum")
 def mask_cumsum(mask: jnp.ndarray) -> jnp.ndarray:
     """Inclusive prefix sum of a boolean mask, MXU-shaped.
 
@@ -115,6 +126,7 @@ def mask_cumsum(mask: jnp.ndarray) -> jnp.ndarray:
     return (row_cs + offs[:, None]).reshape(-1)
 
 
+@jax.named_scope("csr.block_scan_f32")
 def _block_scan_f32(vals_f32: jnp.ndarray) -> jnp.ndarray:
     """[n/B, B] per-block inclusive scans as ONE triangular matmul on
     the systolic array. Exact while every block-local partial stays
@@ -133,6 +145,7 @@ def _block_scan_f32(vals_f32: jnp.ndarray) -> jnp.ndarray:
     return jnp.dot(rows, tri, precision=jax.lax.Precision.HIGHEST)
 
 
+@jax.named_scope("csr.value_cumsum")
 def value_cumsum(vals: jnp.ndarray, force_blocked: bool = False) -> jnp.ndarray:
     """Inclusive prefix sum of int32/f32 VALUES, MXU-shaped like
     :func:`mask_cumsum` — the COUNT-pushdown weight chain runs this
@@ -188,6 +201,7 @@ def value_cumsum(vals: jnp.ndarray, force_blocked: bool = False) -> jnp.ndarray:
 
 
 @partial(jax.jit, static_argnames=("out_size",))
+@jax.named_scope("csr.compact_indices")
 def compact_indices(mask: jnp.ndarray, out_size: int) -> jnp.ndarray:
     """Indices of True entries (ascending), -1-padded to the static
     `out_size`.
@@ -214,6 +228,7 @@ def compact_indices(mask: jnp.ndarray, out_size: int) -> jnp.ndarray:
 
 
 @jax.jit
+@jax.named_scope("csr.take_pad")
 def take_pad(values: jnp.ndarray, idx: jnp.ndarray, fill) -> jnp.ndarray:
     """`values[idx]` where idx ≥ 0, else `fill` (padding-safe gather)."""
     n = values.shape[0]
@@ -225,11 +240,13 @@ def take_pad(values: jnp.ndarray, idx: jnp.ndarray, fill) -> jnp.ndarray:
 
 
 @jax.jit
+@jax.named_scope("csr.mask_count")
 def mask_count(mask: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(mask.astype(jnp.int32))
 
 
 @partial(jax.jit, static_argnames=("out_size",))
+@jax.named_scope("csr.indptr_segment_sum")
 def indptr_segment_sum(
     vals: jnp.ndarray, indptr: jnp.ndarray, out_size: int
 ) -> jnp.ndarray:
@@ -254,6 +271,7 @@ def indptr_segment_sum(
 
 
 @partial(jax.jit, static_argnames=("vb",))
+@jax.named_scope("csr.rows_to_bitmap")
 def rows_to_bitmap(rows: jnp.ndarray, vb: int) -> jnp.ndarray:
     """[C] vertex ids (-1 = none) → [C, vb] one-hot frontier bitmap."""
     C = rows.shape[0]
@@ -263,6 +281,7 @@ def rows_to_bitmap(rows: jnp.ndarray, vb: int) -> jnp.ndarray:
 
 
 @jax.jit
+@jax.named_scope("csr.bitmap_hop")
 def bitmap_hop(
     act_idx: jnp.ndarray,
     emit_idx: jnp.ndarray,
@@ -286,6 +305,7 @@ def bitmap_hop(
 
 
 @partial(jax.jit, static_argnames=("num_segments",))
+@jax.named_scope("csr.rows_with_matches")
 def rows_with_matches(rows: jnp.ndarray, mask: jnp.ndarray, num_segments: int):
     """Per-source-row match counts (OPTIONAL-arm left-join bookkeeping):
     scatter-add 1 for every surviving expansion into its origin row."""

@@ -36,7 +36,10 @@ clients no longer have to:
   BEFORE collecting batch N's results, so batch formation and upload
   overlap the device execution in front of them. While a batch's fetch
   blocks, new arrivals queue behind it and drain as the next batch —
-  continuous batching, no idle device between drains.
+  continuous batching, no idle device between drains. The worker's
+  two halves of a turn are spans on its own thread: ``lane.stage``
+  (plan pick, dynamic args, ring or ``device_put``, launch) and
+  ``lane.finish`` (fetch, materialize, ``to_dicts``, deliver).
 
 Per-item isolation: statements that cannot ride a batch
 (non-idempotent, EXPLAIN, parse errors, active tx) execute directly on
@@ -103,6 +106,12 @@ class _Item:
         #: lane window formed pre-write cannot serve post-write queries
         #: stale results)
         self.epoch: int = 0
+
+
+def _first_ctx(batch: List[_Item]) -> Optional[Dict]:
+    """The trace context the lane worker's spans continue: the first
+    rider's that has one."""
+    return next((i.ctx for i in batch if i.ctx), None)
 
 
 class _Lane:
@@ -243,9 +252,17 @@ class _Lane:
                 # dispatch N+1 BEFORE collecting N (double buffering):
                 # the new batch's params stage into the ring's other
                 # slot and its Execute queues behind N's on device
-                handle = self._dispatch(batch)
+                with continue_trace(
+                    "lane.stage", _first_ctx(batch), n=len(batch)
+                ):
+                    handle = self._dispatch(batch)
             if inflight is not None:
-                self._finish(*inflight)
+                with continue_trace(
+                    "lane.finish",
+                    _first_ctx(inflight[0]),
+                    n=len(inflight[0]),
+                ):
+                    self._finish(*inflight)
                 inflight = None
             if batch:
                 if handle is not None:
@@ -343,7 +360,7 @@ class _Lane:
         """Collect a double-buffered dispatch: fetch, marshal, deliver.
         The span continues the FIRST submitter's trace — the dispatch
         is theirs; co-riders join via their own coalesce.lane spans."""
-        ctx = next((i.ctx for i in batch if i.ctx), None)
+        ctx = _first_ctx(batch)
         try:
             waits = [max(0.0, t0 - i.t_enq) for i in batch]
             with continue_trace(
@@ -381,7 +398,7 @@ class _Lane:
         import orientdb_tpu.obs.stats as S
         from orientdb_tpu.exec.engine import execute_query_batch
 
-        ctx = next((i.ctx for i in batch if i.ctx), None)
+        ctx = _first_ctx(batch)
         n = max(len(batch), 1)
         try:
             # worker-side harvest record: execute_query_batch's front

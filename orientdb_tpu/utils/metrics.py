@@ -6,7 +6,8 @@ duration stats, exported over the HTTP server's ``/metrics`` endpoint
 (the JMX/`/profiler` analog) and readable in-process for tests.
 
 Two primitive kinds, both thread-safe:
-- counters   — ``incr("query.tpu")``
+- counters   — ``incr("query.tpu")``; ``incr_many({...})`` moves
+  several under one lock
 - durations  — ``observe("query.tpu.dispatch", seconds)`` keeping
   count/total/max so rates and tails are recoverable.
 """
@@ -14,7 +15,7 @@ Two primitive kinds, both thread-safe:
 from __future__ import annotations
 
 import threading
-from typing import Dict
+from typing import Dict, Mapping
 
 
 class MetricsRegistry:
@@ -27,6 +28,14 @@ class MetricsRegistry:
     def incr(self, name: str, n: int = 1) -> None:
         with self._lock:
             self._counters[name] = self._counters.get(name, 0) + n
+
+    def incr_many(self, deltas: Mapping[str, int]) -> None:
+        """Several counters under one lock: what a fold site (a span
+        exit, a critpath commit) pays per request."""
+        with self._lock:
+            counters = self._counters
+            for name, n in deltas.items():
+                counters[name] = counters.get(name, 0) + n
 
     def gauge(self, name: str, value: float) -> None:
         """Set an instantaneous value (e.g. per-device HBM bytes)."""

@@ -562,22 +562,27 @@ class TestWatchdogLifecycleAndOverhead:
             srv.shutdown()
 
     def test_watchdog_overhead_off_the_query_hot_path(self, monkeypatch):
-        """The PR-4-style guard: a 1k-query loop with a fast-ticking
-        watchdog stays close to a watchdog-less run — rule evaluation
-        rides the tick thread, never the query path. Best-of-3 per
-        config, generous threshold: this asserts the mechanism, not
-        the microbenchmark."""
+        """The PR-4-style guard: a query loop beside a fast-ticking
+        watchdog. Rule evaluation rides the tick thread, never the
+        query path, and what it costs is read where it is spent: the
+        program's own counters (``watchdog.ticks``, and the span fold's
+        ``span.watchdog.tick.us``) over the loop's wall. Best of 3,
+        generous threshold: this asserts the mechanism, not the
+        microbenchmark (two wall-clock loops side by side are a coin
+        toss under six xdist workers on one host)."""
+        import threading
+
         from orientdb_tpu.models.database import Database
         from orientdb_tpu.models.schema import PropertyType
         from orientdb_tpu.obs.stats import stats as _qstats
+        from orientdb_tpu.obs.trace import tracer
         from orientdb_tpu.utils.metrics import metrics as _metrics
 
         # earlier tests in this file bloat the process-global stats
-        # table / metric registry / alert state, and every 5ms tick
-        # snapshots ALL of it on the tick thread — GIL time charged to
-        # the measured loop. Reset so the guard measures the watchdog
-        # mechanism, not the suite's accumulated registry (the bloat
-        # made this order-dependent: green alone, red after the file).
+        # table / metric registry / alert state, and every tick
+        # snapshots ALL of it on the tick thread. Reset so the guard
+        # measures the watchdog mechanism, not the suite's accumulated
+        # registry.
         _qstats.reset()
         _metrics.reset()
         engine.reset()
@@ -588,38 +593,69 @@ class TestWatchdogLifecycleAndOverhead:
         for i in range(10):
             db.new_vertex("P", uid=i, age=20 + i)
         q = "SELECT count(*) AS n FROM P WHERE age > 25"
-        n = 1000
+        n = 200
 
-        def loop():
+        def loop(seconds=0.0):
+            """At least ``n`` queries, and then until ``seconds``."""
             t0 = time.perf_counter()
-            for _ in range(n):
+            done = 0
+            while done < n or time.perf_counter() - t0 < seconds:
                 db.query(q).to_dicts()
-            return time.perf_counter() - t0
+                done += 1
+            return time.perf_counter() - t0, done
 
         class _Host:  # duck-typed server: databases + no cluster
             databases = {"wd_overhead": db}
             cluster = None
 
         loop()  # warm parse/plan caches
-        on, off = [], []
-        # 50 Hz is already ~100x the production tick rate and still
-        # lands >5 ticks per measured loop; at 200 Hz the NORMAL cost
-        # of one full-registry evaluation (~1-2ms) reads as >35% loop
-        # overhead through GIL steal alone, failing the guard without
-        # any regression in the mechanism it asserts
-        wd = HealthWatchdog(_Host(), interval=0.02)
-        for _ in range(3):
-            wd.start()
-            try:
-                on.append(loop())
-            finally:
-                wd.stop()
-            off.append(loop())
+        # 10 Hz is 50x the production tick rate and lands >5 ticks
+        # per measured loop. A tick's span is wall time on a thread
+        # that has to win the interpreter from the loop for every
+        # stretch of it (~1-2 ms of work reads as ~7 ms, 20-odd on a
+        # crowded host: a share of 6 to 20 %), and the share is held
+        # under the 35 % the guard has always allowed
+        wd = HealthWatchdog(_Host(), interval=0.1)
+        wd.tick()  # the first evaluation imports what it reads
+
+        tick_threads = set()
+
+        def on_span(sp):
+            if sp.name == "watchdog.tick":
+                tick_threads.add(threading.current_thread().name)
+
+        def counters():
+            c = _metrics.snapshot()["counters"]
+            return (
+                c.get("watchdog.ticks", 0),
+                c.get("span.watchdog.tick.us", 0),
+                c.get("span.watchdog.tick.n", 0),
+            )
+
+        ticks, shares, queries = [], [], []
+        tracer.add_listener(on_span)
+        try:
+            for _ in range(3):
+                wd.start()
+                try:
+                    t0, us0, n0 = counters()
+                    wall, done = loop(1.0)
+                    t1, us1, n1 = counters()
+                finally:
+                    wd.stop()
+                # one tick may still be open across either reading
+                assert abs((t1 - t0) - (n1 - n0)) <= 1
+                ticks.append(t1 - t0)
+                shares.append((us1 - us0) / 1e6 / wall)
+                queries.append(done)
+        finally:
+            tracer.remove_listener(on_span)
         assert engine.summary()["ticks"] > 0  # it really was ticking
-        ratio = min(on) / min(off)
-        assert ratio < 1.35, (
-            f"watchdog overhead {ratio:.2f}x (on={min(on):.3f}s "
-            f"off={min(off):.3f}s for {n} queries)"
+        assert max(ticks) >= 5, ticks
+        assert tick_threads == {"health-watchdog"}, tick_threads
+        assert min(shares) < 0.35, (
+            f"watchdog ticks held {min(shares):.0%} of the loop's wall "
+            f"({ticks} ticks over {queries} queries)"
         )
 
 

@@ -220,6 +220,14 @@ class TestDivergenceEndToEnd:
         monkeypatch.setattr(config, "alert_pending_ticks", 2)
         # short TTL so the probe re-admission leg runs in-test
         monkeypatch.setattr(config, "devicefault_quarantine_ttl_s", 0.2)
+        # the stats table is the process's: where an earlier test file on
+        # this worker ran this statement 8 times (view_min_calls), the
+        # corrupted answer below would be kept as a materialized view and
+        # served again to the probe in step 5 (exec/views takes what was
+        # served; ROADMAP C12)
+        from orientdb_tpu.obs.stats import stats
+
+        stats.reset()
 
         oracle_rows = db.query(MATCH_ROWS, engine="oracle").to_dicts()
         assert len(oracle_rows) == 6
