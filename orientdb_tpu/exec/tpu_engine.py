@@ -260,6 +260,23 @@ def _cap_of(n: int) -> int:
     return K.bucket(max(1, int(n * config.schedule_headroom)))  # lint: allow(jaxlint)
 
 
+def _index_range(
+    dg: DeviceGraph, tier, start: int, size: int, width: int,
+    slab_start: int = 0, slab_size: int = 0,
+):
+    """The contiguous index range ``[start, start + size)`` (then the
+    slab segment), ``-1``-padded to ``width``: a class hull, the vertex
+    universe, an edge list's ids. It stays a ``K.IndexRange``, so every
+    column read through it is a slice, where the columns are whole on
+    one device; a mesh-sharded graph (columns row-sharded with their own
+    padding) and a tiered snapshot get the ``int32`` array and gather as
+    before."""
+    rng = K.IndexRange(start, size, width, slab_start, slab_size)
+    if dg.mesh_graph is not None or tier is not None:
+        return rng.materialise()
+    return rng
+
+
 def _observe_compact(sched: "SizeSchedule", mask, min_capacity: int = 0):
     """Shared compaction protocol: surviving-row indices sized via the
     schedule (one blocking sync on the recording run, free on replay).
@@ -848,7 +865,9 @@ class TpuMatchSolver:
         return self._vertex_scope_cache
 
     def _compile_node(self, node: PatternNode):
-        """Node admission mask: fn(idx_array) -> bool mask over vertex ids.
+        """Node admission mask: fn(idx) -> bool mask over vertex ids,
+        ``idx`` an int32 array or a ``K.IndexRange`` (whose column reads
+        are slices).
 
         Mirrors oracle.check_node: class closure ∧ rid ∧ WHERE. A WHERE
         referencing earlier bindings (``alias.prop``) compiles against the
@@ -874,7 +893,7 @@ class TpuMatchSolver:
             if f.rid is not None:
                 want = self.snap.idx_of(RID(f.rid.cluster, f.rid.position))
                 wi = -2 if want is None else want  # -2 matches nothing (≠ -1 pad)
-                parts.append(lambda idx, env, wi=wi: idx == wi)
+                parts.append(lambda idx, env, wi=wi: K.as_index(idx) == wi)
             if f.where is not None:
                 if _expr_uses_bindings(f.where, self.pattern.nodes):
                     scope = ColumnScope(
@@ -897,7 +916,7 @@ class TpuMatchSolver:
 
         def mask(idx, env=None, parts=parts):
             env = env or {}
-            m = idx >= 0
+            m = K.as_index(idx) >= 0
             for p in parts:
                 m = m & p(idx, env)
             return m
@@ -1283,8 +1302,7 @@ class TpuMatchSolver:
         width = table.width or 1
         V = self.dg.num_vertices
         vb = K.bucket(max(V, 1))
-        univ = jnp.arange(vb, dtype=jnp.int32)
-        univ = jnp.where(univ < V, univ, -1)
+        univ = _index_range(self.dg, self.tier, 0, V, vb)
         node_vecs = [m(univ) for m in masks]
         hops_per_item = []
         for it in items:
@@ -1294,7 +1312,9 @@ class TpuMatchSolver:
                 dec = self.dg.edges[cname]
                 emask = None
                 if f is not None and f.where is not None:
-                    eids = jnp.arange(dec.num_edges, dtype=jnp.int32)
+                    eids = _index_range(
+                        self.dg, self.tier, 0, dec.num_edges, dec.num_edges
+                    )
                     emask = self._edge_where(cname, f.where)(eids, {})
                 dirs = ("out", "in") if it.direction == "both" else (it.direction,)
                 for d in dirs:
@@ -1505,8 +1525,7 @@ class TpuMatchSolver:
         # predicate per EDGE emit re-gathers every referenced column
         # [E]-wide per hop (2-3 extra 80M-row gathers per pass at SF100
         # shape), where a [vb] precompute plus one bool gather does it
-        univ = jnp.arange(vb, dtype=jnp.int32)
-        univ = jnp.where(univ < V, univ, -1)
+        univ = _index_range(self.dg, self.tier, 0, V, vb)
         from contextlib import nullcontext
 
         from orientdb_tpu.obs.trace import span as _span
@@ -1553,7 +1572,7 @@ class TpuMatchSolver:
             E = dec.num_edges
             if E == 0:
                 continue
-            eids = jnp.arange(E, dtype=jnp.int32)
+            eids = _index_range(self.dg, self.tier, 0, E, E)
             emask = (
                 self._edge_where(cname, f.where)(eids, {})
                 if (f is not None and f.where is not None)
@@ -1648,21 +1667,13 @@ class TpuMatchSolver:
             self.snap.slab_vertex_range() if has_class else (0, 0)
         )
         slab = max(shi - slo, 0)
-        if slab:
-            width = K.bucket(max(size + slab, 1))
-            pos = jnp.arange(width, dtype=jnp.int32)
-            idx = jnp.where(
-                pos < size,
-                start + pos,
-                jnp.where(pos < size + slab, slo + (pos - size), -1),
-            )
-        else:
-            idx = start + jnp.arange(K.bucket(max(size, 1)), dtype=jnp.int32)
-            idx = jnp.where(idx < end, idx, -1)
+        idx = _index_range(
+            self.dg, self.tier, start, size,
+            K.bucket(max(size + slab, 1)), slo if slab else 0, slab,
+        )
         mask = self._node_masks[alias](idx)
         cand, n, n_dev = self._compact(mask)
-        cand = K.take_pad(idx, cand, jnp.int32(-1))
-        return cand, n, n_dev
+        return K.index_at(idx, cand), n, n_dev
 
     def _root(self, table: Table, alias: str) -> Table:
         cand, n, n_dev = self._root_candidates(alias)
@@ -2080,8 +2091,7 @@ class TpuMatchSolver:
         depth_alias = item.target.depth_alias
         V = self.dg.num_vertices
         vb = K.bucket(max(V, 1))
-        univ = jnp.arange(vb, dtype=jnp.int32)
-        univ = jnp.where(univ < V, univ, -1)
+        univ = _index_range(self.dg, self.tier, 0, V, vb)
         node_mask_vec = self._node_masks[dst_alias](univ)  # [vb]
         # per-(class, dir) edge hop closures; edge WHERE fused as edge masks
         f = item.edge_filter
@@ -2090,7 +2100,9 @@ class TpuMatchSolver:
             dec = self.dg.edges[cname]
             emask = None
             if f is not None and f.where is not None:
-                eids = jnp.arange(dec.num_edges, dtype=jnp.int32)
+                eids = _index_range(
+                    self.dg, self.tier, 0, dec.num_edges, dec.num_edges
+                )
                 emask = self._edge_where(cname, f.where)(eids, {})
             for d in ("out", "in") if direction == "both" else (direction,):
                 items.append((cname, d, emask))
@@ -2619,8 +2631,7 @@ class TpuTraverseSolver:
         level by level (depth-0 roots first, then each BFS level)."""
         V = self.dg.num_vertices
         vb = K.bucket(max(V, 1))
-        univ = jnp.arange(vb, dtype=jnp.int32)
-        univ = jnp.where(univ < V, univ, -1)
+        univ = _index_range(self.dg, self.tier, 0, V, vb)
         hops = build_bitmap_hops(
             self.dg, self.hop_items, sched=self.sched, tier=self.tier,
             touched=self.tier_touched,

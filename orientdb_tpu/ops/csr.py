@@ -13,7 +13,9 @@ and are masked out, so the jit cache holds O(log n) specializations per
 kernel instead of one per distinct frontier size.
 
 Every kernel traces under ``jax.named_scope("csr.<its name>")`` (inside
-its ``jit``, so an eager call pays nothing): the operations it becomes
+its ``jit``, so an eager call pays nothing; ``take_pad``, which
+dispatches on its index's type before any ``jit``, wears its scope
+outside): the operations it becomes
 carry ``.../csr.gather_expand/...`` in their HLO ``op_name`` metadata,
 under the plan's own scope (``match.replay``, ``exec/tpu_engine``), and
 a profiler trace can say which kernel a fusion came from. Trace time
@@ -22,10 +24,13 @@ only; the compiled program is the same.
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from orientdb_tpu.utils.metrics import metrics
 
 MIN_BUCKET = 8
 
@@ -227,16 +232,131 @@ def compact_indices(mask: jnp.ndarray, out_size: int) -> jnp.ndarray:
     return jnp.where(ok, pos, -1)
 
 
+@dataclass(frozen=True)
+class IndexRange:
+    """A contiguous index range carried AS a range: positions
+    ``[0, size)`` are the indices ``start + pos``, positions
+    ``[size, size + slab_size)`` the optional second segment
+    ``slab_start + (pos - size)`` (the delta slab a root scan appends to
+    its class hull), and the rest, up to the padded ``width``, is ``-1``
+    padding.
+
+    A column read through an ``int32`` index array is a gather, and a TPU
+    gather of scalars is serial (~26 ns an element on the v5e: PERF.md
+    §5); the same read through a range is a slice (:func:`take_range`).
+    All fields are static, so the readers dispatch on the index's type
+    alone: :func:`take_pad`, ``ops/predicates._column_val`` and the node
+    masks of ``exec/tpu_engine``. A consumer that does not know ranges
+    takes :func:`as_index`."""
+
+    start: int
+    size: int
+    width: int
+    slab_start: int = 0
+    slab_size: int = 0
+
+    def __post_init__(self):
+        if min(self.start, self.size, self.slab_start, self.slab_size) < 0:
+            raise ValueError(f"negative field in {self}")
+        if self.size + self.slab_size > self.width:
+            raise ValueError(f"{self} is longer than its width")
+
+    @property
+    def shape(self):
+        return (self.width,)
+
+    def segments(self):
+        """The non-empty ``(start, size)`` segments, in position order."""
+        segs = ((self.start, self.size), (self.slab_start, self.slab_size))
+        return [(s, z) for s, z in segs if z]
+
+    def materialise(self) -> jnp.ndarray:
+        """The ``int32[width]`` index array this range stands for."""
+        return _range_at(self, None)
+
+
+@partial(jax.jit, static_argnames=("rng",))
+@jax.named_scope("csr.range_at")
+def _range_at(rng: IndexRange, pos) -> jnp.ndarray:
+    """The range's indices at positions ``pos`` (``None``: at every
+    position in turn); ``-1`` where ``pos`` is negative or in the
+    padding: ``take_pad(materialise(), pos, -1)`` as arithmetic. One
+    program, so an eager (recording) call holds one output and no
+    ``pos``-sized temporaries."""
+    if pos is None:
+        pos = jnp.arange(rng.width, dtype=jnp.int32)
+    idx = rng.start + pos
+    if rng.slab_size:
+        idx = jnp.where(pos < rng.size, idx, rng.slab_start + (pos - rng.size))
+    live = rng.size + rng.slab_size
+    return jnp.where((pos >= 0) & (pos < live), idx, -1).astype(jnp.int32)
+
+
+def as_index(idx) -> jnp.ndarray:
+    """``idx`` as the ``int32`` array every consumer understands."""
+    return idx.materialise() if isinstance(idx, IndexRange) else idx
+
+
+def index_at(idx, pos: jnp.ndarray) -> jnp.ndarray:
+    """``idx[pos]`` where ``pos ≥ 0``, else ``-1``: arithmetic for a
+    range, a gather for an array."""
+    if isinstance(idx, IndexRange):
+        return _range_at(idx, pos)
+    return take_pad(idx, pos, jnp.int32(-1))
+
+
+def count_read(idx) -> None:
+    """Count one column read by how it lowers: ``plan.read.range`` (a
+    slice) or ``plan.read.gather``. Counted where Python lowers the read
+    (a plan's eager recording, and each trace of its replay); nothing of
+    it is in the compiled program."""
+    metrics.incr(
+        "plan.read.range" if isinstance(idx, IndexRange) else "plan.read.gather"
+    )
+
+
+@partial(jax.jit, static_argnames=("rng",))
+@jax.named_scope("csr.take_range")
+def take_range(values: jnp.ndarray, rng: IndexRange, fill) -> jnp.ndarray:
+    """``take_pad(values, rng.materialise(), fill)`` without the gather:
+    each segment is a static slice, the padding is ``fill``. A segment
+    that runs past the column's end reads the last element there, as
+    ``take_pad``'s clip does; the padding is padded, never re-read."""
+    n = values.shape[0]
+    fill = jnp.asarray(fill, values.dtype)
+    if n == 0:
+        return jnp.full(rng.shape, fill, values.dtype)
+    parts = []
+    for s, z in rng.segments():
+        lo, hi = min(s, n), min(s + z, n)
+        if hi > lo:
+            parts.append(jax.lax.slice(values, (lo,), (hi,)))
+        if hi - lo < z:
+            parts.append(jnp.broadcast_to(values[n - 1], (z - (hi - lo),)))
+    pad = rng.width - rng.size - rng.slab_size
+    if pad:
+        parts.append(jnp.broadcast_to(fill, (pad,)))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+
+
 @jax.jit
-@jax.named_scope("csr.take_pad")
-def take_pad(values: jnp.ndarray, idx: jnp.ndarray, fill) -> jnp.ndarray:
-    """`values[idx]` where idx ≥ 0, else `fill` (padding-safe gather)."""
+def _gather_pad(values: jnp.ndarray, idx: jnp.ndarray, fill) -> jnp.ndarray:
     n = values.shape[0]
     if n == 0:
         return jnp.full(idx.shape, fill, values.dtype)
     ok = idx >= 0
     v = jnp.take(values, jnp.clip(idx, 0, n - 1))
     return jnp.where(ok, v, fill)
+
+
+@jax.named_scope("csr.take_pad")
+def take_pad(values: jnp.ndarray, idx, fill) -> jnp.ndarray:
+    """`values[idx]` where idx ≥ 0, else `fill` (padding-safe gather).
+    An :class:`IndexRange` is sliced, not gathered."""
+    count_read(idx)
+    if isinstance(idx, IndexRange):
+        return take_range(values, idx, fill)
+    return _gather_pad(values, idx, fill)
 
 
 @jax.jit

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from orientdb_tpu.exec.eval import like_match
+from orientdb_tpu.ops import csr as K
 from orientdb_tpu.ops.device_graph import DeviceColumn
 from orientdb_tpu.sql import ast as A
 
@@ -200,6 +201,14 @@ def _column_val(col: DeviceColumn) -> _Val:
         n = col.values.shape[0]
         if n == 0:
             return (jnp.zeros(idx.shape, col.values.dtype), jnp.zeros(idx.shape, bool))
+        K.count_read(idx)
+        if isinstance(idx, K.IndexRange):
+            # a contiguous range is sliced, not gathered; its padding
+            # reads absent, as `ok` makes it below
+            return (
+                K.take_range(col.values, idx, 0),
+                K.take_range(col.present, idx, False),
+            )
         ok = idx >= 0
         ci = jnp.clip(idx, 0, n - 1)
         return (
@@ -220,6 +229,7 @@ def _binding_val(alias: str, col: DeviceColumn) -> _Val:
         n = col.values.shape[0]
         if n == 0:
             return (jnp.zeros(rows.shape, col.values.dtype), jnp.zeros(rows.shape, bool))
+        K.count_read(rows)
         ok = rows >= 0
         ci = jnp.clip(rows, 0, n - 1)
         return (

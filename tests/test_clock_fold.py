@@ -542,7 +542,7 @@ class TestKernelScopes:
         "name",
         [
             "degree_counts", "exclusive_cumsum", "gather_expand", "mask_cumsum",
-            "value_cumsum", "compact_indices", "take_pad", "mask_count",
+            "value_cumsum", "compact_indices", "take_pad", "take_range", "mask_count",
             "indptr_segment_sum", "rows_to_bitmap", "bitmap_hop", "rows_with_matches",
         ],
     )

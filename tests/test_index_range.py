@@ -1,0 +1,405 @@
+"""A contiguous index range is sliced, not gathered (``ops/csr.IndexRange``).
+
+Parity of every reader over a range against the same reader over the
+``int32`` array the range stands for; the lowering of the benchmark's
+rooted statements (no gather as long as the root's hull bucket is left
+in the replay's jaxpr); and the ``plan.read.range`` / ``plan.read.gather``
+counters. No chip and no time in any of it.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from orientdb_tpu.ops import csr as K
+from orientdb_tpu.utils.metrics import metrics
+
+N = 50  # the column's length
+
+#: name -> (start, size, width, slab_start, slab_size)
+RANGES = {
+    "hull_at_the_start": (0, 12, 16, 0, 0),
+    "hull_in_the_middle": (17, 9, 16, 0, 0),
+    "hull_at_the_end": (38, 12, 16, 0, 0),
+    "width_past_the_columns_end": (40, 10, 64, 0, 0),
+    "size_0": (7, 0, 8, 0, 0),
+    "whole_column_no_padding": (0, N, N, 0, 0),
+    "hull_then_slab": (5, 9, 16, 44, 6),
+    "slab_alone": (0, 0, 8, 44, 6),
+    "segment_runs_past_the_column": (45, 10, 16, 0, 0),
+    "segment_wholly_past_the_column": (60, 4, 8, 0, 0),
+}
+
+COLUMNS = {
+    "int32": (np.arange(100, 100 + N, dtype=np.int32), jnp.int32(-1)),
+    "bool": (np.arange(N) % 3 == 0, False),
+    "float32": (np.linspace(0.5, 9.5, N).astype(np.float32), jnp.float32(-2.5)),
+}
+
+
+def _same(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.dtype == b.dtype and a.shape == b.shape
+    assert np.array_equal(a, b), (a, b)
+
+
+class TestTakeRange:
+    @pytest.mark.parametrize("dtype", sorted(COLUMNS))
+    @pytest.mark.parametrize("name", sorted(RANGES))
+    def test_take_range_is_take_pad_over_the_materialised_index(self, name, dtype):
+        values, fill = COLUMNS[dtype]
+        values = jnp.asarray(values)
+        rng = K.IndexRange(*RANGES[name])
+        idx = rng.materialise()
+        assert idx.shape == rng.shape == (rng.width,) and idx.dtype == jnp.int32
+        want = K.take_pad(values, idx, fill)
+        _same(K.take_range(values, rng, fill), want)
+        # the public reader dispatches on the index's type alone
+        _same(K.take_pad(values, rng, fill), want)
+
+    @pytest.mark.parametrize("name", sorted(RANGES))
+    def test_an_empty_column_reads_as_fill(self, name):
+        rng = K.IndexRange(*RANGES[name])
+        empty = jnp.zeros((0,), jnp.int32)
+        _same(
+            K.take_pad(empty, rng, jnp.int32(-1)),
+            K.take_pad(empty, rng.materialise(), jnp.int32(-1)),
+        )
+
+    @pytest.mark.parametrize("name", sorted(RANGES))
+    def test_materialise_is_the_array_the_engine_used_to_build(self, name):
+        start, size, width, slo, slab = RANGES[name]
+        pos = np.arange(width)
+        old = np.where(
+            pos < size,
+            start + pos,
+            np.where(pos < size + slab, slo + (pos - size), -1),
+        ).astype(np.int32)
+        _same(K.IndexRange(*RANGES[name]).materialise(), old)
+
+    @pytest.mark.parametrize("name", sorted(RANGES))
+    def test_index_at_is_take_pad_of_the_materialised_index(self, name):
+        rng = K.IndexRange(*RANGES[name])
+        pos = jnp.asarray(
+            [-1, 0, 1, rng.size - 1, rng.size, rng.size + rng.slab_size - 1,
+             rng.size + rng.slab_size, rng.width - 1, -1],
+            jnp.int32,
+        )
+        pos = jnp.where(pos < rng.width, pos, -1)
+        _same(K.index_at(rng, pos), K.take_pad(rng.materialise(), pos, jnp.int32(-1)))
+        _same(K.index_at(rng.materialise(), pos), K.index_at(rng, pos))
+
+    def test_as_index_leaves_an_array_alone(self):
+        arr = jnp.asarray([3, -1, 7], jnp.int32)
+        assert K.as_index(arr) is arr
+        _same(K.as_index(K.IndexRange(2, 3, 4)), np.asarray([2, 3, 4, -1], np.int32))
+
+    @pytest.mark.parametrize(
+        "fields",
+        [(-1, 2, 4), (0, 5, 4), (0, 2, 4, 0, 3), (0, 2, 4, -1, 1)],
+    )
+    def test_a_range_that_cannot_be_is_refused(self, fields):
+        with pytest.raises(ValueError):
+            K.IndexRange(*fields)
+
+    def test_a_range_lowers_to_no_gather_and_an_array_to_one(self):
+        values = jnp.arange(N, dtype=jnp.int32)
+        rng = K.IndexRange(5, 9, 16, 44, 6)
+
+        def prims(fn, *args):
+            return {e.primitive.name for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)}
+
+        assert "gather" not in prims(lambda v: K.take_pad(v, rng, -1), values)
+        assert "gather" in prims(
+            lambda v, i: K.take_pad(v, i, -1), values, rng.materialise()
+        )
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = sub if hasattr(sub, "eqns") else getattr(sub, "jaxpr", None)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _gather_index_lengths(jaxpr):
+    """The leading length of every gather's index operand."""
+    return [
+        int(e.invars[1].aval.shape[0])
+        for e in _eqns(jaxpr)
+        if e.primitive.name == "gather"
+    ]
+
+
+def _reads():
+    c = metrics.snapshot()["counters"]
+    return c.get("plan.read.range", 0), c.get("plan.read.gather", 0)
+
+
+# -- node masks -----------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def mask_db():
+    """Two vertex classes laid out one after the other, a subclass, a
+    string and an int column with gaps."""
+    from orientdb_tpu.models.database import Database
+    from orientdb_tpu.storage.snapshot import attach_fresh_snapshot
+
+    db = Database("range_masks")
+    db.schema.create_vertex_class("Animal")
+    db.schema.create_class("Dog", superclasses=("Animal",))
+    db.schema.create_vertex_class("Rock")
+    db.schema.create_edge_class("Near")
+    vs = []
+    for i in range(9):
+        vs.append(db.new_vertex("Animal", name=f"a{i % 4}", age=i))
+    for i in range(7):
+        props = {"name": f"d{i % 3}"}
+        if i % 2:
+            props["age"] = 10 + i  # age absent on every other dog
+        vs.append(db.new_vertex("Dog", **props))
+    for i in range(6):
+        vs.append(db.new_vertex("Rock", age=3 * i))
+    for i in range(len(vs) - 1):
+        db.new_edge("Near", vs[i], vs[i + 1])
+    attach_fresh_snapshot(db)
+    db._vs = vs
+    yield db
+    db.detach_snapshot()
+
+
+def _node_patterns(db):
+    rid = db._vs[12].rid  # a dog with an age
+    return {
+        "bare_node": "{as:a}",
+        "class_filter": "{class:Animal, as:a}",
+        "subclass_filter": "{class:Dog, as:a}",
+        "rid_filter": f"{{rid:{rid}, as:a}}",
+        "where_int": "{as:a, where:(age > 4)}",
+        "where_param": "{as:a, where:(age = :k)}",
+        "where_string": "{as:a, where:(name = 'd1')}",
+        "where_is_null": "{as:a, where:(age IS NULL)}",
+        "class_and_where": "{class:Animal, as:a, where:(age < 13 AND name != 'a2')}",
+        "class_rid_and_where": f"{{class:Dog, rid:{rid}, as:a, where:(age > 0)}}",
+    }
+
+
+NODE_CASES = [
+    "bare_node", "class_filter", "subclass_filter", "rid_filter", "where_int",
+    "where_param", "where_string", "where_is_null", "class_and_where",
+    "class_rid_and_where",
+]
+
+
+class TestNodeMasksOverARange:
+    @pytest.mark.parametrize("case", NODE_CASES)
+    def test_a_mask_over_a_range_is_the_mask_over_its_array(self, mask_db, case):
+        from orientdb_tpu.exec.engine import parse_cached
+        from orientdb_tpu.exec.tpu_engine import TpuMatchSolver
+
+        sql = f"MATCH {_node_patterns(mask_db)[case]}-Near->{{as:b}} RETURN b"
+        solver = TpuMatchSolver(mask_db, parse_cached(sql), {"k": 11})
+        mask = solver._node_masks["a"]
+        V = solver.dg.num_vertices
+        lo, hi = solver.snap.vertex_hull("Dog")
+        assert 0 < lo < hi < V
+        some_true = False
+        for rng in (
+            K.IndexRange(0, V, K.bucket(V)),  # the universe
+            K.IndexRange(lo, hi - lo, K.bucket(hi - lo)),  # a class hull
+            K.IndexRange(lo, hi - lo, 32, 0, 4),  # a hull and a second segment
+            K.IndexRange(V - 3, 3, 8),  # the column's end, padded past it
+            K.IndexRange(4, 0, 8),  # nothing
+        ):
+            got = np.asarray(mask(rng))
+            _same(got, mask(rng.materialise()))
+            some_true = some_true or bool(got.any())
+        assert some_true, "the case admits nothing anywhere: it tests nothing"
+
+
+# -- the lowering of the benchmark's rooted statements --------------------------
+
+FRIENDS = (
+    "MATCH {class:Person, as:p, where:(uid = :personId)}-knows-{as:f} "
+    "RETURN f.uid AS personId, f.age AS age"
+)
+CREATOR_1HOP = (
+    "MATCH {class:Message, as:m, where:(length > :minLen)}-hasCreator->"
+    "{as:p, where:(age < :maxAge)} RETURN count(*) AS n"
+)
+LOWERED = {
+    # statement, parameters, the root's class
+    "friends": (FRIENDS, {"personId": 17}, "Person"),
+    # few candidates: the COUNT's own gather through them, at their
+    # capacity, stays well under the hull's bucket
+    "creator_1hop": (CREATOR_1HOP, {"minLen": 1950, "maxAge": 60}, "Message"),
+}
+
+
+@pytest.fixture(scope="module")
+def snb():
+    from orientdb_tpu.exec.tpu_engine import drain_warmups
+    from orientdb_tpu.storage.bigshape import build_snb_shape
+
+    db, snap = build_snb_shape(3000, msgs_per_person=3, avg_knows=6, seed=1)
+    yield db, snap
+    drain_warmups()
+    db.detach_snapshot()
+
+
+def _record(db, snap, sql, params):
+    known = set(getattr(snap, "_plan_cache", ()))
+    before = _reads()
+    rows = db.query(sql, params, engine="tpu", strict=True).to_dicts()
+    after = _reads()
+    (new,) = set(snap._plan_cache) - known
+    plan = snap._plan_cache[new].plans[0]
+    return rows, plan, (after[0] - before[0], after[1] - before[1])
+
+
+class TestLowering:
+    @pytest.mark.parametrize("shape", sorted(LOWERED))
+    def test_no_gather_as_long_as_the_roots_hull_bucket(self, snb, shape):
+        db, snap = snb
+        sql, params, root_class = LOWERED[shape]
+        rows, plan, (ranged, gathered) = _record(db, snap, sql, params)
+        assert rows and (shape != "friends" or len(rows) > 3)
+        lo, hi = snap.vertex_hull(root_class)
+        hull = K.bucket(hi - lo)
+        assert hull >= 2048  # far from every other buffer of the plan
+        jaxpr = jax.make_jaxpr(plan._replay)(
+            plan._arg_subset(), plan._dyn_args(params)
+        ).jaxpr
+        lengths = _gather_index_lengths(jaxpr)
+        assert lengths, "a plan with no gather at all proves nothing"
+        assert hull not in lengths, sorted(set(lengths))
+        if shape == "friends":
+            # a rooted row read: nothing in it is sized by the class
+            assert max(lengths) < hull // 8, sorted(set(lengths))
+        assert ranged > 0 and gathered > 0
+
+    def test_the_vmapped_group_replay_has_no_hull_gather_either(self, snb):
+        db, snap = snb
+        sql, params, _ = LOWERED["friends"]
+        rows, plan, _counts = _record(
+            db, snap, sql.replace("AS age", "AS years"), params
+        )
+        replay = plan._replay_group if plan._rows_grouped() else plan._replay
+        dyn = {k: jnp.stack([v] * 4) for k, v in plan._dyn_args(params).items()}
+        jaxpr = jax.make_jaxpr(jax.vmap(replay, in_axes=(None, 0)))(
+            plan._arg_subset(), dyn
+        ).jaxpr
+        lo, hi = snap.vertex_hull("Person")
+        assert K.bucket(hi - lo) not in _gather_index_lengths(jaxpr)
+
+    def test_a_seeded_root_counts_only_gathers(self):
+        """An indexed ``uid`` seeds the root from the host index: its
+        candidates are an array, and every read through them a gather."""
+        from orientdb_tpu import Database, PropertyType
+        from orientdb_tpu.exec.tpu_engine import drain_warmups
+        from orientdb_tpu.storage.snapshot import attach_fresh_snapshot
+
+        db = Database("range_seeded")
+        person = db.schema.create_vertex_class("Person")
+        person.create_property("uid", PropertyType.LONG)
+        db.schema.create_edge_class("knows")
+        vs = [db.new_vertex("Person", uid=i, age=20 + i) for i in range(30)]
+        for i in range(29):
+            db.new_edge("knows", vs[i], vs[i + 1])
+        db.command("CREATE INDEX Person.uid ON Person (uid) UNIQUE")
+        snap = attach_fresh_snapshot(db)
+        try:
+            rows, plan, (ranged, gathered) = _record(
+                db, snap, FRIENDS, {"personId": 7}
+            )
+            assert sorted(r["personId"] for r in rows) == [6, 8]
+            assert plan.seed_spec, "the root was not seeded from the index"
+            assert ranged == 0 and gathered > 0
+            # the same statement with no index scans the hull as a range
+            db.command("DROP INDEX Person.uid")
+            snap2 = attach_fresh_snapshot(db)
+            rows2, plan2, (ranged2, _g) = _record(
+                db, snap2, FRIENDS, {"personId": 7}
+            )
+            assert sorted(r["personId"] for r in rows2) == [6, 8]
+            assert not plan2.seed_spec and ranged2 > 0
+        finally:
+            drain_warmups()
+            db.detach_snapshot()
+
+
+# -- who materialises: a mesh-sharded graph --------------------------------------
+
+
+class TestWhoKeepsTheGather:
+    def test_a_mesh_sharded_graph_gets_the_array(self):
+        from orientdb_tpu.exec.tpu_engine import _index_range
+
+        class Graph:
+            mesh_graph = None
+
+        plain, sharded = Graph(), Graph()
+        sharded.mesh_graph = object()
+        assert isinstance(_index_range(plain, None, 3, 4, 8), K.IndexRange)
+        for got in (
+            _index_range(sharded, None, 3, 4, 8),
+            _index_range(plain, object(), 3, 4, 8),  # a tiered snapshot
+        ):
+            _same(got, K.IndexRange(3, 4, 8).materialise())
+
+
+# -- a delta-maintained snapshot: the slab is the hull's second segment ----------
+
+
+class TestSlabSegment:
+    ROWS = (
+        "MATCH {class:Person, as:p, where:(age > :a)}-Knows->{as:q} "
+        "RETURN p.name AS p, q.name AS q"
+    )
+    BARE = "MATCH {as:p, where:(age > :a)}-Knows->{as:q} RETURN p.name AS p, q.name AS q"
+    COUNT = (
+        "MATCH {class:Person, as:p, where:(age > :a)}-Knows->{as:q} "
+        "RETURN count(*) AS n"
+    )
+
+    @pytest.mark.parametrize("sql", ["ROWS", "BARE", "COUNT"])
+    def test_a_root_in_the_live_slab_is_found(self, sql):
+        from orientdb_tpu.exec.tpu_engine import drain_warmups
+        from orientdb_tpu.models.database import Database
+        from orientdb_tpu.storage.deltas import arm_delta_maintenance
+
+        db = Database(f"range_slab_{sql.lower()}")
+        vs = [db.new_vertex("Person", name=f"p{i}", age=20 + i) for i in range(12)]
+        for i in range(11):
+            db.new_edge("Knows", vs[i], vs[i + 1])
+        arm_delta_maintenance(db, spare_vertices=64, spare_edges=64)
+        q = getattr(self, sql)
+        try:
+            # the two newcomers land in the append slab, outside the hull
+            w = db.new_vertex("Person", name="w", age=77)
+            x = db.new_vertex("Person", name="x", age=78)
+            db.new_edge("Knows", w, vs[0])
+            db.new_edge("Knows", x, w)
+            db.delete(vs[9])  # a dead base vertex: class -1 inside the hull
+            before = _reads()
+            got = db.query(q, {"a": 28}, engine="tpu", strict=True).to_dicts()
+            assert _reads()[0] > before[0]
+            want = db.query(q, {"a": 28}, engine="oracle").to_dicts()
+            key = lambda rows: sorted(str(sorted(r.items())) for r in rows)
+            assert key(got) == key(want)
+            if sql == "COUNT":
+                assert got == [{"n": 3}]  # p10->p11, w->p0, x->w; p9 is gone
+            else:
+                assert {"p": "w", "q": "p0"} in got and {"p": "x", "q": "w"} in got
+            snap = db.current_snapshot(require_fresh=True)
+            assert snap.slab_vertex_range()[1] > snap.slab_vertex_range()[0]
+        finally:
+            drain_warmups()
+            db.detach_snapshot()
