@@ -10,11 +10,12 @@
     that it is the same computation on the chip and in a CPU test.
 
 ``stale_snapshot``
-    A guarantee of the configuration broken: the server holds the
-    snapshot of one batch of updates ago (one ``knows`` target and one
-    message creator in a thousand differ from the data), where the
-    configuration states reads of THE immutable snapshot, exact. For
-    cells whose sums never pass 256, where the lower precision is exact.
+    A guarantee of the configuration broken: the server holds what the
+    configuration's kinds module makes of the data with its planted
+    fault (``<module>.stale(raw, seed)``; for the SNB arrays the snapshot
+    of one batch of updates ago), where the configuration states reads
+    of THE immutable snapshot, exact. For cells whose sums never pass
+    256, where the lower precision is exact.
 
 Run on the chip at a cell's own size:
 
@@ -28,12 +29,9 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import dataclasses
 import json
 import os
 import sys
-
-import numpy as np
 
 CONTROLS = ("lower_precision", "stale_snapshot")
 
@@ -65,20 +63,6 @@ def lower_precision():
     finally:
         csr._block_scan_f32, csr.value_cumsum = sound_scan, sound_cumsum
         jax.clear_caches()
-
-
-def stale_snapshot(raw, seed: int, share: float = 0.001):
-    """``raw`` as it stood one batch of updates ago."""
-    rng = np.random.default_rng([int(seed) & 0xFFFFFFFF, 0x57A1E])
-    out = dataclasses.replace(
-        raw, knows_dst=raw.knows_dst.copy(), creator=raw.creator.copy()
-    )
-    for arr in (out.knows_dst, out.creator):
-        if arr.size:
-            n = max(1, int(arr.size * share))
-            at = rng.choice(arr.size, n, replace=False)
-            arr[at] = (arr[at] + 1 + rng.integers(0, raw.P - 1, n)) % raw.P
-    return out
 
 
 def main(argv=None) -> int:

@@ -2,7 +2,7 @@
 
 layer: kernels (ops/csr); source: device_trace; moves: qps. The least
 bytes the traced span's answered requests must move
-(``benchmark/peaks.least_bytes``) over the chip's HBM bandwidth
+(the kinds module's ``least_bytes``) over the chip's HBM bandwidth
 (``benchmark/peaks.PEAKS``), over the device busy time of the span, in
 percent. The bound is memory: these statements do integer compares and
 adds, a few operations per byte."""

@@ -1,7 +1,7 @@
 """lane_wait_ms_per_q — enqueue to the batch's dispatch, per request.
 
 layer: coalescer lanes (server/coalesce); source: program_span;
-moves: latency_p50_ms. Δ``critpath.queue_us`` / Δ``critpath.requests`` /
+moves: qps. Δ``critpath.queue_us`` / Δ``critpath.requests`` /
 1000 over the window: the ``queue`` segment (``obs/critpath``), a rider's
 stay in its lane from ``submit`` until the worker stages the batch it
 rides. Service behind the batch in flight is not in it (that is

@@ -1,7 +1,7 @@
 """marshal_ms_per_q — rows to dicts and dicts to bytes, per request.
 
 layer: result marshal (server/coalesce to_dicts, binary_server encode);
-source: program_span; moves: latency_p50_ms. Δ``critpath.marshal_us`` /
+source: program_span; moves: qps. Δ``critpath.marshal_us`` /
 Δ``critpath.requests`` / 1000 over the window: the lane worker's
 ``to_dicts`` of a rider's result and the session's JSON encode of the
 reply, both stamped as the ``marshal`` segment (``obs/critpath``)."""

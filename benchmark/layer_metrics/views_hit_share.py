@@ -1,7 +1,7 @@
 """views_hit_share — share of requests a materialised view answered.
 
 layer: engine front doors (exec/views); source: program_counter;
-moves: latency_p50_ms. Δ``views.hit`` over the window's requests, in
+moves: qps. Δ``views.hit`` over the window's requests, in
 percent. These cells repeat no (statement, parameters) pair, so it
 should read 0."""
 

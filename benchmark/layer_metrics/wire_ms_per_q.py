@@ -1,7 +1,7 @@
 """wire_ms_per_q — the server's share of the wire, per request.
 
 layer: wire (server/binary_server, client/remote); source: program_span;
-moves: latency_p50_ms. (Δ``critpath.parse_us`` + Δ``critpath.flush_us``)
+moves: qps. (Δ``critpath.parse_us`` + Δ``critpath.flush_us``)
 / Δ``critpath.requests`` / 1000 over the window: the frame's decode and
 the reply's ``sendall``, as ``obs/critpath`` stamps them on the session's
 thread and folds them into counters at commit. The kernel's and the

@@ -11,8 +11,9 @@ of ``{"op": "run", "seconds", "base": [per-shape pool offset]}`` or
 ``{"op": "burst", "shape", "k", "base"}`` and at last ``{"op": "quit"}``;
 the generator answers each with one line. A run is a closed loop: every
 session walks the plan's block from its own offset (or, pinned, stays
-there), sends its next request when the last has answered, STOPS ISSUING
-at ``seconds`` and returns when its last request has answered.
+there), sends its next request when the last has answered (after the
+mix's seeded pause, where it states one), STOPS ISSUING at ``seconds``
+and returns when its last request has answered.
 Every request issued is reported: none is dropped for being in flight.
 
 Times are ``CLOCK_MONOTONIC`` seconds, the same clock in every process
@@ -22,6 +23,7 @@ of the machine.
 from __future__ import annotations
 
 import json
+import random
 import sys
 import threading
 import time
@@ -31,6 +33,17 @@ from benchmark.canon import digest, rows_of
 
 def now() -> float:
     return time.clock_gettime(time.CLOCK_MONOTONIC)
+
+
+def pauses(seed: int, session: int, think_ms: list):
+    """The pauses of one session before its sends, in seconds, without
+    end: uniform between the mix's two ``think_ms``, a function of the
+    seed and the session's index alone, so that every run of a seed
+    pauses alike and no two sessions pause in step."""
+    lo, hi = think_ms
+    rng = random.Random(f"think:{int(seed)}:{int(session)}")
+    while True:
+        yield rng.uniform(lo, hi) / 1000.0
 
 
 class Session(threading.Thread):
@@ -80,22 +93,26 @@ class Session(threading.Thread):
 
     def loop(self, t_start: float, seconds: float, base: list) -> list:
         """The closed loop: walk the block from this session's offset by
-        the plan's stride (0: stay there), issue until ``seconds`` after
+        the plan's stride (0: stay there), pause before each send where
+        the mix states a pause, issue until ``seconds`` after
         ``t_start``, wait for every answer."""
         plan = self.plan
         block = plan["block"]
-        think = plan["think_ms"] / 1000.0
+        lo_hi = plan["think_ms"]
+        think = pauses(plan["seed"], self.idx, lo_hi) if lo_hi[1] > 0 else None
         used = [0] * len(plan["shapes"])
         pos = plan["offsets"][self.idx]
         records = []
-        while now() - t_start < seconds:
+        while True:
+            if think:
+                time.sleep(next(think))
+            if now() - t_start >= seconds:
+                break
             i = block[pos % len(block)]
             pos += plan["stride"]
             k = base[i] + self.idx + used[i] * plan["sessions"]
             used[i] += 1
             records.append(self.one(i, k))
-            if think:
-                time.sleep(think)
         return records
 
     def run(self) -> None:

@@ -1,7 +1,8 @@
-"""The table of peaks and the least bytes each kind of statement must
-move: the denominators of ``hbm_roofline_share``. Kept with the
-benchmark so that a PR which replaces a kernel is read against the
-same work.
+"""The table of published peaks: what the chip can do, the denominator
+of ``hbm_roofline_share``. It belongs to the chip, not to a deployment;
+the least bytes a kind of statement must move are its kinds module's
+(``benchmark/kinds/<module>.least_bytes``). Kept with the benchmark so
+that a PR which replaces a kernel is read against the same work.
 """
 
 from __future__ import annotations
@@ -22,33 +23,3 @@ def peak_for(device_kind: str) -> dict:
     if device_kind not in PEAKS:
         raise KeyError(f"no published peaks for device kind {device_kind!r}")
     return PEAKS[device_kind]
-
-
-def least_bytes(kind: str, P: int, M: int, E: int) -> float:
-    """Bytes the *query* needs for one request of a reference kind on a
-    graph of ``P`` persons, ``M`` messages and ``E`` directed ``knows``
-    edges: every int32 CSR array and column the statement must read,
-    once, and every result value written, once. A function of the
-    graph's sizes alone: never of what the present kernels move.
-
-    Rooted kinds are reckoned at the graph's mean degrees
-    (``d = E / P`` out, ``2 d`` both ways), which the curated roots stay
-    near."""
-    d = E / P
-    w = 4  # every id, pointer and property column is int32
-    table = {
-        # knows: indptr, dst, creationDate; age of both ends; messages per
-        # person = the in-direction pointers of hasCreator over persons
-        "config5_count": w * (P + 2 * E + P + P) + w,
-        # length of every message, its creator, the creators' ages
-        "creator_1hop_count": w * (2 * M + P) + w,
-        "knows_1hop_count": w * (P + E + P) + w,
-        # the second hop's per-vertex weights must be complete before the
-        # first hop sums them: the edge list is read twice
-        "knows_2hop_count": w * (2 * P + 2 * E + P) + w,
-        # two pointer pairs, 2d neighbour ids, their ages; 2 values a row out
-        "friends_rows": w * (4 + 2 * d + 2 * d) + w * 4 * d,
-    }
-    if kind not in table:
-        raise KeyError(f"no byte count for reference kind {kind!r}")
-    return float(table[kind])

@@ -2,8 +2,10 @@
 
     python3 -m benchmark.run --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-One process holds the chip. It makes the cell's graph from ``--seed``
-(``datagen``), attaches it to a ``server.server.Server`` on loopback,
+One process holds the chip. It loads the kinds module the cell's
+configuration names (``benchmark/kinds/<module>.py``: generator, attach,
+reference, root measures, byte counts), makes the cell's data from
+``--seed``, attaches it to a ``server.server.Server`` on loopback,
 starts the load generator (``loadgen``, a process of its own without
 JAX), warms the cell's own statement shapes through the served path at
 the cell's own concurrency until the plan counters stand still, and
@@ -13,8 +15,8 @@ The window (``run_window`` in ``loadgen``): sessions stop ISSUING at
 ``--seconds`` and the window closes when the last issued request has
 answered. Every request issued is in ``attempted``, in ``qps`` and in
 both percentiles. After the window the chip's peak memory is read, the
-server stopped and the graph detached; then every answer of the window
-is compared with the numpy reference (``reference``), which decides
+server stopped and the data detached; then every answer of the window
+is compared with the kinds module's plain reference, which decides
 ``correct``.
 
 The last line of standard output is the one JSON object the driver
@@ -87,12 +89,39 @@ def metrics_for(bench: dict, section: str, workload: str) -> list:
     ]
 
 
+def load_module(sub: str, name: str, root: str = HERE):
+    """``benchmark/<sub>/<name>.py``, loaded by path: what belongs to one
+    metric or one kind of deployment is a file that ``BENCHMARK.json`` or
+    a configuration names, never an entry in a table here."""
+    path = os.path.join(root, sub, name + ".py")
+    spec = importlib.util.spec_from_file_location(f"{sub}_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod  # dataclasses look their module up by name
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(name: str, root: str = HERE):
     """``benchmark/layer_metrics/<name>.py``: one metric, one reader."""
-    path = os.path.join(root, "layer_metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"layer_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    return load_module("layer_metrics", name, root)
+
+
+#: what ``benchmark/kinds/<module>.py`` has to define (kinds/README.md)
+KINDS_NAMES = ("make_raw", "attach", "Reference", "Measures", "least_bytes", "stale")
+
+
+def load_kinds(cfg: dict, root: str = HERE):
+    """The kinds module a configuration names, ``benchmark/kinds/<module>.py``:
+    everything the benchmark knows about one kind of deployment. There is
+    no default and no fallback."""
+    if not cfg.get("kinds"):
+        raise SystemExit(
+            f"benchmark: configuration {cfg.get('name')!r} names no \"kinds\" module"
+        )
+    mod = load_module("kinds", cfg["kinds"], root)
+    missing = [n for n in KINDS_NAMES if not hasattr(mod, n)]
+    if missing:
+        raise SystemExit(f"benchmark: kinds module {mod.__file__} lacks {', '.join(missing)}")
     return mod
 
 
@@ -266,6 +295,7 @@ def run_cell(
 
     cfg = load_json("configs", cell["config"], root)
     mix = load_json("traffic", cell["traffic"], root)
+    kinds = load_kinds(cfg, root)
 
     import jax
 
@@ -290,26 +320,25 @@ def run_cell(
 
     parts = {"import_s": now() - T_PROCESS}
     t = now()
-    from benchmark.datagen import attach, make_raw
-    from benchmark.reference import Reference
-
     from benchmark import control as controls
 
     broken = contextlib.ExitStack()
     if control == "lower_precision":
         broken.enter_context(controls.lower_precision())
-    raw = make_raw(cfg["scale"], args.seed)
-    served = controls.stale_snapshot(raw, args.seed) if control == "stale_snapshot" else raw
-    db, snap = attach(served)
+    raw = kinds.make_raw(cfg["scale"], args.seed)
+    served = kinds.stale(raw, args.seed) if control == "stale_snapshot" else raw
+    db, snap = kinds.attach(served)
     del served
     parts["build_s"] = now() - t
     t = now()
-    ref = Reference(raw)
-    plan = build_plan(mix, ref, args.seed, int(mix.get("pool_size", 20000)))
+    ref = kinds.Reference(raw)
+    plan = build_plan(
+        mix, kinds.Measures(ref), args.seed, int(mix.get("pool_size", 20000))
+    )
     parts["plan_s"] = now() - t
     note(
-        f"graph P={raw.P} M={raw.M} E={raw.E}; block {plan['block']}; "
-        f"pools {[len(s['pool']['rows']) for s in plan['shapes']]}"
+        f"data {({k: getattr(v, 'shape', v) for k, v in vars(raw).items()})}; "
+        f"block {plan['block']}; pools {[len(s['pool']['rows']) for s in plan['shapes']]}"
     )
 
     from orientdb_tpu.server.server import Server
@@ -428,7 +457,7 @@ def run_cell(
     }
     result = {"correct": False, "attempted": len(records), "failed": failed}
     if tracing:
-        from benchmark import peaks, tracered
+        from benchmark import tracered
 
         t = now()
         loaded = tracered.load_xplane(tracered.find_xplane(trace_dir))
@@ -437,7 +466,6 @@ def run_cell(
         shutil.rmtree(trace_dir, ignore_errors=True)
         note(f"trace read in {now() - t:.1f}s: {trace['summary']}")
         in_span = [r for r in records if t_trace[0] <= r[4] <= t_trace[1]]
-        sizes = {"P": raw.P, "M": raw.M, "E": raw.E}
         obs = {
             "counters": counters,
             "requests": len(records),
@@ -445,7 +473,7 @@ def run_cell(
             "requests_in_trace": len(in_span),
             "trace": trace,
             "least_bytes_in_trace": sum(
-                peaks.least_bytes(plan["shapes"][r[1]]["reference"], **sizes)
+                kinds.least_bytes(plan["shapes"][r[1]]["reference"], raw)
                 for r in in_span
             ),
             "device_kind": dev.device_kind,

@@ -23,13 +23,14 @@ import time
 import numpy as np
 import pytest
 
-from benchmark import canon, control, loadgen, peaks, run, tracered, traffic
-from benchmark.datagen import attach, make_raw
-from benchmark.reference import Reference
+from benchmark import canon, loadgen, peaks, run, tracered, traffic
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH_DIR = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH_DIR)
+#: the kinds module of both accepted configurations, loaded as a run loads it
+SNB = run.load_kinds({"name": "tests", "kinds": "snb_arrays"})
+make_raw, attach, Reference, Measures = SNB.make_raw, SNB.attach, SNB.Reference, SNB.Measures
 TINY = {"persons": 200, "avg_knows": 6, "msgs_per_person": 12, "supernodes": 2, "supernode_degree": 40}
 
 
@@ -74,7 +75,7 @@ def test_block_refuses_weights_that_are_not_whole(bad):
 @pytest.fixture(scope="module")
 def tiny():
     raw = make_raw(TINY, 11)
-    return raw, Reference(raw)
+    return raw, Measures(Reference(raw))
 
 
 @pytest.mark.parametrize("mix_name", sorted(all_mixes()))
@@ -83,7 +84,7 @@ def test_two_seeds_same_shape_order_different_parameters(mix_name):
     plans = []
     for seed in (7, 2**31 + 12345):
         raw = make_raw(TINY, seed)
-        plans.append(traffic.build_plan(mix, Reference(raw), seed, 64))
+        plans.append(traffic.build_plan(mix, Measures(Reference(raw)), seed, 64))
     a, b = plans
     assert a["block"] == b["block"] and a["offsets"] == b["offsets"]
     assert [s["sql"] for s in a["shapes"]] == [s["sql"] for s in b["shapes"]]
@@ -106,10 +107,10 @@ def test_every_seed_gets_the_same_sizes_in_another_order():
 
 
 def test_same_seed_same_plan(tiny):
-    raw, ref = tiny
+    _raw, measures = tiny
     mix = all_mixes()["rooted_16s"]
-    assert traffic.build_plan(mix, ref, 11, 64) == traffic.build_plan(
-        mix, Reference(make_raw(TINY, 11)), 11, 64
+    assert traffic.build_plan(mix, measures, 11, 64) == traffic.build_plan(
+        mix, Measures(Reference(make_raw(TINY, 11))), 11, 64
     )
 
 
@@ -120,17 +121,17 @@ def test_sessions_start_at_different_places():
 
 
 def test_a_pinned_mix_gives_every_shape_a_session_of_its_own(tiny):
-    _raw, ref = tiny
+    _raw, measures = tiny
     mix = all_mixes()["scan_4s"]
-    plan = traffic.build_plan(mix, ref, 3, 32)
+    plan = traffic.build_plan(mix, measures, 3, 32)
     assert plan["stride"] == 0 and plan["sessions"] == len(plan["shapes"]) == 4
     assert plan["shape_sessions"] == [[0], [1], [2], [3]]
-    walking = traffic.build_plan({**mix, "walk": "block"}, ref, 3, 32)
+    walking = traffic.build_plan({**mix, "walk": "block"}, measures, 3, 32)
     assert walking["stride"] == 1 and walking["shape_sessions"] == [[0, 1, 2, 3]] * 4
     with pytest.raises(ValueError):  # three sessions cannot hold four shapes
-        traffic.build_plan({**mix, "sessions": 3}, ref, 3, 32)
+        traffic.build_plan({**mix, "sessions": 3}, measures, 3, 32)
     with pytest.raises(ValueError):
-        traffic.build_plan({**mix, "walk": "sideways"}, ref, 3, 32)
+        traffic.build_plan({**mix, "walk": "sideways"}, measures, 3, 32)
 
 
 def test_a_pinned_session_sends_its_own_shape_alone():
@@ -152,13 +153,13 @@ def test_a_pinned_session_sends_its_own_shape_alone():
 
 @pytest.mark.parametrize("measure", ["degree_both"])
 def test_curated_roots_fall_inside_the_stated_band(tiny, measure):
-    raw, ref = tiny
+    raw, measures = tiny
     shape = {
         "name": "s",
         "params": {"personId": {"root": measure, "band": [0.4, 0.6]}, "k": {"const": 3}},
     }
-    pool = traffic.draw_pool(shape, ref, 5, 500)
-    values = getattr(traffic.Measures(ref), measure)()
+    pool = traffic.draw_pool(shape, measures, 5, 500)
+    values = getattr(measures, measure)()
     lo, hi = np.quantile(values, [0.4, 0.6])
     roots = [r[0] for r in pool["rows"]]
     assert roots and all(lo <= values[p] <= hi for p in roots)
@@ -169,21 +170,21 @@ def test_curated_roots_fall_inside_the_stated_band(tiny, measure):
 
 
 def test_int_parameters_are_distinct_and_in_range(tiny):
-    _raw, ref = tiny
+    _raw, measures = tiny
     shape = {"name": "c", "params": {"d": {"int": [10, 500]}, "a": {"const": 40}}}
-    rows = traffic.draw_pool(shape, ref, 1, 300)["rows"]
+    rows = traffic.draw_pool(shape, measures, 1, 300)["rows"]
     assert len({tuple(r) for r in rows}) == len(rows) > 100
     assert all(10 <= r[0] <= 500 and r[1] == 40 for r in rows)
 
 
 def test_the_pools_first_tuple_carries_the_lead_values(tiny):
-    _raw, ref = tiny
+    _raw, measures = tiny
     shape = {
         "name": "c",
         "params": {"d": {"int": [10, 500], "lead": 10}, "a": {"int": [1, 9], "lead": 9}},
     }
     for seed in (1, 2**31 + 2):
-        rows = traffic.draw_pool(shape, ref, seed, 300)["rows"]
+        rows = traffic.draw_pool(shape, measures, seed, 300)["rows"]
         assert rows[0] == [10, 9] and len({tuple(r) for r in rows}) == len(rows)
 
 
@@ -208,7 +209,8 @@ def stub_sessions(delays, stride=1, shapes=1):
     n = len(delays)
     plan = {
         "sessions": n,
-        "think_ms": 0,
+        "seed": 0,
+        "think_ms": [0.0, 0.0],
         "block": list(range(shapes)),
         "offsets": [s % shapes for s in range(n)],
         "stride": stride,
@@ -323,11 +325,12 @@ def test_reference_agrees_with_the_embedded_engine(embedded, kind):
         assert got == want, (kind, params)
 
 
-def test_every_reference_kind_has_a_byte_count():
+def test_every_reference_kind_has_a_byte_count(tiny):
+    raw, _measures = tiny
     for kind in every_shape():
-        assert peaks.least_bytes(kind, P=1000, M=50_000, E=30_000) > 0
+        assert SNB.least_bytes(kind, raw) > 0
     with pytest.raises(KeyError):
-        peaks.least_bytes("no_such_kind", 1, 1, 1)
+        SNB.least_bytes("no_such_kind", raw)
     with pytest.raises(KeyError):
         peaks.peak_for("cpu")
 
@@ -341,8 +344,20 @@ def test_the_benchmark_imports_nothing_it_may_not():
             if name.endswith(".py"):
                 text = open(os.path.join(dirpath, name)).read()
                 assert not any(b in text for b in banned), name
-    ref_src = open(os.path.join(BENCH_DIR, "reference.py")).read()
-    assert "orientdb_tpu" not in ref_src.split('"""', 2)[2]
+    # a kinds module meets the program in ``attach`` alone: nothing of it
+    # at the module's top level, nothing in the reference's side
+    import ast
+    import inspect
+
+    for name in sorted(os.listdir(os.path.join(BENCH_DIR, "kinds"))):
+        if not name.endswith(".py"):
+            continue
+        mod = run.load_kinds({"name": "tests", "kinds": name[:-3]})
+        top = ast.parse(inspect.getsource(mod)).body
+        imports = [n for n in top if isinstance(n, (ast.Import, ast.ImportFrom))]
+        assert not any("orientdb_tpu" in ast.unparse(n) for n in imports), name
+        for part in (mod.Reference, mod.Measures, mod.least_bytes, mod.make_raw, mod.stale):
+            assert "import" not in inspect.getsource(part), (name, part)
 
 
 # -- the trace reduction ------------------------------------------------------------------
@@ -587,13 +602,14 @@ def plugged(tmp_path_factory):
     metric as files beside copies of what is there, and a BENCHMARK.json
     (as a dict) that adds their entries: no existing file is edited."""
     root = str(tmp_path_factory.mktemp("bench"))
-    for sub in ("configs", "traffic", "layer_metrics"):
+    for sub in ("configs", "traffic", "layer_metrics", "kinds"):
         shutil.copytree(os.path.join(BENCH_DIR, sub), os.path.join(root, sub))
     with open(os.path.join(root, "configs", "tiny.json"), "w") as f:
-        json.dump({"name": "tiny", "scale": TINY}, f)
+        json.dump({"name": "tiny", "kinds": "snb_arrays", "scale": TINY}, f)
     with open(os.path.join(root, "configs", "heavy.json"), "w") as f:
         json.dump(
-            {"name": "heavy", "scale": {"persons": 120, "avg_knows": 8, "msgs_per_person": 400}}, f
+            {"name": "heavy", "kinds": "snb_arrays",
+             "scale": {"persons": 120, "avg_knows": 8, "msgs_per_person": 400}}, f
         )
     mix = traffic.load_json("traffic", "rooted_16s")
     mix.update(name="pair_3s", sessions=3, pool_size=200)
@@ -633,8 +649,8 @@ def test_a_driven_run_is_correct_and_counts_everything(plugged):
     assert res["correct"] is True and res["failed"] == 0
     assert res["attempted"] == res["compared"]["answers_compared"]["value"] > 20
     assert list(res)[-1] == "compared"  # the numbers compared come last
-    # latency_p95_ms lists its cells, and this new one is not among them
-    assert set(res["metrics"]) == {"qps", "latency_p50_ms", "setup_s"}
+    # both latencies list their cells, and this new one is not among them
+    assert set(res["metrics"]) == {"qps", "setup_s"}
     assert all(m["value"] > 0 for m in res["metrics"].values())
     assert res["window"]["param_repeats"] >= 0  # 200 persons: the bands run out
     by_shape = res["window"]["by_shape"]
@@ -692,7 +708,7 @@ def test_the_lower_precision_control_is_not_correct_once_sums_pass_256(plugged):
 
 def test_stale_snapshot_changes_one_in_a_thousand():
     raw = make_raw({**TINY, "persons": 5000}, 4)
-    stale = control.stale_snapshot(raw, 4)
+    stale = SNB.stale(raw, 4)
     assert 0 < (stale.knows_dst != raw.knows_dst).sum() <= raw.E // 1000 + 1
     assert 0 < (stale.creator != raw.creator).sum() <= raw.M // 1000 + 1
     assert stale.age is raw.age

@@ -127,10 +127,10 @@ def test_the_new_entries_are_in_benchmark_json_without_a_workloads_list():
         assert "workloads" not in m and m["source"] == "program_span"
         assert m["better"] == "lower"
     assert {entries[n]["layer"] for n in NAMES[2:]} <= old_layers
-    # every cell reports both metrics they move
+    # every cell reports the metric they move
     for w in bench["workloads"]:
         e2e = {m["name"] for m in run.metrics_for(bench, "end_to_end", w["name"])}
-        assert {"qps", "latency_p50_ms"} <= e2e
+        assert {entries[n]["moves"] for n in NAMES} <= e2e
 
 
 # -- one driven run: a served window moves the counters together ------------------------------
@@ -143,10 +143,10 @@ def plugged(tmp_path_factory):
     """Copies of what is there, plus a tiny configuration and the rooted
     mix with 4 sessions: no existing file is edited."""
     root = str(tmp_path_factory.mktemp("bench"))
-    for sub in ("configs", "traffic", "layer_metrics"):
+    for sub in ("configs", "traffic", "layer_metrics", "kinds"):
         shutil.copytree(os.path.join(BENCH_DIR, sub), os.path.join(root, sub))
     with open(os.path.join(root, "configs", "tiny.json"), "w") as f:
-        json.dump({"name": "tiny", "scale": TINY}, f)
+        json.dump({"name": "tiny", "kinds": "snb_arrays", "scale": TINY}, f)
     mix = traffic.load_json("traffic", "rooted_16s")
     mix.update(name="rooted_4s", sessions=4, pool_size=200)
     with open(os.path.join(root, "traffic", "rooted_4s.json"), "w") as f:

@@ -20,11 +20,21 @@ A parameter of a shape is one of
 - ``{"int": [lo, hi]}`` uniform whole numbers, both ends included;
   with ``"lead": v`` the pool's first tuple carries ``v`` (the value of
   the largest answer), see ``draw_pool``
-- ``{"root": "<measure>", "band": [q_lo, q_hi]}`` a person whose measure
-  lies between the two quantiles of the persons' measures; the measures
-  are the methods of ``Measures`` below.
+- ``{"root": "<measure>", "band": [q_lo, q_hi]}`` a root whose measure
+  lies between the two quantiles of the candidates' measures; the
+  measures are the methods of the configuration's kinds module's
+  ``Measures``. A measure returns one value per candidate, and the
+  candidate's index is the parameter (a person); or it returns
+  ``(values, candidates)``, one row of parameter values per candidate
+  (a pair of persons, curated by their distance), and the parameter's
+  key is the row's names joined by commas (``"person1Id,person2Id"``).
 
 No (statement, parameters) pair is drawn twice while the domain lasts.
+
+``"think_ms"`` is the pause of a session before each send: a number of
+milliseconds (0: none), or ``{"uniform": [lo, hi]}``, drawn anew for
+every request from ``--seed`` and the session's index
+(``loadgen.pauses``), so that a seed's sequence of pauses is fixed.
 """
 
 from __future__ import annotations
@@ -70,15 +80,12 @@ def session_offsets(sessions: int, block_len: int) -> List[int]:
     return [(s * block_len) // sessions % block_len for s in range(sessions)]
 
 
-class Measures:
-    """Per-person counts a root may be curated by, from the reference's
-    arrays (never from the program's)."""
-
-    def __init__(self, ref) -> None:
-        self.ref = ref
-
-    def degree_both(self) -> np.ndarray:
-        return self.ref.degree_both()
+def think_range(spec) -> List[float]:
+    """``[lo, hi]`` milliseconds from a mix's ``think_ms``."""
+    lo, hi = spec["uniform"] if isinstance(spec, dict) else (spec, spec)
+    if not 0.0 <= float(lo) <= float(hi):
+        raise ValueError(f"think_ms must be 0 <= lo <= hi: {spec}")
+    return [float(lo), float(hi)]
 
 
 def band_members(values: np.ndarray, band) -> np.ndarray:
@@ -91,11 +98,12 @@ def band_members(values: np.ndarray, band) -> np.ndarray:
     return np.flatnonzero((values >= lo) & (values <= hi))
 
 
-def draw_pool(shape: dict, ref, seed: int, want: int) -> Dict:
+def draw_pool(shape: dict, measures, seed: int, want: int) -> Dict:
     """Up to ``want`` distinct parameter tuples for one shape, as
-    ``{"names": [...], "rows": [[...], ...]}``. Seeded by ``seed`` and
-    the shape's name, so that adding a shape to a mix moves no other
-    shape's parameters.
+    ``{"names": [...], "rows": [[...], ...]}``. ``measures`` is the kinds
+    module's ``Measures`` over the reference. Seeded by ``seed`` and the
+    shape's name, so that adding a shape to a mix moves no other shape's
+    parameters.
 
     The first tuple is the one warm-up records the shape's plan with, and
     a plan keeps the buffer sizes of the answer it was recorded on
@@ -106,11 +114,9 @@ def draw_pool(shape: dict, ref, seed: int, want: int) -> Dict:
     rng = np.random.default_rng(
         [int(seed) & 0xFFFFFFFF, int(seed) >> 32, zlib.crc32(shape["name"].encode())]
     )
-    measures = Measures(ref)
-    names = list(shape["params"])
+    names = [n for key in shape["params"] for n in key.split(",")]
     cols = []
-    for name in names:
-        spec = shape["params"][name]
+    for name, spec in shape["params"].items():
         if "const" in spec:
             cols.append(np.full(want, int(spec["const"]), np.int64))
         elif "int" in spec:
@@ -123,7 +129,9 @@ def draw_pool(shape: dict, ref, seed: int, want: int) -> Dict:
             measure = getattr(measures, spec["root"], None)
             if measure is None or spec["root"].startswith("_"):
                 raise KeyError(f"no root measure {spec['root']!r}")
-            values = measure()
+            values, candidates = measure(), None
+            if isinstance(values, tuple):
+                values, candidates = values
             members = band_members(values, spec["band"])
             if members.size == 0:
                 raise ValueError(f"{shape['name']}: empty band {spec}")
@@ -132,7 +140,11 @@ def draw_pool(shape: dict, ref, seed: int, want: int) -> Dict:
             top = int(np.argmax(values[picks]))
             picks[[0, top]] = picks[[top, 0]]
             reps = -(-want // picks.size)
-            cols.append(np.tile(picks, reps)[:want])
+            col = np.tile(picks, reps)[:want]
+            if candidates is None:
+                cols.append(col)
+            else:
+                cols.extend(np.asarray(candidates)[col].T)
         else:
             raise ValueError(f"{shape['name']}.{name}: unknown draw {spec}")
     rows = np.stack(cols, axis=1) if cols else np.zeros((want, 0), np.int64)
@@ -142,7 +154,7 @@ def draw_pool(shape: dict, ref, seed: int, want: int) -> Dict:
     return {"names": names, "rows": rows.tolist()}
 
 
-def build_plan(mix: dict, ref, seed: int, pool_size: int) -> dict:
+def build_plan(mix: dict, measures, seed: int, pool_size: int) -> dict:
     """Everything the load generator needs for one cell and seed: the
     statements, the block, each session's offset and each shape's pool
     of parameters."""
@@ -160,7 +172,8 @@ def build_plan(mix: dict, ref, seed: int, pool_size: int) -> dict:
         raise ValueError(f"mix {mix.get('name')}: a pinned shape has no session")
     return {
         "sessions": sessions,
-        "think_ms": float(mix.get("think_ms", 0)),
+        "seed": int(seed),
+        "think_ms": think_range(mix.get("think_ms", 0)),
         "block": block,
         "offsets": offsets,
         "stride": 1 if walk == "block" else 0,
@@ -177,7 +190,7 @@ def build_plan(mix: dict, ref, seed: int, pool_size: int) -> dict:
                 "columns": s["columns"],
                 "ordered": bool(s.get("ordered", False)),
                 "reference": s["reference"],
-                "pool": draw_pool(s, ref, seed, pool_size),
+                "pool": draw_pool(s, measures, seed, pool_size),
             }
             for s in shapes
         ],
