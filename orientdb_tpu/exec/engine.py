@@ -487,6 +487,7 @@ def _execute_query_batch(
     if tpu_idx and db.tx is None:
         from orientdb_tpu.exec import tpu_engine
         from orientdb_tpu.exec.devicefault import domain as _fault_domain
+        from orientdb_tpu.utils.metrics import metrics
 
         # per-item quarantine gate: quarantined fingerprints drop to
         # the oracle loop below; "probe" items ride the batch and clear
@@ -507,6 +508,7 @@ def _execute_query_batch(
                 if isinstance(res, tpu_engine.Uncompilable):
                     if strict:
                         raise res
+                    metrics.incr("query.tpu.fallback")
                     log.info("tpu batch fallback to oracle: %s", res)
                 else:
                     out[i] = _result_set(res, "tpu")

@@ -387,6 +387,61 @@ def _eq_conjuncts(e):
             yield e.left, e.right
 
 
+def _path_len_call(e) -> Optional[A.FunctionCall]:
+    """The ``shortestPath(...)`` call of ``shortestPath(...).size() - 1``
+    (a path's rids less one: its number of edges, 0 from a vertex to
+    itself, -1 where there is none), the one form of the function that
+    compiles; None for any other expression."""
+    if not (
+        isinstance(e, A.Binary)
+        and e.op == "-"
+        and isinstance(e.right, A.Literal)
+        and type(e.right.value) is int
+        and e.right.value == 1
+    ):
+        return None
+    m = e.left
+    if not (isinstance(m, A.MethodCall) and m.name.lower() == "size" and not m.args):
+        return None
+    f = m.base
+    if isinstance(f, A.FunctionCall) and f.name.lower() == "shortestpath":
+        return f
+    return None
+
+
+def _calls_function(e, name: str) -> bool:
+    """Whether expression ``e`` calls SQL function ``name`` anywhere."""
+    if isinstance(e, A.FunctionCall) and e.name.lower() == name:
+        return True
+    if not hasattr(e, "__dataclass_fields__"):
+        return False
+    for f in e.__dataclass_fields__:
+        v = getattr(e, f)
+        for x in v if isinstance(v, tuple) else (v,):
+            if isinstance(x, A.Expression) and _calls_function(x, name):
+                return True
+    return False
+
+
+def _bfs_caps(csr) -> Tuple[int, int]:
+    """``(front, chunk)`` of :func:`ops.csr.bfs_pair_len` for one edge
+    class, from the snapshot's own degrees and not from a caller's
+    first pair: an end's neighbour list gets four times the mean
+    undirected degree ``D`` a direction, and a pair's expansion has room
+    for ``D·m`` frontier edges, what the neighbours of a vertex have
+    behind them (``m = Σdeg² / Σdeg``, the mean degree of a neighbour,
+    which the hubs raise), each rounded up to a power of two."""
+    deg = np.diff(csr.indptr_out).astype(np.int64) + np.diff(csr.indptr_in)
+    ends = int(deg.sum())
+    if ends == 0:
+        return K.MIN_BUCKET, K.MIN_BUCKET
+    mean = ends / int(np.count_nonzero(deg))
+    of_a_neighbour = float((deg * deg).sum()) / ends
+    front = min(K.bucket(int(4 * mean), K.MIN_BUCKET), 1 << 12)
+    chunk = min(K.bucket(int(mean * of_a_neighbour), K.MIN_BUCKET), 1 << 20)
+    return front, max(chunk, front)
+
+
 class PlanStep:
     __slots__ = ("kind", "alias", "edge", "reverse", "close")
 
@@ -630,6 +685,10 @@ class TpuMatchSolver:
         self._vertex_scope_cache: Optional[ColumnScope] = None
         self._check_supported()
         self.plan = build_plan(self.pattern, self.interp)
+        #: RETURN items that are a compiled path length: projection
+        #: expression -> (column prefix, from alias, to alias, edge class,
+        #: the kernel's static arguments), see _compile_path_lens
+        self._path_lens = self._compile_path_lens()
         # binding visibility: which (vertex) aliases are bound BEFORE each
         # alias' first bind / each step — this is the scope a
         # binding-referencing WHERE may see (mirrors the oracle, whose
@@ -852,6 +911,126 @@ class TpuMatchSolver:
                 and node.alias not in edge_bind_targets
             ):
                 raise Uncompilable("edge-alias pattern nodes not compiled yet")
+
+    def _compile_path_lens(self) -> Dict:
+        """The statement's ``shortestPath(a, b, 'BOTH', class).size() - 1``
+        RETURN items as device searches between two bound vertex aliases
+        over one concrete edge class, walked both ways. Any other use of
+        the function (the path itself, ``maxDepth``, one direction only,
+        several edge classes or none named, an end that is no vertex
+        alias) is the oracle's."""
+        out: Dict = {}
+        for p in self.stmt.returns:
+            call = _path_len_call(p.expr)
+            if call is None:
+                if _calls_function(p.expr, "shortestpath"):
+                    raise Uncompilable(
+                        "shortestPath compiles as shortestPath(a, b, "
+                        "direction, class).size() - 1 only"
+                    )
+                continue
+            args = call.args
+            if not 2 <= len(args) <= 4:
+                raise Uncompilable("shortestPath with maxDepth/options")
+            ends = []
+            for a in args[:2]:
+                node = (
+                    self.pattern.nodes.get(a.name)
+                    if isinstance(a, A.Identifier)
+                    else None
+                )
+                if node is None or node.is_edge_alias:
+                    raise Uncompilable("shortestPath end is no vertex alias")
+                ends.append(a.name)
+            lits = [a.value if isinstance(a, A.Literal) else a for a in args[2:]]
+            direction = (lits[0] if lits else None) or "BOTH"
+            if not isinstance(direction, str) or direction.upper() != "BOTH":
+                raise Uncompilable("shortestPath compiles for 'BOTH' only")
+            ecls = lits[1] if len(lits) > 1 else None
+            if not isinstance(ecls, str):
+                raise Uncompilable("shortestPath over every edge class")
+            concrete = self.snap.edge_closure.get(ecls.lower(), [])
+            if len(concrete) != 1 or concrete[0] not in self.dg.edges:
+                raise Uncompilable(
+                    f"shortestPath over {len(concrete)} concrete edge classes"
+                )
+            if (
+                self.overlay is not None
+                or self.tier is not None
+                or self.dg.mesh_graph is not None
+            ):
+                raise Uncompilable(
+                    "shortestPath on a delta-maintained, tiered or sharded graph"
+                )
+            front, chunk = _bfs_caps(self.snap.edge_classes[concrete[0]])
+            out[p.expr] = (
+                f"$path{len(out)}",
+                ends[0],
+                ends[1],
+                concrete[0],
+                dict(front=front, chunk=chunk),
+            )
+        return out
+
+    def _apply_path_lens(self, table: Table) -> Table:
+        """One search a live row and compiled path length: the rows are
+        packed to as many pairs as the recording had rows (a pair costs a
+        whole search, so none is spent on padding; a replay with more
+        live rows overflows and records anew), searched under ``vmap``,
+        and what each search returned (``K.BFS_PARTS``) is laid back
+        into the table as int32 columns ``<prefix>.<part>``."""
+        width = table.width
+        pairs = max(table.count, 1)
+        keep = K.compact_indices(table.valid_device[:width].astype(bool), pairs)
+        self.sched.note_flag(table.count_device > pairs)
+        back = jnp.where(keep >= 0, keep, width)
+        for prefix, a, b, ecls, static in self._path_lens.values():
+            dec = self.dg.edges[ecls]
+            graph = (dec.indptr_out, dec.dst, dec.edge_src, dec.indptr_in, dec.src)
+            ends = (
+                K.take_pad(table.cols[a], keep, jnp.int32(-1)),
+                K.take_pad(table.cols[b], keep, jnp.int32(-1)),
+            )
+            if pairs == 1:
+                # the one pair as scalars: the lanes' vmap of the group
+                # replay then searches the lanes' pairs together, and no
+                # axis of one lies under it (the chip pads a
+                # [lanes, 1, E] temporary to eight times its size)
+                found = K.bfs_pair_len(*graph, ends[0][0], ends[1][0], **static)[None]
+            else:
+                found = jax.vmap(
+                    lambda s, t: K.bfs_pair_len(*graph, s, t, **static)
+                )(*ends)
+            for j, part in enumerate(K.BFS_PARTS):
+                table.depth_cols[f"{prefix}.{part}"] = (
+                    jnp.full(width, -1, jnp.int32)
+                    .at[back]
+                    .set(found[:, j], mode="drop")
+                )
+        return table
+
+    def _count_path_lens(self, table: Table) -> None:
+        """The searches' counters, once an answer, from what the device
+        returned with it: ``bfs.queries``, ``bfs.levels``,
+        ``bfs.edges_expanded`` (a dense level reads every edge once a
+        direction), ``bfs.overflow``, ``bfs.unreachable``."""
+        sel = self._live_rows(table)
+        for prefix, _a, _b, ecls, _static in self._path_lens.values():
+            if f"{prefix}.len" not in table.depth_cols:
+                continue  # the recording had no row to search
+            col = {
+                part: np.asarray(table.depth_cols[f"{prefix}.{part}"])[sel]
+                for part in K.BFS_PARTS
+            }
+            a_level = 2 * self.dg.edges[ecls].num_edges
+            metrics.incr("bfs.queries", int(col["len"].size))
+            metrics.incr("bfs.levels", int(col["levels"].sum()))
+            metrics.incr(
+                "bfs.edges_expanded",
+                int(col["edges"].sum()) + a_level * int(col["dense_levels"].sum()),
+            )
+            metrics.incr("bfs.overflow", int(col["overflow"].sum()))
+            metrics.incr("bfs.unreachable", int((col["len"] < 0).sum()))
 
     # -- predicate compilation ---------------------------------------------
 
@@ -1273,6 +1452,8 @@ class TpuMatchSolver:
                 nullcontext()
             ):
                 table = self._apply_not_paths(table)
+        if self._path_lens and not table.empty():
+            table = self._apply_path_lens(table)
         if pushdown and not table.empty():
             return self._apply_count_pushdown(table, pushdown)
         if var_count is not None and not table.empty():
@@ -2363,6 +2544,8 @@ class TpuMatchSolver:
 
     def rows_from_table(self, table: Table, params: Optional[Dict] = None) -> List[Result]:
         params = self.params if params is None else params
+        if self._path_lens:
+            self._count_path_lens(table)
         fast = self._fast_rows(table, params)
         if fast is not None:
             return fast
@@ -2447,6 +2630,13 @@ class TpuMatchSolver:
             if isinstance(e, A.Identifier) and e.name in table.depth_cols:
                 arr = np.asarray(table.depth_cols[e.name])[sel]
                 plans.append((name, arr, arr >= 0, None))
+                continue
+            if self._path_lens and e in self._path_lens:
+                found = table.depth_cols.get(self._path_lens[e][0] + ".len")
+                if found is None:  # the recording had no row to search
+                    return None
+                arr = np.asarray(found)[sel]
+                plans.append((name, arr, np.ones(arr.shape, bool), None))
                 continue
             if (
                 isinstance(e, A.FieldAccess)

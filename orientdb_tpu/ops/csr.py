@@ -25,7 +25,7 @@ only; the compiled program is the same.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -436,3 +436,277 @@ def rows_with_matches(rows: jnp.ndarray, mask: jnp.ndarray, num_segments: int):
     )
 
 
+
+#: what one pair search returns, in the order of its int32 result
+BFS_PARTS = ("len", "levels", "edges", "dense_levels", "overflow")
+#: iterations that read one pair's ``chunk`` of frontier edges: a batch's
+#: slot space is read a quarter of a pair's buffer at a time, so what a
+#: batch costs follows the edges its pairs hold to within that quarter
+#: and not the number of pairs that happened to ride together
+BFS_STEPS_A_CHUNK = 4
+
+
+def _bfs_pairs(
+    indptr_out, dst, edge_src, indptr_in, src, s, t, *, front: int, chunk: int
+):
+    """:func:`bfs_pair_len` for ``B`` pairs at once (``s``, ``t``:
+    ``int32[B]``; returns ``int32[B, 5]``). The pairs share one slot
+    space: an expansion lays the frontier edges of every pair that still
+    needs it end to end and reads them ``step`` slots an iteration
+    (``chunk / BFS_STEPS_A_CHUNK``), so a batch costs what its pairs'
+    frontiers hold together: nothing for a pair already settled, nothing
+    for a lane that only pads the batch."""
+    i32 = jnp.int32
+    B = s.shape[0]
+    V = indptr_out.shape[0] - 1
+    E = dst.shape[0]
+    step = max(chunk // BFS_STEPS_A_CHUNK, 1)
+    s, t = s.astype(i32), t.astype(i32)
+    ok = (s >= 0) & (t >= 0) & (s < V) & (t < V)
+    same = ok & (s == t)
+    if E == 0 or V <= 0:
+        z = jnp.zeros(B, i32)
+        return jnp.stack([jnp.where(same, 0, -1).astype(i32), z, z, z, z], 1)
+    ends = jnp.clip(jnp.stack([s, t]), 0, V - 1)
+    # one neighbour array for both CSRs: slot e is dst[e], slot E + e src[e]
+    nbr_both = jnp.concatenate([dst, src])
+    csrs = ((indptr_out, 0), (indptr_in, E))
+    F = 2 * front  # one side's list: out-neighbours, then in-neighbours
+    slot = jnp.arange(front, dtype=i32)
+    lane_base = jnp.arange(B, dtype=i32) * V  # a pair's bitmap in [B * V]
+
+    # bitmaps are built by int32 scatter-adds: the TPU compiler takes half a
+    # minute over a bool scatter of this size and a second over an add
+    def marked(idx):
+        at = jnp.where(idx >= 0, lane_base[:, None] + idx, B * V)
+        return jnp.zeros(B * V, i32).at[at.reshape(-1)].add(1, mode="drop") > 0
+
+    def looked_up(bitmap, lane, idx):
+        at = jnp.clip(lane, 0, B - 1) * V + jnp.clip(idx, 0, V - 1)
+        return jnp.take(bitmap, at) & (idx >= 0)
+
+    lists, n1, over, counts, starts = [], [], jnp.zeros(B, bool), [], []
+    for k in (0, 1):
+        v = ends[k]
+        parts, n = [], jnp.zeros(B, i32)
+        for ip, base in csrs:
+            lo = jnp.take(ip, v)
+            deg = jnp.take(ip, v + 1) - lo
+            at = jnp.clip(base + lo[:, None] + slot[None, :], 0, 2 * E - 1)
+            got = jnp.take(nbr_both, at)
+            parts.append(jnp.where(slot[None, :] < deg[:, None], got, -1))
+            n, over = n + deg, over | (deg > front)
+        lst = jnp.concatenate(parts, axis=1)  # [B, F]
+        lists.append(lst)
+        n1.append(n)
+        # the list's own edges, as 2 F virtual sources: every member's
+        # out-slice, then every member's in-slice
+        c = jnp.clip(lst, 0, V - 1)
+        cnt, sta = [], []
+        for ip, base in csrs:
+            lo = jnp.take(ip, c)
+            deg = jnp.take(ip, c + 1) - lo
+            cnt.append(jnp.where(lst >= 0, deg, 0))
+            sta.append(base + lo)
+        counts.append(jnp.concatenate(cnt, axis=1))  # [B, 2 F]
+        starts.append(jnp.concatenate(sta, axis=1))
+    total = jnp.stack([c.sum(axis=1) for c in counts])  # [2, B]
+    lane_of = jnp.arange(B, dtype=i32)
+    vis = [
+        marked(jnp.concatenate([lists[k], ends[k][:, None]], axis=1))
+        for k in (0, 1)
+    ]
+    d1 = jnp.any(lists[0] == t[:, None], axis=1)
+    d2 = jnp.any(looked_up(vis[0], lane_of[:, None], lists[1]), axis=1)
+    cut = (n1[0] == 0) | (n1[1] == 0)  # an end nothing leads from, or to
+    x = total[1] < total[0]  # the side with fewer edges behind its list
+
+    def expansion(active, side_is_1, visit):
+        """Read the frontier edges of the ``active`` pairs' lists
+        (side 1's where ``side_is_1``), ``step`` slots an iteration;
+        ``visit(state, lane, neighbour, first, last)`` folds one
+        iteration in, ``first`` / ``last`` being each pair's slots in
+        it."""
+        cnt = jnp.where(side_is_1[:, None], counts[1], counts[0])
+        cnt = jnp.where(active[:, None], cnt, 0).reshape(-1)
+        sta = jnp.where(side_is_1[:, None], starts[1], starts[0]).reshape(-1)
+        off = exclusive_cumsum(cnt)
+        smo = sta - off
+        whole = jnp.sum(cnt)
+        lane_lo = off[:: 2 * F]
+        lane_hi = lane_lo + cnt.reshape(B, -1).sum(axis=1)
+
+        def cond(st):
+            return st[0] * step < whole
+
+        def body(st):
+            c, state = st
+            base = c * step
+            rel = off - base
+            at = jnp.where((rel > 0) & (rel < step), rel, step)
+            begun = jnp.zeros(step, i32).at[at].add(1, mode="drop")
+            row = jnp.sum(off <= base) - 1 + value_cumsum(begun)
+            row = jnp.clip(row, 0, 2 * F * B - 1)
+            pos = base + jnp.arange(step, dtype=i32)
+            ep = jnp.take(smo, row) + pos
+            nbr = jnp.take(nbr_both, jnp.clip(ep, 0, 2 * E - 1))
+            nbr = jnp.where(pos < whole, nbr, -1)
+            first = jnp.clip(lane_lo - base, 0, step)
+            last = jnp.clip(lane_hi - base, 0, step)
+            return c + 1, visit(state, row // (2 * F), nbr, first, last)
+
+        return lambda state: jax.lax.while_loop(cond, body, (i32(0), state))[1]
+
+    def meets(bitmap):
+        """Per pair: does a neighbour this iteration read lie in the
+        pair's ``bitmap``? The slots are in pair order, so a pair's hits
+        are a difference of prefix sums."""
+
+        def visit(found, lane, nbr, first, last):
+            hit = looked_up(bitmap, lane, nbr).astype(i32)
+            upto = jnp.concatenate([jnp.zeros(1, i32), value_cumsum(hit)])
+            return found | (jnp.take(upto, last) > jnp.take(upto, first))
+
+        return visit
+
+    def marks(seen, lane, nbr, first, last):
+        at = jnp.where(nbr >= 0, lane * V + nbr, B * V)
+        return seen.at[at].add(1, mode="drop")
+
+    none = jnp.zeros(B, bool)
+    go3 = ok & ~same & ~over & ~cut & ~d1 & ~d2
+    vis_y = jnp.where(
+        jnp.repeat(x, V), vis[0], vis[1]
+    )  # the bitmap of the side that is not expanded
+    d3 = expansion(go3, x, meets(vis_y))(none)
+    go4 = go3 & ~d3
+    second = expansion(go4, x, marks)(jnp.zeros(B * V, i32))
+    d4 = expansion(go4, ~x, meets(second > 0))(none)
+
+    # the rest: whole-graph levels from s
+    dense = ok & ~same & ~cut & (over | (go4 & ~d4))
+
+    def hop(f):
+        """One level over every edge, as :func:`bitmap_hop` walks it:
+        a vertex is reached as often as frontier edges end there."""
+        out = jnp.zeros(V, i32)
+        out = out.at[dst].add(jnp.take(f, edge_src).astype(i32))
+        out = out.at[edge_src].add(jnp.take(f, dst).astype(i32))
+        return out > 0
+
+    def levels_from(needed, a, b):
+        def cond(st):
+            f, _, _, found = st
+            return needed & ~found & jnp.any(f)
+
+        def body(st):
+            f, seen, k, _ = st
+            nxt = hop(f) & ~seen
+            return nxt, seen | nxt, k + 1, jnp.take(nxt, b)
+
+        start = jnp.zeros(V, bool).at[a].set(True)
+        _, _, k, reached = jax.lax.while_loop(
+            cond, body, (start, start, i32(0), jnp.zeros((), bool))
+        )
+        return k, reached
+
+    k, reached = jax.vmap(levels_from)(dense, ends[0], ends[1])
+    sparse = jnp.select([d1, d2, d3, d4], [1, 2, 3, 4], -1)
+    length = jnp.where(dense, jnp.where(reached, k, -1), sparse)
+    length = jnp.where(same, 0, jnp.where(ok & ~cut, length, -1))
+    walked = ok & ~same
+    tot_x = jnp.where(x, total[1], total[0])
+    tot_y = jnp.where(x, total[0], total[1])
+    levels = jnp.where(walked, 2 + go3.astype(i32) + go4.astype(i32) + k, 0)
+    read = n1[0] + n1[1] + jnp.where(go3, tot_x, 0) + jnp.where(go4, tot_x + tot_y, 0)
+    outgrew = walked & (
+        over | (go3 & (tot_x > chunk)) | (go4 & (tot_y > chunk))
+    )
+    return jnp.stack(
+        [
+            length.astype(i32),
+            levels,
+            jnp.where(walked, read, 0),
+            k,
+            outgrew.astype(i32),
+        ],
+        axis=1,
+    )
+
+
+@lru_cache(maxsize=None)
+def _pair_search(front: int, chunk: int):
+    """The search of one pair whose ``vmap`` is the search of a batch:
+    under ``vmap`` (the lanes of a group replay) the pairs of all lanes
+    go through :func:`_bfs_pairs` together and share its slot space,
+    where batching the one-pair program would give every lane, the
+    padding lanes too, buffers of its own."""
+    kw = dict(front=front, chunk=chunk)
+
+    def plain(ipo, dst, edge_src, ipi, src, s, t):
+        return _bfs_pairs(ipo, dst, edge_src, ipi, src, s[None], t[None], **kw)[0]
+
+    one = jax.custom_batching.custom_vmap(plain)
+
+    @one.def_vmap
+    def many(axis_size, in_batched, *args):
+        if axis_size * (args[0].shape[-1] - 1) >= 1 << 31:
+            # the lanes' bitmaps past int32's reach: search lane by lane
+            axes = [0 if b else None for b in in_batched]
+            return jax.vmap(plain, axes)(*args), True
+        s, t = (
+            a if b else jnp.broadcast_to(a, (axis_size,))
+            for a, b in zip(args[5:], in_batched[5:])
+        )
+        return _bfs_pairs(*args[:5], s, t, **kw), True
+
+    return one
+
+
+@partial(jax.jit, static_argnames=("front", "chunk"))
+@jax.named_scope("csr.bfs_pair_len")
+def bfs_pair_len(
+    indptr_out: jnp.ndarray,
+    dst: jnp.ndarray,
+    edge_src: jnp.ndarray,
+    indptr_in: jnp.ndarray,
+    src: jnp.ndarray,
+    s: jnp.ndarray,
+    t: jnp.ndarray,
+    *,
+    front: int,
+    chunk: int,
+) -> jnp.ndarray:
+    """Length of the shortest path from vertex ``s`` to vertex ``t`` over
+    one edge class walked both ways (``BOTH``), as ``int32[5]`` in the order
+    of :data:`BFS_PARTS`: the length (0 for ``s == t``, -1 where there is
+    no path or an end is -1), the frontiers expanded, the frontier edges
+    the sparse levels read, the dense levels run, and whether a frontier
+    outgrew its buffer. One pair; under ``vmap`` the pairs of a batch
+    are searched together (:func:`_pair_search`).
+
+    A level-synchronous search from both ends whose loops end on the
+    device. Its cost follows the frontier, not the graph:
+
+    - both ends' neighbour lists are read as lists (``front`` slots a
+      direction) and marked in one bitmap a side: lengths 1 and 2;
+    - the side whose list has fewer edges behind it is expanded, a
+      quarter of ``chunk`` frontier edges an iteration (count → scan →
+      rank → gather, as :func:`gather_expand`, from a slot offset), and
+      each neighbour is looked up in the other side's bitmap: length 3.
+      A frontier with more than ``chunk`` edges is counted as outgrown
+      and takes more iterations, never a wrong answer;
+    - where that finds nothing, the same expansion marks the first
+      side's second level and the other side's list is expanded against
+      it: length 4;
+    - what is left (five steps or more, no path, an end with more than
+      ``front`` neighbours a direction) runs whole-graph levels from
+      ``s``, a gather and a scatter over every edge a direction, until
+      ``t`` is reached or the frontier is empty.
+
+    Every stage is a ``lax.while_loop`` over the work that is left (the
+    slots of the pairs that still need the stage; "this pair still needs
+    a dense level"), so a batch pays for a stage only if one of its
+    pairs does: a ``lax.cond`` under ``vmap`` would run both arms for
+    every lane."""
+    return _pair_search(front, chunk)(indptr_out, dst, edge_src, indptr_in, src, s, t)

@@ -127,3 +127,39 @@ def social_db():
     db.new_edge("Likes", vs["bob"], vs["eve"], weight=1)
     db._test_vertices = vs  # convenience for assertions
     return db
+
+
+# -- the benchmark's own tests (benchmark/tests) -----------------------------
+# They live beside the benchmark, where a PR that adds a deployment adds
+# its test as a new file; tier-1 collects ``tests/`` alone. The shim
+# ``tests/test_benchmark_suite.py`` stands for them: where it is
+# collected, every ``benchmark/tests/test_*.py`` is collected with it,
+# under the shim's own node id, so that ``--dist loadfile`` gives them to
+# ONE worker: their traced runs share ``.bench_trace/``, and two at once
+# delete each other's trace. (Importing their tests into the shim's
+# namespace would not do: two of the files define a fixture of one name.)
+
+
+class _BenchmarkTests(pytest.File):
+    def collect(self):
+        from tests.test_benchmark_suite import benchmark_test_files
+
+        for path in benchmark_test_files():
+            yield pytest.Module.from_parent(
+                self, path=path, nodeid=f"{self.nodeid}::{path.name}"
+            )
+
+
+def pytest_collect_file(file_path, parent):
+    if file_path.name == "test_benchmark_suite.py":
+        return _BenchmarkTests.from_parent(parent, path=file_path)
+    return None
+
+
+def pytest_collection_modifyitems(config, items):
+    from tests.test_benchmark_suite import STALE_ASSUMPTIONS
+
+    for item in items:
+        why = STALE_ASSUMPTIONS.get(item.nodeid)
+        if why is not None:
+            item.add_marker(pytest.mark.xfail(reason=why, strict=True))

@@ -117,6 +117,40 @@ def test_expand_and_compact_at_80m_edges(one_chip, blocked_cumsum):
     )
 
 
+def test_pair_search_on_sf100s_friendship_graph_sixteen_lanes(
+    one_chip, blocked_cumsum
+):
+    """``sf100_ic13_16s``'s program: 448 626 persons, 21 M ``knows``
+    edges, the buffers ``_bfs_caps`` gives that graph, vmapped over a
+    16-lane batch as the group replay does. Its dense level's
+    temporaries are what fills the chip (3.3 GB; an axis of one under
+    the lanes read 13.7 GB, and a bool scatter took the compiler half a
+    minute where an int32 add takes a second)."""
+    persons, edges = 448_626, 21_016_306
+
+    def spec(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    def batch(ipo, dst, es, ipi, src, s, t):
+        return jax.vmap(
+            lambda a, b: csr.bfs_pair_len(
+                ipo, dst, es, ipi, src, a, b, front=512, chunk=1 << 17
+            )
+        )(s, t)
+
+    compiled = _compile(
+        batch,
+        spec((persons + 1,)),
+        spec((edges,)),
+        spec((edges,)),
+        spec((persons + 1,)),
+        spec((edges,)),
+        spec((16,)),
+        spec((16,)),
+    )
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
 @pytest.mark.parametrize("shape", ["two_hop_count", "config5_count"])
 def test_whole_count_plan_at_real_shapes(one_chip, blocked_cumsum, shape):
     """Record the plan on a tiny graph, then lower its whole jitted
