@@ -1790,10 +1790,10 @@ class TpuMatchSolver:
                 # in-direction reorders the out-order edge mask
                 # through the in-CSR's edge-id map first
                 if d == "out":
-                    emit, ip = dec.dst, dec.indptr_out
+                    emit, ip, hull = dec.dst, dec.indptr_out, dec.hull_out
                     em = emask
                 else:
-                    emit, ip = dec.src, dec.indptr_in
+                    emit, ip, hull = dec.src, dec.indptr_in, dec.hull_in
                     em = jnp.take(emask, dec.edge_id_in)
                 if E >= vb:
                     # [vb] mask precompute + one bool gather beats
@@ -1805,7 +1805,7 @@ class TpuMatchSolver:
                 vals = contrib.astype(dtype)
                 if w is not None:
                     vals = vals * K.take_pad(w, emit, dtype(0))
-                new_w = new_w + K.indptr_segment_sum(vals, ip, vb)
+                new_w = new_w + K.indptr_segment_sum(vals, ip, vb, hull)
         return new_w
 
     def _root_candidates(self, alias: str):

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import lru_cache, partial
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -365,10 +366,24 @@ def mask_count(mask: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(mask.astype(jnp.int32))
 
 
-@partial(jax.jit, static_argnames=("out_size",))
+@partial(jax.jit, static_argnames=("out_size", "hull"))
+def _segment_sum(
+    vals: jnp.ndarray, indptr: jnp.ndarray, out_size: int, hull: Tuple[int, int]
+) -> jnp.ndarray:
+    lo, hi = hull
+    tot = jnp.concatenate([jnp.zeros(1, vals.dtype), value_cumsum(vals)])
+    ends = jax.lax.slice(indptr, (lo,), (hi + 1,))
+    seg = jnp.take(tot, ends[1:]) - jnp.take(tot, ends[:-1])
+    seg = jnp.pad(seg, (lo, max(out_size - hi, 0)))
+    return seg[:out_size]
+
+
 @jax.named_scope("csr.indptr_segment_sum")
 def indptr_segment_sum(
-    vals: jnp.ndarray, indptr: jnp.ndarray, out_size: int
+    vals: jnp.ndarray,
+    indptr: jnp.ndarray,
+    out_size: int,
+    hull: Optional[Tuple[int, int]] = None,
 ) -> jnp.ndarray:
     """Segment sums of CSR-ordered values: cumsum + boundary gathers.
 
@@ -380,14 +395,21 @@ def indptr_segment_sum(
     batched scatter. The prefix sum itself runs MXU-blocked
     (:func:`value_cumsum`): at SF100 scale this cumsum over the 80M-row
     edge list was ~2 s/pass of XLA's log-depth reduce-window — the
-    whole r04 two-hop COUNT cliff. Result is zero-padded to the static
-    `out_size`."""
-    tot = jnp.concatenate([jnp.zeros(1, vals.dtype), value_cumsum(vals)])
-    seg = jnp.take(tot, indptr[1:]) - jnp.take(tot, indptr[:-1])
-    pad = out_size - seg.shape[0]
-    if pad > 0:
-        seg = jnp.pad(seg, (0, pad))
-    return seg[:out_size]
+    whole r04 two-hop COUNT cliff.
+
+    ``hull = (lo, hi)`` (static; default: every segment) says that the
+    segments outside ``[lo, hi)`` are empty, as
+    ``ops/device_graph.vertex_hull`` finds it from the pointer array:
+    only the hull's boundaries are gathered (a TPU gather of scalars is
+    serial, 6-7 ns a boundary) and the other sums are the zeros of a
+    static pad. Counted as :func:`count_read` counts, by how the pass
+    lowers: ``plan.segsum.hull`` where the hull is narrower than the
+    pointer array, else ``plan.segsum.full``. Result is zero-padded to
+    the static `out_size`."""
+    full = (0, indptr.shape[0] - 1)
+    hull = full if hull is None else hull
+    metrics.incr("plan.segsum.full" if hull == full else "plan.segsum.hull")
+    return _segment_sum(vals, indptr, out_size, hull)
 
 
 @partial(jax.jit, static_argnames=("vb",))

@@ -17,7 +17,7 @@ constants.
 from __future__ import annotations
 
 import threading
-from typing import Dict, List, Optional, Set
+from typing import Dict, List, Optional, Set, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -90,6 +90,25 @@ class DeviceColumn:
         return self._g.arrays[self._kp]
 
 
+def vertex_hull(indptr: np.ndarray, bounds: np.ndarray) -> Tuple[int, int]:
+    """``[lo, hi)`` holding every vertex with an edge in the CSR whose
+    pointer array is ``indptr``: two binary searches over the monotone
+    array, no pass over V. The pair is snapped OUTWARDS to ``bounds``
+    (sorted; 0 and V are in it), the boundaries of the snapshot's
+    vertex-class ranges: the hull is static in every program that reads
+    it, and a hull that followed one seed's degrees by a vertex would
+    compile anew for the next. No edges: the empty hull ``(0, 0)``."""
+    E = int(indptr[-1])
+    if E == 0:
+        return (0, 0)
+    lo = int(np.searchsorted(indptr, 0, "right")) - 1
+    hi = int(np.searchsorted(indptr, E, "left"))
+    return (
+        int(bounds[np.searchsorted(bounds, lo, "right") - 1]),
+        int(bounds[np.searchsorted(bounds, hi, "left")]),
+    )
+
+
 class DeviceEdgeClass:
     """One edge class's CSR adjacency (both directions) in HBM.
 
@@ -100,12 +119,21 @@ class DeviceEdgeClass:
     columns are row-sharded by edge range on a mesh (O(E/S) per device);
     predicate gathers read them through XLA-inserted collectives."""
 
-    __slots__ = ("class_name", "columns", "non_columnar", "num_edges", "_g", "_p")
+    __slots__ = (
+        "class_name", "columns", "non_columnar", "num_edges", "hull_out",
+        "hull_in", "_g", "_p",
+    )
 
     def __init__(self, csr, g: "DeviceGraph") -> None:
         self.class_name = csr.class_name
         self._g = g
         p = self._p = f"e:{csr.class_name}"
+        #: vertex hulls of the class, by direction: where the sources
+        #: (out) and the targets (in) of its edges lie. A segment sum
+        #: over the class visits these vertices and no others
+        #: (``ops/csr.indptr_segment_sum``)
+        self.hull_out = vertex_hull(csr.indptr_out, g.class_bounds)
+        self.hull_in = vertex_hull(csr.indptr_in, g.class_bounds)
         if g.mesh_graph is None:
             # tiered snapshots (storage/tiering) page the four [E]
             # value arrays between a hot device pool and host-pinned
@@ -280,6 +308,12 @@ class DeviceGraph:
                 "tiered snapshots are single-device; drop the mesh or "
                 "raise tier_hbm_cap_bytes"
             )
+        #: 0, V and every boundary of a concrete vertex class's range
+        #: between them, sorted: what an edge class's hull snaps to
+        self.class_bounds = np.unique(
+            [0, self.num_vertices]
+            + [b for r in snap.class_vertex_range.values() for b in r]
+        )
         self.edges: Dict[str, DeviceEdgeClass] = {
             n: DeviceEdgeClass(c, self) for n, c in snap.edge_classes.items()
         }

@@ -196,6 +196,8 @@ def test_whole_count_plan_at_real_shapes(one_chip, blocked_cumsum, shape):
         dg.num_vertices = dims[v_tiny]
         for dec in dg.edges.values():
             dec.num_edges = dims[dec.num_edges]
+            dec.hull_out = tuple(b * scale for b in dec.hull_out)
+            dec.hull_in = tuple(b * scale for b in dec.hull_in)
         _compile(plan._replay, arrays, dyn)
     finally:
         db.detach_snapshot()

@@ -202,3 +202,228 @@ class TestVarDepthCountPushdown:
         want = sdb.query(q, engine="oracle").to_dicts()
         got = sdb.query(q, engine="tpu", strict=True).to_dicts()
         assert got == want
+
+
+# -- the weight pass's segment sums over an edge class's vertex hull ------------
+
+V_SEG = 40  # vertices of the seeded CSRs below
+
+#: name -> the [lo, hi) that holds every vertex with an edge
+SEG_HULLS = {
+    "at_the_start": (0, 12),
+    "in_the_middle": (13, 29),
+    "at_the_end": (31, V_SEG),
+    "the_whole_universe": (0, V_SEG),
+    "empty": (0, 0),
+}
+
+
+def _seeded_csr(hull, dtype, seed, lanes=None):
+    """A pointer array whose degrees are zero outside ``hull`` (and at
+    some vertices inside it, its two ends never), and values in its
+    order: whole numbers, so a float32 prefix sum is exact."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    lo, hi = hull
+    deg = np.zeros(V_SEG, np.int64)
+    if hi > lo:
+        deg[lo:hi] = rng.integers(0, 5, hi - lo)
+        deg[lo] = deg[hi - 1] = 3
+    indptr = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
+    shape = (int(indptr[-1]),) if lanes is None else (lanes, int(indptr[-1]))
+    return indptr, rng.integers(0, 9, shape).astype(dtype)
+
+
+class TestSegmentSumOverAHull:
+    @pytest.mark.parametrize("lanes", [None, 3], ids=["one", "vmapped"])
+    @pytest.mark.parametrize("dtype", ["int32", "float32"])
+    @pytest.mark.parametrize("name", sorted(SEG_HULLS))
+    def test_the_hull_form_is_the_full_form(self, name, dtype, lanes):
+        import jax
+        import numpy as np
+
+        from orientdb_tpu.ops import csr as K
+        from orientdb_tpu.ops.device_graph import vertex_hull
+
+        hull = SEG_HULLS[name]
+        indptr, vals = _seeded_csr(hull, dtype, seed=len(name), lanes=lanes)
+        # the hull the attach finds is the one the case was built on
+        assert vertex_hull(indptr, np.arange(V_SEG + 1)) == hull
+        for out_size in (64, V_SEG):
+            def seg(h):
+                one = lambda v: K.indptr_segment_sum(v, indptr, out_size, h)
+                return np.asarray((jax.vmap(one) if lanes else one)(vals))
+
+            got, full = seg(hull), seg(None)
+            assert got.dtype == full.dtype == np.dtype(dtype)
+            assert got.shape == full.shape == vals.shape[:-1] + (out_size,)
+            assert np.array_equal(got, full)
+            rows = vals.reshape(lanes or 1, vals.shape[-1])
+            want = np.zeros((rows.shape[0], out_size), dtype)
+            for i in range(V_SEG):
+                want[:, i] = rows[:, indptr[i] : indptr[i + 1]].sum(axis=1)
+            assert np.array_equal(got.reshape(want.shape), want)
+
+    def test_a_hull_snaps_outwards_to_the_class_boundaries(self):
+        import numpy as np
+
+        from orientdb_tpu.ops.device_graph import vertex_hull
+
+        indptr, _ = _seeded_csr((13, 29), "int32", seed=0)
+        bounds = np.asarray([0, 10, 30, V_SEG])
+        assert vertex_hull(indptr, bounds) == (10, 30)
+        assert vertex_hull(indptr, np.asarray([0, 13, 29, V_SEG])) == (13, 29)
+        assert vertex_hull(indptr, np.asarray([0, V_SEG])) == (0, V_SEG)
+        assert vertex_hull(np.zeros(V_SEG + 1, np.int32), bounds) == (0, 0)
+
+
+SNB_COUNTS = {
+    # the four statements of the benchmark's scan_4s mix, each as written
+    # there and from its other end (the weight passes then walk the other
+    # CSR of each edge class)
+    "knows_1hop": (
+        "MATCH {class:Person, as:p, where:(age > :minAge)}-knows->"
+        "{as:f, where:(age < :maxAge)} RETURN count(*) AS n",
+        "MATCH {class:Person, as:f, where:(age < :maxAge)}<-knows-"
+        "{as:p, where:(age > :minAge)} RETURN count(*) AS n",
+    ),
+    "knows_2hop": (
+        "MATCH {class:Person, as:p, where:(age > :minAge)}-knows->{as:f}-knows->"
+        "{as:g, where:(age < :maxAge)} RETURN count(*) AS n",
+        "MATCH {class:Person, as:g, where:(age < :maxAge)}<-knows-{as:f}<-knows-"
+        "{as:p, where:(age > :minAge)} RETURN count(*) AS n",
+    ),
+    "creator_1hop": (
+        "MATCH {class:Message, as:m, where:(length > :minLen)}-hasCreator->"
+        "{as:p, where:(age < :maxAge)} RETURN count(*) AS n",
+        "MATCH {class:Person, as:p, where:(age < :maxAge)}<-hasCreator-"
+        "{as:m, where:(length > :minLen)} RETURN count(*) AS n",
+    ),
+    "config5": (
+        "MATCH {class:Person, as:p, where:(age > 40)}.outE('knows')"
+        "{where:(creationDate > :d)}.inV(){as:f, where:(age < 30)}, "
+        "{class:Message, as:m}-hasCreator->{as:f} RETURN count(*) AS n",
+        "MATCH {class:Message, as:m}-hasCreator->{as:f, where:(age < 30)}, "
+        "{as:f}.inE('knows'){where:(creationDate > :d)}.outV()"
+        "{as:p, where:(age > 40)} RETURN count(*) AS n",
+    ),
+}
+SNB_PARAMS = {"minAge": 40, "maxAge": 30, "minLen": 900, "d": 14_000}
+
+
+def _segsums():
+    """(passes over a hull, passes over the universe) lowered so far."""
+    from orientdb_tpu.utils.metrics import metrics
+
+    c = metrics.snapshot()["counters"]
+    return c.get("plan.segsum.hull", 0), c.get("plan.segsum.full", 0)
+
+
+@pytest.fixture(scope="module")
+def snb_counts():
+    import numpy as np
+
+    from orientdb_tpu.exec.tpu_engine import drain_warmups
+    from orientdb_tpu.storage import bigshape as B
+
+    db, snap = B.build_snb_shape(400, msgs_per_person=3, avg_knows=5, seed=11)
+    age = snap.v_columns["age"]
+    old = (age.values > 40) & age.present
+    young = (age.values < 30) & age.present
+    hc = snap.edge_classes["hasCreator"]
+    long_msg = snap.v_columns["length"].values > 900  # 0 on a person
+    want = {
+        "knows_1hop": B.numpy_1hop_count(snap, old, young),
+        "knows_2hop": B.numpy_2hop_count(snap, old, age.present, young),
+        # every message has the one edge, so hasCreator's dst lies in
+        # message order
+        "creator_1hop": int((long_msg[400:] & young[hc.dst]).sum()),
+        "config5": B.numpy_config5_count(snap, 14_000),
+    }
+    yield db, snap, want
+    drain_warmups()
+    db.detach_snapshot()
+
+
+class TestSnbCountsOverHulls:
+    def test_the_hulls_are_the_classes_of_the_layout(self, snb_counts):
+        from orientdb_tpu.ops.device_graph import device_graph
+
+        _db, snap, _want = snb_counts
+        edges = device_graph(snap).edges
+        P, V = 400, 400 * 4
+        assert (edges["knows"].hull_out, edges["knows"].hull_in) == ((0, P), (0, P))
+        assert edges["hasCreator"].hull_out == (P, V)
+        assert edges["hasCreator"].hull_in == (0, P)
+
+    @pytest.mark.parametrize("end", [0, 1], ids=["as_written", "from_the_other_end"])
+    @pytest.mark.parametrize("shape", sorted(SNB_COUNTS))
+    def test_a_scan_count_is_the_numpy_count(self, snb_counts, shape, end):
+        db, _snap, want = snb_counts
+        assert want[shape] > 0, "a count of nothing tests nothing"
+        hull0, full0 = _segsums()
+        got = db.query(
+            SNB_COUNTS[shape][end], SNB_PARAMS, engine="tpu", strict=True
+        ).to_dicts()
+        hull1, full1 = _segsums()
+        assert got == [{"n": want[shape]}]
+        # the pushdown answered, and every pass of it ran over a class's
+        # hull: no edge class of this layout spans the universe
+        assert hull1 > hull0 and full1 == full0
+
+
+class TestAHullUnderDeltas:
+    """A hull is read from the pointer array at attach. Writes land in
+    the slab, outside it: the pushdown steps aside while the topology is
+    dirty, and a compacted snapshot is a new device graph with hulls of
+    its own. A recorded hull is never replayed over other arrays."""
+
+    MSGS = "MATCH {class:Msg, as:m}-Wrote->{as:p, where:(age < :a)} RETURN count(*) AS n"
+    BACK = "MATCH {class:Writer, as:p, where:(age < :a)}<-Wrote-{as:m} RETURN count(*) AS n"
+
+    @pytest.mark.parametrize("sql", ["MSGS", "BACK"])
+    def test_a_count_after_a_write_is_the_oracles(self, sql):
+        from orientdb_tpu.exec.tpu_engine import drain_warmups
+        from orientdb_tpu.ops.device_graph import device_graph
+        from orientdb_tpu.storage.deltas import arm_delta_maintenance
+
+        hulls_counted = lambda: _segsums()[0]
+        db = Database(f"hull_deltas_{sql.lower()}")
+        db.schema.create_vertex_class("Writer")
+        db.schema.create_vertex_class("Msg")
+        db.schema.create_edge_class("Wrote")
+        writers = [db.new_vertex("Writer", age=20 + i) for i in range(6)]
+        for i in range(10):
+            db.new_edge("Wrote", db.new_vertex("Msg", length=i), writers[i % 6])
+        m = arm_delta_maintenance(db, spare_vertices=16, spare_edges=16)
+        q, a = getattr(self, sql), {"a": 24}
+        ask = lambda engine: db.query(
+            q, a, engine=engine, strict=(engine == "tpu")
+        ).to_dicts()
+        try:
+            snap = db.current_snapshot(require_fresh=True)
+            dec = device_graph(snap).edges["Wrote"]
+            # the two classes lie one after the other, the slab behind both
+            assert {dec.hull_out, dec.hull_in} == {(0, 6), (6, 16)}
+            assert snap.num_vertices == 32
+            before = hulls_counted()
+            assert ask("tpu") == ask("oracle") == [{"n": 8}]
+            assert hulls_counted() > before, "the pushdown did not answer"
+            # a message and its edge land in the slab, outside every hull
+            db.new_edge("Wrote", db.new_vertex("Msg", length=99), writers[1])
+            before = hulls_counted()
+            assert ask("tpu") == ask("oracle") == [{"n": 9}]
+            assert hulls_counted() == before, "a dirty topology took the pushdown"
+            # compaction folds the slab in: new arrays, new hulls, a new plan
+            m.compact("the hulls' test")
+            snap2 = db.current_snapshot(require_fresh=True)
+            dec2 = device_graph(snap2).edges["Wrote"]
+            assert snap2 is not snap
+            assert {dec2.hull_out, dec2.hull_in} == {(0, 6), (6, 17)}
+            before = hulls_counted()
+            assert ask("tpu") == ask("oracle") == [{"n": 9}]
+            assert hulls_counted() > before
+        finally:
+            drain_warmups()
+            db.detach_snapshot()

@@ -4,7 +4,10 @@ Parity of every reader over a range against the same reader over the
 ``int32`` array the range stands for; the lowering of the benchmark's
 rooted statements (no gather as long as the root's hull bucket is left
 in the replay's jaxpr); and the ``plan.read.range`` / ``plan.read.gather``
-counters. No chip and no time in any of it.
+counters. Beside them, the COUNT pushdown's segment sums over an edge
+class's vertex hull (``ops/device_graph.vertex_hull``): what their
+replays gather, what ``plan.segsum.hull`` / ``plan.segsum.full`` count,
+and that a seed does not move a hull. No chip and no time in any of it.
 """
 
 import numpy as np
@@ -333,6 +336,117 @@ class TestLowering:
         finally:
             drain_warmups()
             db.detach_snapshot()
+
+
+# -- the COUNT pushdown's segment sums run over the edge class's vertex hull ------
+
+KNOWS_1HOP = (
+    "MATCH {class:Person, as:p, where:(age > :minAge)}-knows->"
+    "{as:f, where:(age < :maxAge)} RETURN count(*) AS n"
+)
+KNOWS_2HOP = (
+    "MATCH {class:Person, as:p, where:(age > :minAge)}-knows->{as:f}-knows->"
+    "{as:g, where:(age < :maxAge)} RETURN count(*) AS n"
+)
+#: statement, its weight passes (one a hop)
+SEGSUMS = {"knows_1hop": (KNOWS_1HOP, 1), "knows_2hop": (KNOWS_2HOP, 2)}
+AGES = {"minAge": 40, "maxAge": 30}
+
+
+def _segsums():
+    c = metrics.snapshot()["counters"]
+    return c.get("plan.segsum.hull", 0), c.get("plan.segsum.full", 0)
+
+
+def _segment_sum_gathers(jaxpr):
+    """The index lengths of the gathers of each ``csr._segment_sum`` call."""
+    return [
+        _gather_index_lengths(e.params["jaxpr"].jaxpr)
+        for e in _eqns(jaxpr)
+        if e.primitive.name in ("pjit", "jit") and e.params.get("name") == "_segment_sum"
+    ]
+
+
+class TestSegmentSumsOverTheHull:
+    @pytest.mark.parametrize("shape", sorted(SEGSUMS))
+    def test_a_knows_count_gathers_no_boundary_past_the_persons(self, snb, shape):
+        db, snap = snb
+        sql, passes = SEGSUMS[shape]
+        hull0, full0 = _segsums()
+        rows, plan, _reads_counted = _record(db, snap, sql, AGES)
+        assert rows[0]["n"] > 0
+        lo, hi = snap.vertex_hull("Person")
+        V = snap.num_vertices
+        assert (lo, hi) == (0, 3000) and V == 12000
+        jaxpr = jax.make_jaxpr(plan._replay)(
+            plan._arg_subset(), plan._dyn_args(AGES)
+        ).jaxpr
+        hull1, full1 = _segsums()
+        calls = _segment_sum_gathers(jaxpr)
+        # two boundary gathers a pass, each as long as the person hull
+        assert calls == [[hi - lo, hi - lo]] * passes, calls
+        # and nothing in the whole replay is as wide as the vertex universe
+        # (or its bucket): what is left are the [E] gathers over knows and
+        # the root's candidates
+        lengths = _gather_index_lengths(jaxpr)
+        E = snap.edge_classes["knows"].num_edges
+        assert E > K.bucket(V), "an [E] gather must be told apart from a [V] one"
+        assert not [n for n in lengths if V <= n < E], sorted(set(lengths))
+        # counted where Python lowers the pass: the eager recording (with
+        # its float32 twin) and this trace, every one over a hull
+        assert hull1 - hull0 >= 3 * passes and full1 == full0
+
+    def test_a_class_that_spans_the_universe_counts_as_full(self):
+        """A persons-only graph: the hull is the universe, and the pass is
+        the program it was before hulls."""
+        from orientdb_tpu.exec.tpu_engine import drain_warmups
+        from orientdb_tpu.ops.device_graph import device_graph
+        from orientdb_tpu.storage.bigshape import build_person_knows
+
+        db, snap = build_person_knows(2000, avg_knows=6, seed=4)
+        try:
+            dec = device_graph(snap).edges["knows"]
+            assert dec.hull_out == dec.hull_in == (0, 2000)
+            hull0, full0 = _segsums()
+            _rows, plan, _counted = _record(db, snap, KNOWS_1HOP, AGES)
+            jaxpr = jax.make_jaxpr(plan._replay)(
+                plan._arg_subset(), plan._dyn_args(AGES)
+            ).jaxpr
+            hull1, full1 = _segsums()
+            assert _segment_sum_gathers(jaxpr) == [[2000, 2000]]
+            assert full1 - full0 >= 3 and hull1 == hull0
+        finally:
+            drain_warmups()
+            db.detach_snapshot()
+
+    def test_two_seeds_of_one_scale_have_the_same_hulls(self):
+        """A seed deals the same degrees in another order (as the
+        benchmark's seeds do): the vertices with an edge move, the hull
+        does not, and one compiled program serves both."""
+        from orientdb_tpu.ops.device_graph import vertex_hull
+
+        P, V = 300, 1000
+        rng = np.random.default_rng(7)
+        deg = rng.poisson(1.0, P)
+        deg[0], deg[P - 1] = 0, 2
+        graphs = []
+        for order in (np.arange(P), np.arange(P)[::-1]):
+            full = np.zeros(V, np.int64)
+            full[:P] = deg[order]
+            indptr = np.concatenate([[0], np.cumsum(full)]).astype(np.int32)
+            graphs.append(
+                (indptr, rng.integers(0, 5, int(indptr[-1])).astype(np.int32))
+            )
+        exact = [vertex_hull(ip, np.arange(V + 1)) for ip, _ in graphs]
+        assert exact[0] != exact[1]
+        snapped = [vertex_hull(ip, np.asarray([0, P, V])) for ip, _ in graphs]
+        assert snapped == [(0, P), (0, P)]
+        want = [K.indptr_segment_sum(vals, ip, 1024) for ip, vals in graphs]
+        for hulls, programs in ((snapped, 1), (exact, 2)):
+            compiled = K._segment_sum._cache_size()
+            for (indptr, vals), hull, full in zip(graphs, hulls, want):
+                _same(K.indptr_segment_sum(vals, indptr, 1024, hull), full)
+            assert K._segment_sum._cache_size() - compiled == programs
 
 
 # -- who materialises: a mesh-sharded graph --------------------------------------
