@@ -3,7 +3,7 @@
 
 Exit status 0 when every pass is clean (no unsuppressed findings),
 1 otherwise — the same gate ``tests/test_analysis.py`` enforces
-tier-1 and ``bench.py`` records into its evidence stream.
+tier-1.
 
 ``--baseline PATH`` is the adopt-in-a-dirty-tree mode CI wants: the
 first run snapshots the current findings to PATH (exit 0 even when

@@ -1,6 +1,6 @@
 """The AST-walking framework every analysis pass shares.
 
-One discovery walk (the package tree plus ``bench.py``), one parse per
+One discovery walk (the package tree), one parse per
 module, one suppression syntax, one report shape — a new invariant
 check is a ~50-line registered function instead of another bespoke
 walker with its own discovery and its own test plumbing.
@@ -119,7 +119,7 @@ class SourceTree:
 
     @classmethod
     def from_repo(cls, root: Optional[str] = None) -> "SourceTree":
-        """Every ``.py`` under ``orientdb_tpu/`` plus ``bench.py``."""
+        """Every ``.py`` under ``orientdb_tpu/``."""
         if root is None:
             root = repo_root()
         files: List[str] = []
@@ -129,9 +129,6 @@ class SourceTree:
             for f in sorted(names):
                 if f.endswith(".py"):
                     files.append(os.path.join(dirpath, f))
-        bench = os.path.join(root, "bench.py")
-        if os.path.exists(bench):
-            files.append(bench)
         mods = []
         for path in files:
             rel = os.path.relpath(path, root).replace(os.sep, "/")

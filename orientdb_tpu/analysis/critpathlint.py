@@ -3,8 +3,8 @@
 The critical-path attribution plane (``obs/critpath.py``) aggregates,
 renders, and documents decompositions by SEGMENT NAME: the
 ``GET /stats/critpath`` report, the blame annotation on
-``latency_regression`` alerts, the README segment-catalog table, and
-the bench's per-segment perfdiff leaves all join on it. A
+``latency_regression`` alerts and the README segment-catalog table
+all join on it. A
 ``segment("marshall")`` typo would silently grow a segment no surface
 documents and leave the cataloged name an empty column in every
 breakdown — the exact failure mode spanlint/alertlint close for span

@@ -9,7 +9,7 @@ watches the TPU suites live:
   ``jax.transfer_guard`` so an **implicit host↔device transfer** on a
   serving path fails the test that performed it — a silent round
   trip stalls the dispatch pipeline on every occurrence, and the
-  PR 4 profiling counters only show it after a bench round;
+  PR 4 profiling counters only show it after the fact;
 - the plan-compile entry point (``tpu_engine._record``) is wrapped:
   recording the SAME statement+parameters twice against the same
   snapshot within one test is a **same-shape re-record** — the plan
@@ -26,8 +26,7 @@ watches the TPU suites live:
 - at session end the observed violation sites are **cross-checked
   against jaxlint's static findings**: an observed-but-unflagged site
   is a jaxlint gap and is reported (the sanitizer↔locklint
-  convention), and the summary is dumped to ``DEVICEGUARD.json`` for
-  ``bench.py``'s static_analysis evidence record.
+  convention), and the summary is dumped to ``DEVICEGUARD.json``.
 
 ``ORIENTTPU_DEVICEGUARD`` tunes the guard: ``disallow`` (default),
 ``log`` (warn, never fail — first runs on a new backend), ``0``/``off``

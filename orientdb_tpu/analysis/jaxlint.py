@@ -5,7 +5,7 @@ boundary, and nothing static watched it: a host materialization inside
 a jitted replay stalls the device pipeline every dispatch, an impure
 call bakes a trace-time value into the executable forever, and an
 un-memoized ``jax.jit(...)`` in method scope recompiles on every call
-— all silent until a bench round regresses. This pass discovers the
+— all silent until a measured run regresses. This pass discovers the
 **traced regions** (functions decorated ``@jax.jit`` /
 ``@partial(jax.jit, ...)``, functions and lambdas passed to
 ``jax.jit`` / ``jax.vmap`` / ``shard_map``, plus their same-module

@@ -20,8 +20,8 @@ tracks the acquisition stack:
 - at session end the dynamic edges are **cross-checked against
   locklint's static graph**: a dynamic edge the static pass missed is
   a locklint gap and is reported (never silently tolerated), and the
-  edge set is dumped to ``SANITIZER_EDGES.json`` so ``bench.py`` can
-  record the dynamic-vs-static coverage ratio as round evidence.
+  edge set is dumped to ``SANITIZER_EDGES.json`` with the
+  dynamic-vs-static coverage ratio.
 
 Lock identity mirrors locklint's node ids: the construction site's
 source line names the attribute (``self._lock = threading.Lock()`` in
@@ -374,8 +374,8 @@ class LockOrderSanitizer:
         return out
 
     def dump_edges(self, path: str) -> None:
-        """Persist the session's dynamic graph + cross-check for
-        bench.py's evidence record (atomic rewrite)."""
+        """Persist the session's dynamic graph + cross-check
+        (atomic rewrite)."""
         import json
 
         from orientdb_tpu.storage.durability import atomic_write

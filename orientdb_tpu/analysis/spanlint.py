@@ -5,7 +5,7 @@ The profile aggregator groups stages by span NAME and cross-node
 traces join on the names both sides emit — a typo'd name in a new
 ``span("replication.aply")`` silently splits a stage out of every
 profile with no test to notice. Every string-literal first argument
-of a ``span``/``_span``/``continue_trace``/``_bench_span`` call must
+of a ``span``/``_span``/``continue_trace`` call must
 appear in ``SPAN_CATALOG``, and every catalog entry must be used by
 at least one call site (a stale entry is dead documentation).
 

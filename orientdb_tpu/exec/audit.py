@@ -1,8 +1,8 @@
 """Sampled shadow-oracle parity auditor.
 
 The north star is 50x MATCH throughput **at result-set parity**, but
-until this module parity was asserted only inside ``bench.py``'s dryrun
-gate — never in production serving. PRs 15-18 stacked mutable device
+until this module parity was asserted only by tests and the dry run —
+never in production serving. PRs 15-18 stacked mutable device
 state under every cached plan (delta slab scatters, tier paging, epoch
 compaction swaps, OOM-relief evictions), so a single mis-applied patch
 could serve wrong rows at full speed with zero signal. This module
@@ -15,8 +15,8 @@ makes the parity claim continuously verified:
   compared epoch's device state alive until the audit retires);
 - a bounded background worker re-executes the statement on the pure
   Python oracle and compares canonical result digests — the SAME
-  canonicalization bench's parity gates use (``exec/result``
-  helpers), so the two parity definitions cannot drift;
+  canonicalization every other parity check uses (``exec/result``
+  helpers), so the parity definitions cannot drift;
 - a divergence emits a structured, replayable divergence record
   (fingerprint, trace id, epoch, row-level diff sample), bumps
   ``parity.diverged``, and convicts the fingerprint through the PR-18
@@ -300,7 +300,7 @@ class ParityAuditor:
     # -- views ---------------------------------------------------------------
 
     def flush(self, timeout_s: float = 5.0) -> bool:
-        """Drain every queued audit (tests and bench settle): True when
+        """Drain every queued audit (tests settle on it): True when
         every submitted capture has retired — exact accounting, immune
         to the dequeue-to-inflight handoff window."""
         deadline = time.monotonic() + timeout_s
@@ -375,27 +375,3 @@ def corrupt_point(rows):
         if hasattr(rows, "__len__") and len(rows) > 0:
             return rows[1:]  # drop the first served row
         return [Result(props={"__corrupt__": True})]
-
-
-# -- bench evidence ----------------------------------------------------------
-
-
-def bench_parity_audit_summary() -> Dict:
-    """One per-round ``parity_audit`` evidence record (the
-    device_faults block's sibling): audit volume, divergences, scrub
-    findings. ``tools/perfdiff.degraded_round`` reads it to keep
-    diverged/repaired rounds out of the regression baseline."""
-    from orientdb_tpu.storage.scrub import scrubber
-
-    auditor.flush(timeout_s=2.0)
-    s = auditor.snapshot()
-    sc = scrubber.snapshot()
-    return {
-        "submitted": s["submitted"],
-        "audited": s["audited"],
-        "diverged": s["diverged"],
-        "dropped": s["dropped"],
-        "stale": s["stale"],
-        "scrub_corruptions": sc["corruptions"],
-        "scrub_repairs": sum(sc["repairs"].values()),
-    }

@@ -39,9 +39,8 @@ Injectable end to end: the ``tpu.dispatch`` / ``tpu.transfer`` /
 seeded :class:`~orientdb_tpu.chaos.faults.FaultPlan` drives the whole
 ladder deterministically in tests. Observable end to end: the
 ``devicefault.escalate`` span, the ``device_fault_storm`` alert rule,
-quarantine state in ``/cluster/health`` and the debug bundle, fault
-events on the flight-recorder timeline, and a per-round
-``device_faults`` bench evidence record.
+quarantine state in ``/cluster/health`` and the debug bundle, and fault
+events on the flight-recorder timeline.
 """
 
 from __future__ import annotations
@@ -676,26 +675,3 @@ def transfer_point() -> None:
         pass
     with fault.point("tpu.transfer"):
         pass
-
-
-# -- bench evidence ----------------------------------------------------------
-
-
-def bench_device_faults_summary() -> Dict:
-    """One per-round ``device_faults`` evidence record (the watchdog /
-    memory blocks' sibling): classified counts, quarantines, sheds,
-    relief actuations. ``tools/perfdiff.degraded_round`` reads it to
-    keep chaos rounds out of the regression baseline."""
-    s = domain.snapshot()
-    return {
-        "total": sum(s["classified"].values()),
-        "classified": s["classified"],
-        "retries": s["retries"],
-        "reliefs": s["reliefs"],
-        "quarantines": s["quarantines_total"],
-        "quarantined_now": len(s["quarantined"]),
-        "readmitted": s["readmitted"],
-        "oracle_served": s["oracle_served"],
-        "sheds": s["sheds"],
-        "shedding": bool(s["shedding"]),
-    }

@@ -5,8 +5,8 @@ the reference's pull-based step executor ([E] core/.../sql/executor/ —
 OSelectExecutionPlanner step chains, OMatchExecutionPlanner +
 MatchEdgeTraverser per-record DFS, Depth/BreadthFirstTraverseStep;
 SURVEY.md §3.2–§3.3). Deliberately simple and record-at-a-time: this is the
-slow path OrientDB actually runs, and the baseline `bench.py` compares the
-batched TPU engine against.
+slow path OrientDB actually runs, and the reference the batched TPU
+engine's answers are compared against.
 
 MATCH semantics implemented here (the golden-corpus spec, mirroring
 [E] OMatchStatementExecutionNewTest):

@@ -24,9 +24,9 @@ from orientdb_tpu.models.rid import RID
 # ---------------------------------------------------------------------------
 # result canonicalization (THE parity definition)
 # ---------------------------------------------------------------------------
-# bench.py's parity gates and the shadow-oracle auditor (exec/audit) must
-# agree on what "the same result set" means; both import these helpers so
-# the two parity planes cannot drift apart.
+# Every parity check (the shadow-oracle auditor in exec/audit, the tests)
+# must agree on what "the same result set" means; all import these helpers
+# so the definitions cannot drift apart.
 
 
 def canonical_rows(rows: Iterable[Dict[str, object]]) -> List[Tuple]:

@@ -1375,8 +1375,7 @@ class TpuMatchSolver:
         # (supernodes) from inflating every shard's block
         cap_total = _cap_of(max(total, 1))
         if self.sched.recording:
-            # merge-traffic observability (tools/mesh_scaling.py plots
-            # the S-curve): rows actually merged vs what the old
+            # merge-traffic observability: rows actually merged vs what the old
             # all_gather-of-blocks design would have shipped, per-hop
             # collective bytes (3 packed int32 psum segments), live-
             # frontier occupancy of the expansion slots, and how many

@@ -1,12 +1,10 @@
 """Crash-safe evidence streaming: an append-only, fsync'd JSONL sink.
 
-Round 5's bench ran to the driver's timeout and left NOTHING —
-``bench.py`` wrote its detail artifact only at process exit, so
-``BENCH_r05.json`` records ``rc:124`` and zero numbers. This module is
-the fix: every completed block's result is appended as one JSON line
-and flushed + fsync'd immediately, so a SIGKILL mid-run still leaves
-every finished block on disk. ``bench.py`` emits after every block and
-``tools/dryrun.py`` after every parity query.
+A run that writes its results only at process exit leaves nothing when
+it is killed at a time limit. Here every completed block's result is
+appended as one JSON line and flushed + fsync'd immediately, so a
+SIGKILL mid-run still leaves every finished block on disk.
+``tools/dryrun.py`` emits after every parity query.
 
 The format is one JSON object per line::
 

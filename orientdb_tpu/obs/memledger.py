@@ -23,10 +23,8 @@ size, creation trace id, pin state), and three consumers sit on top:
 - **surfaces**: scrape-time ``hbm.ledger_*`` / ``hbm.owner.*`` gauges
   ride ``snapshot_all()`` into ``/metrics`` and the member-labeled
   ``/cluster/metrics`` fan-in; ``GET /debug/memory`` (admin-only),
-  the debug bundle's ``memory`` section, console
-  ``MEMORY [OWNERS|WATERMARK]``, and a per-round ``memory``
-  bench-evidence record whose peak-HBM leaf ``tools/perfdiff.py``
-  gates round over round.
+  the debug bundle's ``memory`` section, and console
+  ``MEMORY [OWNERS|WATERMARK]``.
 
 Owner taxonomy (fixed — the per-kind gauges and rollups key on it):
 
@@ -639,24 +637,3 @@ def _install() -> None:
 
 
 _install()
-
-
-def bench_memory_summary() -> Dict:
-    """One per-round ``memory`` evidence record (the watchdog block's
-    twin): peak/steady bytes per owner, reconciliation residue, leak
-    count. ``tools/perfdiff.py`` gates the peak-HBM leaves."""
-    rec = memledger.reconcile()
-    return {
-        "peak_bytes": memledger.peak_total(),
-        "peak_by_owner": memledger.peaks(),
-        "steady_bytes": memledger.total_bytes(),
-        "steady_by_owner": memledger.totals(),
-        "pinned_bytes": memledger.pinned_bytes(),
-        "entries": memledger.entry_count(),
-        "reconcile_ok": rec["ok"],
-        "untracked_bytes": rec["untracked_bytes"],
-        "tracked_dead_bytes": rec["tracked_dead_bytes"],
-        "reclaimed_bytes": rec["reclaimed_bytes"],
-        "leak_count": len(memledger.stale_leases()),
-        "lease_outstanding": memledger.lease_count(),
-    }

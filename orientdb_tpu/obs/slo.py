@@ -18,8 +18,7 @@ relevant fingerprints' histograms, :meth:`SloEngine.finish` differences
 against them — so one run is judged on ITS traffic, not the process's
 cumulative history. The result is one machine-readable report
 (``verdict`` pass/fail with every failure naming its rule and key),
-served by ``GET /slo``, console ``SLO``, and persisted by bench.py as
-``BENCH_SLO_r{N}.json``.
+served by ``GET /slo`` and console ``SLO``.
 """
 
 from __future__ import annotations

@@ -9,9 +9,8 @@ enforced tier-1 by ``tests/test_analysis.py``; ``lint_spans`` below
 stays as a back-compat shim) makes that a build failure:
 
 - every **string-literal** first argument of a ``span(...)`` /
-  ``_span(...)`` / ``continue_trace(...)`` / ``_bench_span(...)``
-  call under ``orientdb_tpu/`` and in ``bench.py`` must appear in
-  :data:`SPAN_CATALOG`;
+  ``_span(...)`` / ``continue_trace(...)`` call under
+  ``orientdb_tpu/`` must appear in :data:`SPAN_CATALOG`;
 - every catalog entry must be used by at least one call site (a stale
   entry is dead documentation).
 
@@ -51,8 +50,6 @@ SPAN_CATALOG: Dict[str, str] = {
     "replication.apply_entry": "one WAL entry applied on a replica "
     "(joins the originating write's trace)",
     "forward.request": "non-owner → write-owner HTTP forward",
-    "bench.block": "one measured bench block (evidence carries its "
-    "trace id)",
     "coalesce.lane": "cross-session micro-batching: one item's stay in "
     "its fingerprint lane, enqueue through result (submitter side)",
     "coalesce.dispatch": "one lane micro-batch executed on the lane "
@@ -82,10 +79,9 @@ SPAN_CATALOG: Dict[str, str] = {
     "(obs/slo: stats-table deltas + alert state + burn policy)",
     "timeline.overlap": "one overlap-accounting pass over the flight "
     "recorder's recent window (obs/timeline: scrape-time gauges, "
-    "bench evidence, the alert rule's signal)",
+    "the alert rule's signal)",
     "timeline.export": "Chrome-trace/Perfetto export of the flight "
-    "recorder window (GET /debug/timeline, debug bundle, bench "
-    "TIMELINE artifact)",
+    "recorder window (GET /debug/timeline, debug bundle)",
     "tier.prefetch": "tiered snapshot cold-block upload wave "
     "(storage/tiering: recording fault or dispatch footprint ensure; "
     "recorded as prefetch-kind transfers in the flight recorder)",
@@ -118,9 +114,7 @@ DYNAMIC_FAMILIES: Dict[str, str] = {
 }
 
 #: call names whose first positional string argument is a span name
-#: (bench's block_span() helper takes a block TAG, not a span name —
-#: its inner _bench_span("bench.block", ...) literal is what's linted)
-SPAN_CALLS = frozenset({"span", "_span", "continue_trace", "_bench_span"})
+SPAN_CALLS = frozenset({"span", "_span", "continue_trace"})
 
 
 def _literal_span_names(tree: ast.Module) -> List[Tuple[int, str]]:

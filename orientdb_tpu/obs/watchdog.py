@@ -6,7 +6,7 @@
 ``Cluster``'s probe thread) and every ``config.watchdog_interval_s``
 seconds evaluates the built-in rule catalog over this server's
 databases and cluster. Evaluation happens ONLY here (and in explicit
-:meth:`tick` calls from tests/bench) — the query hot path never pays
+:meth:`tick` calls from tests) — the query hot path never pays
 for it; the PR-4-style overhead guard in ``tests/test_alerts.py``
 asserts that.
 
@@ -106,11 +106,3 @@ class HealthWatchdog:
                 out["resolved"],
             )
         return out
-
-
-def bench_watchdog_summary() -> Dict[str, object]:
-    """One standalone evaluation over this process (no server needed)
-    + the engine summary — the per-round health-evidence record
-    ``bench.py`` writes next to ``static_analysis``."""
-    engine.evaluate()
-    return engine.summary()

@@ -191,9 +191,8 @@ class MeshGraph:
 # own shape cache, and shard row-ranges arrive as the `sh:rowspan`
 # device operand. Before the memo, the eager recording executed each
 # shard_map body primitive-by-primitive (a fresh SPMD program compile
-# per primitive per call — 171 XLA compiles for ONE probe query, the
-# dominant term of BENCH_r04's anti-scaling 35.9→95.4 s mesh_scaling
-# curve); now a recording costs one cached Execute per kernel call, a
+# per primitive per call — 171 XLA compiles for ONE probe query);
+# now a recording costs one cached Execute per kernel call, a
 # shard sweep compiles each geometry once, and revisiting a geometry
 # compiles NOTHING (the zero-retrace contract tests/test_sharded.py
 # asserts via the mesh.kernel_builds counter — it counts memoized
@@ -216,9 +215,9 @@ def _mesh_kernel(name: str, mesh: Mesh, builder, *static):
     if fn is None:
         fn = jax.jit(builder(mesh, ax, *static))
         _MESH_KERNEL_CACHE[key] = fn
-        # geometry-compile observability: the zero-retrace tests and the
-        # mesh_scaling evidence read this counter's deltas; the flight
-        # record gets the event so a compile-tainted dispatch is
+        # geometry-compile observability: the zero-retrace tests read
+        # this counter's deltas; the flight record gets the event so a
+        # compile-tainted dispatch is
         # distinguishable from a steady-state replay on the timeline
         metrics.incr("mesh.kernel_builds")
         from orientdb_tpu.obs.timeline import mark as _tl_mark

@@ -1,13 +1,10 @@
 """Continuous cross-client micro-batching — fingerprint-keyed lanes.
 
-The round-4 measurement story: the compiled engine's batched dispatch
-(`exec/engine.execute_query_batch` → `tpu_engine.dispatch_many`) runs
-~60× faster per query than lone dispatches, but only a client shipping
-an explicit ``query_batch`` frame could reach it — every other remote
-session's query paid a full device round trip alone (BENCH_r04
-``phase_split``: 114.6 ms of transfer against 1.8 ms of device time for
-a lone 2-hop MATCH). For "millions of users" traffic, batch formation —
-not kernels — is the entire game, so this module forms the batches the
+The compiled engine's batched dispatch (`exec/engine.
+execute_query_batch` → `tpu_engine.dispatch_many`) pays one device
+round trip for a whole batch, but only a client shipping an explicit
+``query_batch`` frame could reach it — every other remote session's
+query paid a full round trip alone. This module forms the batches the
 clients no longer have to:
 
 - **dispatch lanes**: sessions submit single queries; each lands in a

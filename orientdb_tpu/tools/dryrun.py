@@ -129,9 +129,8 @@ def run_body(n_devices: int) -> None:
     def canon(rows):
         return sorted(tuple(sorted(r.items())) for r in rows)
 
-    # crash-safe evidence (obs/evidence, same stream discipline as
-    # bench.py): a driver timeout mid-corpus still leaves every
-    # completed query's parity verdict on disk. ORIENTTPU_EVIDENCE
+    # crash-safe evidence (obs/evidence): a driver timeout mid-corpus
+    # still leaves every completed query's parity verdict on disk. ORIENTTPU_EVIDENCE
     # overrides the path.
     import time as _time
 

@@ -1,8 +1,8 @@
 """JAX persistent compilation cache, placeable from outside.
 
-Entry points (``python -m orientdb_tpu.server``, ``bench.py``,
-``chip_smoke.py``, the ``tools`` mains) call :func:`enable_compile_cache`
-before their first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is set,
+Entry points (``python -m orientdb_tpu.server``, ``chip_smoke.py``,
+the ``tools`` mains) call :func:`enable_compile_cache` before their
+first compile. Where ``JAX_COMPILATION_CACHE_DIR`` is set,
 JAX already honours it and this code sets no directory; where it is not,
 the cache goes to ONE fixed path inside the checkout — the path is part
 of the cache key, so a directory built from a temp name, pid or time

@@ -849,7 +849,7 @@ class TrafficSim:
 
 
 def default_chaos_plan(seed: int) -> FaultPlan:
-    """The bench scenario's seeded fault schedule: enough consecutive
+    """The default seeded fault schedule: enough consecutive
     forward-channel drops to trip the ``fwd:`` breaker mid-run (2PC
     prepares retry through them, then fail fast while it is open),
     dropped replica pulls (lag builds, then heals), and jittered

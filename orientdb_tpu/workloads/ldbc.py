@@ -22,7 +22,7 @@ SNB specification to this engine's MATCH dialect:
 Every query is a single MATCH so the whole workload runs on the compiled
 TPU path; parameters use the ``:name`` form so plans cache across
 parameter values. Parity oracle/TPU is asserted in
-``tests/test_ldbc_is.py``; throughput is measured in ``bench.py``.
+``tests/test_ldbc_is.py``.
 """
 
 from __future__ import annotations

@@ -5,17 +5,13 @@ observed through every surface — `GET /alerts`, `/cluster/health`, the
 debug bundle, and the console `ALERTS`/`HEALTH` verbs; the online
 EWMA+MAD latency baseline and two-window burn-rate conditions;
 trace-correlated structured logs and the bundle's bounded `logs` ring;
-the hot-path overhead guard; and the bench headline robustness
-satellite (`BENCH_BUDGET_S=1` exits 0 with a parseable final line plus
-the `BENCH_HEADLINE_r{N}.json` artifact)."""
+and the hot-path overhead guard."""
 
 import base64
 import io
 import json
 import logging
 import os
-import subprocess
-import sys
 import time
 import urllib.error
 import urllib.request
@@ -34,9 +30,6 @@ from orientdb_tpu.obs.trace import span, tracer
 from orientdb_tpu.obs.watchdog import HealthWatchdog
 from orientdb_tpu.utils.config import config
 from orientdb_tpu.utils.logging import JsonFormatter, get_logger, log_ring
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 @pytest.fixture(autouse=True)
 def _clean_alert_state():
@@ -657,84 +650,3 @@ class TestWatchdogLifecycleAndOverhead:
             f"watchdog ticks held {min(shares):.0%} of the loop's wall "
             f"({ticks} ticks over {queries} queries)"
         )
-
-
-class TestBenchWiring:
-    def test_bench_watchdog_summary_shape(self):
-        from orientdb_tpu.obs.watchdog import bench_watchdog_summary
-
-        s = bench_watchdog_summary()
-        assert s["rules"] == len(RULE_CATALOG)
-        assert s["ticks"] >= 1
-        for key in (
-            "firing", "pending", "fired_total", "resolved_total",
-            "baselines", "tick_age_s",
-        ):
-            assert key in s
-
-    @pytest.mark.slow
-    def test_unexpected_crash_still_prints_parseable_headline(
-        self, tmp_path
-    ):
-        """Partial failure cannot leave an unparseable tail: a block
-        that explodes mid-run still ends with a final-line headline
-        carrying an error field, rc 1."""
-        ev = str(tmp_path / "ev.jsonl")
-        detail_dir = tmp_path / "d"
-        detail_dir.mkdir()
-        env = dict(
-            os.environ,
-            JAX_PLATFORMS="cpu",
-            BENCH_BUDGET_S="300",
-            BENCH_DETAIL_DIR=str(detail_dir),
-            BENCH_EVIDENCE=ev,
-            BENCH_PROFILES="boom",  # int() explodes before any block
-        )
-        proc = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py")],
-            env=env, cwd=str(tmp_path), capture_output=True, text=True,
-            timeout=240,
-        )
-        assert proc.returncode == 1
-        line = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert line["metric"] == "demodb_match_2hop_count_qps"
-        assert "ValueError" in line["error"]
-
-    def test_budget_one_exits_rc0_with_parseable_final_line(
-        self, tmp_path
-    ):
-        """The acceptance criterion: BENCH_BUDGET_S=1 exits 0, the
-        LAST stdout line parses as the headline, the same line is
-        persisted to BENCH_HEADLINE_r{N}.json via atomic_write, and
-        the watchdog evidence record rides the stream next to
-        static_analysis."""
-        ev = str(tmp_path / "ev.jsonl")
-        detail_dir = tmp_path / "d"
-        detail_dir.mkdir()
-        env = dict(
-            os.environ,
-            JAX_PLATFORMS="cpu",
-            BENCH_BUDGET_S="1",
-            BENCH_DETAIL_DIR=str(detail_dir),
-            BENCH_EVIDENCE=ev,
-        )
-        proc = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py")],
-            env=env, cwd=str(tmp_path), capture_output=True, text=True,
-            timeout=240,
-        )
-        assert proc.returncode == 0, proc.stderr[-500:]
-        last = proc.stdout.strip().splitlines()[-1]
-        line = json.loads(last)
-        assert line["metric"] == "demodb_match_2hop_count_qps"
-        headlines = [
-            f for f in os.listdir(str(detail_dir))
-            if f.startswith("BENCH_HEADLINE_r")
-        ]
-        assert len(headlines) == 1
-        with open(os.path.join(str(detail_dir), headlines[0])) as f:
-            assert json.loads(f.read()) == line
-        from orientdb_tpu.obs.evidence import read_evidence
-
-        blocks = [r["block"] for r in read_evidence(ev)]
-        assert "watchdog" in blocks  # health evidence next to the rest

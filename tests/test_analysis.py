@@ -31,7 +31,7 @@ def run_pass(name, sources, readme=""):
 class TestTreeIsClean:
     def test_all_passes_clean_over_the_whole_tree(self):
         """THE tier-1 gate: zero unsuppressed findings from any pass
-        over orientdb_tpu/ + bench.py."""
+        over orientdb_tpu/."""
         rep = core.run(root=REPO)
         assert rep.findings == [], "\n" + "\n".join(
             str(f) for f in rep.findings
@@ -42,6 +42,29 @@ class TestTreeIsClean:
             "iolint", "spanlint", "promlint", "racelint", "jaxlint",
             "alertlint", "critpathlint",
         }
+
+    def test_no_module_or_test_knows_the_old_benchmark(self):
+        """Speed has one source, BENCHMARK.json + benchmark/, and the
+        arrows point one way: nothing under orientdb_tpu/ or tests/
+        names the deleted harness, its comparator, its span helper or
+        one of its environment knobs."""
+        import re
+
+        gone = re.compile(r"bench\.py|perfdiff|_bench_span|\bBENCH_[A-Z]")
+        hits = []
+        for top in ("orientdb_tpu", "tests"):
+            for dirpath, _dirs, names in os.walk(os.path.join(REPO, top)):
+                for f in names:
+                    if not f.endswith((".py", ".md", ".json", ".toml")):
+                        continue
+                    path = os.path.join(dirpath, f)
+                    if os.path.samefile(path, __file__):
+                        continue  # the pattern above
+                    with open(path, encoding="utf-8") as fh:
+                        for n, line in enumerate(fh, 1):
+                            if gone.search(line):
+                                hits.append(f"{path}:{n}: {line.strip()}")
+        assert hits == [], "\n" + "\n".join(hits)
 
 
 class TestFramework:

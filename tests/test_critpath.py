@@ -7,8 +7,8 @@ bin.send delay -> flush, forced lane window -> queue) each landing a
 ``latency_regression`` blame annotation that names the injected
 segment and carries a joinable exemplar trace id — the fault_retry one
 end-to-end through GET /alerts; the surfaces (GET /stats/critpath,
-debug bundle, console CRITPATH); the perfdiff segment +
-headline-overlap leaves; and the <1.35x overhead guard."""
+debug bundle, console CRITPATH). What a request pays the plane is
+counted in tests/test_plane_overhead.py."""
 
 import base64
 import io
@@ -19,7 +19,7 @@ import urllib.request
 
 import pytest
 
-from orientdb_tpu.chaos import FaultPlan, fault
+from orientdb_tpu.chaos import FaultError, FaultPlan, fault
 from orientdb_tpu.exec.devicefault import domain
 from orientdb_tpu.obs import critpath as CP
 from orientdb_tpu.obs.alerts import AlertEngine, engine as alert_engine
@@ -319,26 +319,42 @@ class TestChaosBlame:
                     strict=True,
                 ).to_dicts()
 
-            for i in range(8):  # settle variant routing before ticks
+            # settle variant routing before ticks; these real requests
+            # are also the older window the blame diff compares against
+            for i in range(32):
                 run_one(i)
             stats.reset()
             wd.tick()  # tick 0 arms the per-fid call deltas
-            for t in range(4):  # baseline: fast ticks learn the EWMA
-                for i in range(8):
-                    run_one(t * 8 + i)
+            # baseline: the rule learns a level this test states. Real
+            # walls here taught the EWMA whatever six xdist workers left
+            # of the CPU, and one slow baseline request could outlast
+            # every chaos request and take the exemplar.
+            for _t in range(4):
+                for _i in range(8):
+                    stats.record_external(SQL, 0.002, engine="tpu", rows=1)
                 wd.tick()
             fid = fingerprint(SQL).fid
             assert not [
                 a for a in alert_engine.active()
                 if a["rule"] == "latency_regression" and a["key"] == fid
             ]
+
+            def slow_transient():
+                # the failed attempt itself takes 40 ms, so what the
+                # retry ladder costs a request (two of these and the
+                # backoff) is stated here and not left to the backoff's
+                # jitter: 80 ms against a 2 ms baseline
+                time.sleep(0.04)
+                return FaultError("[chaos] injected error at tpu.dispatch")
+
             # chaos: two transient dispatch faults per query — every
             # request pays the retry ladder (failed attempts + backoff)
             states = []
             for tick in range(2):
                 for i in range(8):
                     p = FaultPlan(seed=100 + tick * 8 + i).at(
-                        "tpu.dispatch", "error", times=2
+                        "tpu.dispatch", "error", times=2,
+                        error=slow_transient,
                     )
                     with fault.armed(p):
                         run_one(tick * 8 + i)
@@ -367,7 +383,7 @@ class TestChaosBlame:
             assert "fault_retry" in a["detail"], a["detail"]
             assert a["exemplar_trace_id"] == blame["trace_id"]
             rec = _exemplar_record(a["exemplar_trace_id"])
-            assert rec["segments_ms"].get("fault_retry", 0.0) > 0.0
+            assert rec["segments_ms"].get("fault_retry", 0.0) >= 80.0
         finally:
             wd.stop()
             srv.databases.pop("demo", None)  # keep the module corpus
@@ -432,7 +448,10 @@ class TestChaosBlame:
                 run_via(fast, i)
         finally:
             fast.stop()
-        slow = QueryCoalescer(window_ms=60)  # the forced window
+        # the forced window: 250 ms a request, so that the queue wait
+        # outgrows whatever six xdist workers add to host_compute (a
+        # 60 ms window lost to a 65 ms host_compute in a whole run)
+        slow = QueryCoalescer(window_ms=250)
         try:
             for i in range(4):
                 run_via(slow, i)
@@ -530,143 +549,3 @@ class TestSurfaces:
         db.query(SQL, params={"u": 0}, engine="oracle").to_dicts()
         assert plane.report(5)["requests"] == 0
         assert plane.report(5)["enabled"] is False
-
-
-# ---------------------------------------------------------------------------
-# perfdiff: segment leaves + the headline overlap leaves
-# ---------------------------------------------------------------------------
-
-
-class TestPerfdiffLeaves:
-    BASE = {
-        "value": 100.0,
-        "extras": {
-            "critpath": {
-                "single_2hop": {
-                    "device_compute": 2.0,
-                    "result_transfer": 1.0,
-                    "host_compute": 4.0,
-                    "ring_hit": 0.1,  # sub-floor: never gated
-                },
-            },
-            "headline_overlap": {
-                "records": 40,
-                "device_idle_fraction": 0.3,
-                "transfer_hidden_fraction": 0.8,
-            },
-        },
-    }
-
-    def _cur(self):
-        return json.loads(json.dumps(self.BASE))
-
-    def test_identical_rounds_pass(self):
-        from orientdb_tpu.tools.perfdiff import diff
-
-        rep = diff(self.BASE, self._cur())
-        assert rep["verdict"] == "pass"
-        assert rep["segments"] == {
-            "regressions": [], "improvements": [],
-        }
-        assert (
-            "headline.device_idle_fraction" in rep["overlap"]["deltas"]
-        )
-
-    def test_segment_growth_names_the_segment(self):
-        from orientdb_tpu.tools.perfdiff import diff
-
-        cur = self._cur()
-        cur["extras"]["critpath"]["single_2hop"]["device_compute"] = 5.0
-        rep = diff(self.BASE, cur)
-        assert rep["verdict"] == "regression"
-        regs = [
-            r for r in rep["regressions"] if r["kind"] == "segment"
-        ]
-        assert [r["metric"] for r in regs] == [
-            "critpath.single_2hop.device_compute"
-        ]
-
-    def test_segment_improvement_and_subfloor_skip(self):
-        from orientdb_tpu.tools.perfdiff import diff
-
-        cur = self._cur()
-        cur["extras"]["critpath"]["single_2hop"]["host_compute"] = 1.0
-        cur["extras"]["critpath"]["single_2hop"]["ring_hit"] = 3.0
-        rep = diff(self.BASE, cur)
-        assert rep["verdict"] == "pass"  # sub-floor base never gates
-        imps = {
-            i["metric"] for i in rep["segments"]["improvements"]
-        }
-        assert "critpath.single_2hop.host_compute" in imps
-
-    def test_ungated_headline_overlap_regression_exits_2(self, tmp_path):
-        from orientdb_tpu.tools.perfdiff import diff, main
-
-        cur = self._cur()
-        cur["extras"]["headline_overlap"]["device_idle_fraction"] = 0.9
-        rep = diff(self.BASE, cur)
-        assert rep["verdict"] == "regression"
-        names = {
-            r["metric"] for r in rep["regressions"]
-            if r["kind"] == "overlap"
-        }
-        assert "headline.device_idle_fraction" in names
-        b, c = tmp_path / "b.json", tmp_path / "c.json"
-        b.write_text(json.dumps(self.BASE))
-        c.write_text(json.dumps(cur))
-        assert main([str(b), str(c), "--json"]) == 2
-        assert main([str(b), str(b), "--json"]) == 0
-
-    def test_zero_record_overlap_block_is_ignored(self):
-        from orientdb_tpu.tools.perfdiff import diff
-
-        cur = self._cur()
-        cur["extras"]["headline_overlap"] = {
-            "records": 0, "device_idle_fraction": 0.99,
-            "transfer_hidden_fraction": 0.0,
-        }
-        assert diff(self.BASE, cur)["verdict"] == "pass"
-
-
-# ---------------------------------------------------------------------------
-# overhead guard (the PR-4 stats-plane pattern, same 1.35x bar)
-# ---------------------------------------------------------------------------
-
-
-class TestOverheadGuard:
-    def test_full_sampling_overhead_is_bounded(self, monkeypatch):
-        """With the plane on (full sampling) a 1k-query loop through
-        the engine front door stays close to a critpath-disabled run:
-        begin/commit is one small object + one short lock, stamps are
-        one thread-local read. Best-of-3 interleaved reps; asserts the
-        mechanism, not the microbenchmark."""
-        from orientdb_tpu.models.database import Database
-        from orientdb_tpu.models.schema import PropertyType
-
-        db = Database("cp_overhead")
-        P = db.schema.create_vertex_class("P")
-        P.create_property("age", PropertyType.LONG)
-        for i in range(10):
-            db.new_vertex("P", uid=i, age=20 + i)
-        q = "SELECT count(*) AS n FROM P WHERE age > 25"
-        n = 1000
-        monkeypatch.setattr(config, "stats_sample_rate", 1.0)
-
-        def loop():
-            t0 = time.perf_counter()
-            for _ in range(n):
-                db.query(q).to_dicts()
-            return time.perf_counter() - t0
-
-        loop()  # warm parse/plan caches
-        on, off = [], []
-        for _ in range(3):
-            monkeypatch.setattr(config, "critpath_enabled", True)
-            on.append(loop())
-            monkeypatch.setattr(config, "critpath_enabled", False)
-            off.append(loop())
-        ratio = min(on) / min(off)
-        assert ratio < 1.35, (
-            f"critpath overhead {ratio:.2f}x (on={min(on):.3f}s "
-            f"off={min(off):.3f}s for {n} queries)"
-        )

@@ -1,11 +1,10 @@
 """The runtime transfer/compile guard (analysis/deviceguard):
 jaxlint's dynamic twin. Unit tests for the knobs, site extraction,
-jaxlint cross-check, and the bench round-trip; subprocess end-to-end
+jaxlint cross-check, and the dump round-trip; subprocess end-to-end
 tests proving a seeded implicit-transfer mutation and a seeded
 recompile mutation each FAIL their observing test with an actionable
 message naming the offending site."""
 
-import importlib.util
 import json
 import os
 import subprocess
@@ -105,28 +104,20 @@ class TestDumpRoundTrip:
         doc = json.loads(open(p).read())
         assert doc["tests_guarded"] == 3
         assert doc["recompile_assertions"] == 2  # 3 tests, 1 offender
-        # bench.py summarizes the same file into its evidence record
-        spec = importlib.util.spec_from_file_location(
-            "bench", os.path.join(REPO, "bench.py")
-        )
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
+        # a reader finds the dump where ORIENTTPU_DEVICEGUARD_DUMP says
+        # and gets every figure as plain JSON
+        from orientdb_tpu.analysis.deviceguard import dump_path
+
         os.environ["ORIENTTPU_DEVICEGUARD_DUMP"] = p
         try:
-            summary = bench._read_deviceguard()
+            assert dump_path() == p
         finally:
             del os.environ["ORIENTTPU_DEVICEGUARD_DUMP"]
-        age = summary.pop("age_s")
-        assert 0 <= age < 60
-        assert summary == {
-            "mode": "disallow",
-            "tests_guarded": 3,
-            "transfers_blocked": 0,
-            "rerecords": 1,
-            "recompile_assertions": 2,
-            "static_coverage": doc["cross_check"]["coverage"],
-            "counters": doc["counters"],
-        }
+        assert doc["mode"] == "disallow"
+        assert doc["transfers"] == []
+        assert len(doc["rerecords"]) == 1
+        assert doc["counters"]["plan_cache.hit"] == 7
+        assert "coverage" in doc["cross_check"]
 
 
 def _run_guarded_suite(tmp_path, body: str, env_extra=None):

@@ -21,10 +21,10 @@ Covers:
   the last-refusal record, including the real tiered+overlay path;
 - surfaces: ``GET /debug/memory`` (admin-only), the bundle ``memory``
   section, console ``MEMORY``, scrape gauges + promlint-clean
-  exposition;
-- bench evidence: ``bench_memory_summary`` shape and the perfdiff
-  peak-HBM leaf gating;
-- the <1.35x hot-path overhead guard, ledger on vs off.
+  exposition.
+
+What a request pays the ledger is counted in
+tests/test_plane_overhead.py.
 """
 
 import io
@@ -39,7 +39,6 @@ import pytest
 from orientdb_tpu.obs.alerts import engine
 from orientdb_tpu.obs.memledger import (
     OWNER_KINDS,
-    bench_memory_summary,
     ledger_telemetry,
     memledger,
 )
@@ -497,130 +496,3 @@ class TestSurfaces:
         out2 = io.StringIO()
         Console(stdout=out2).onecmd("MEMORY WATERMARK")
         assert "MiB" in out2.getvalue()
-
-
-# ---------------------------------------------------------------------------
-# bench evidence + perfdiff gating
-# ---------------------------------------------------------------------------
-
-
-class TestBenchEvidence:
-    def test_bench_memory_summary_shape(self):
-        a = jnp.zeros((32, 32), dtype=jnp.int32)
-        memledger.register("snapshot", "o", "own", arr=a)
-        s = bench_memory_summary()
-        for key in (
-            "peak_bytes",
-            "peak_by_owner",
-            "steady_bytes",
-            "steady_by_owner",
-            "pinned_bytes",
-            "entries",
-            "reconcile_ok",
-            "untracked_bytes",
-            "tracked_dead_bytes",
-            "reclaimed_bytes",
-            "leak_count",
-            "lease_outstanding",
-        ):
-            assert key in s, key
-        assert s["peak_bytes"] >= s["steady_by_owner"]["snapshot"] > 0
-        assert s["leak_count"] == 0
-        json.dumps(s)  # the evidence stream is JSON
-
-    def test_perfdiff_gates_peak_hbm_growth(self):
-        from orientdb_tpu.tools.perfdiff import diff, hbm_leaves
-
-        base = {
-            "value": 100.0,
-            "extras": {
-                "memory": {
-                    "peak_bytes": 1 << 24,
-                    "peak_by_owner": {"snapshot": 1 << 23, "tier_pool": 64},
-                }
-            },
-        }
-        leaves = dict(hbm_leaves(base["extras"]))
-        assert leaves["memory.peak_bytes"] == float(1 << 24)
-        assert leaves["memory.peak.snapshot"] == float(1 << 23)
-        grown = {
-            "value": 100.0,
-            "extras": {
-                "memory": {
-                    "peak_bytes": (1 << 24) * 2,
-                    "peak_by_owner": {
-                        "snapshot": 1 << 23,
-                        # grows 100x but from a sub-floor base: skipped
-                        "tier_pool": 6400,
-                    },
-                }
-            },
-        }
-        rep = diff(base, grown)
-        assert rep["verdict"] == "regression"
-        (r,) = rep["hbm"]["regressions"]
-        assert r["metric"] == "memory.peak_bytes" and r["ratio"] == 2.0
-        assert [x["kind"] for x in rep["regressions"]] == ["hbm"]
-        assert rep["thresholds"]["hbm_tol"] == 1.5
-        # within-tolerance growth and shrink both pass
-        ok = {
-            "value": 100.0,
-            "extras": {
-                "memory": {
-                    "peak_bytes": int((1 << 24) * 1.2),
-                    "peak_by_owner": {"snapshot": 1 << 22},
-                }
-            },
-        }
-        rep2 = diff(base, ok)
-        assert rep2["verdict"] == "pass"
-        assert rep2["hbm"]["improvements"], "a 2x shrink should report"
-        # a round with no memory record compares nothing, gates nothing
-        assert diff(base, {"value": 100.0, "extras": {}})["verdict"] == "pass"
-
-
-# ---------------------------------------------------------------------------
-# hot-path overhead guard
-# ---------------------------------------------------------------------------
-
-
-class TestOverhead:
-    def test_ledger_overhead_on_the_query_hot_path(self, monkeypatch):
-        """The sampled-registration guard: a tpu replay loop with the
-        ledger ON stays under 1.35x the ledger-OFF loop. Best-of-3;
-        asserts the mechanism (byte upserts + sampled trace capture
-        are cheap), not a microbenchmark."""
-        from orientdb_tpu.obs.stats import stats as _qstats
-
-        _qstats.reset()
-        metrics.reset()
-        engine.reset()
-        db = generate_demodb(n_profiles=40, avg_friends=3, seed=21)
-        db_snap = attach_fresh_snapshot(db)
-        assert db_snap is not None
-        q = COUNT_2HOP
-        n = 200
-
-        def loop():
-            t0 = time.perf_counter()
-            for i in range(n):
-                db.query(
-                    q, params={"u": i % 20}, engine="tpu", strict=True
-                )
-            return time.perf_counter() - t0
-
-        try:
-            loop()  # warm plan/replay caches
-            on, off = [], []
-            for _ in range(3):
-                monkeypatch.setattr(config, "memledger_enabled", True)
-                on.append(loop())
-                monkeypatch.setattr(config, "memledger_enabled", False)
-                off.append(loop())
-            ratio = min(on) / min(off)
-            assert ratio < 1.35, (
-                f"memledger overhead {ratio:.2f}x (on={min(on):.3f}s "
-                f"off={min(off):.3f}s for {n} queries)"
-            )
-        finally:
-            db.detach_snapshot()

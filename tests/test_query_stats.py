@@ -2,15 +2,12 @@
 obs/profile, obs/spanlint): fingerprint stability, per-fingerprint cost
 accounting through the engine front door (including cached executions),
 the slowlog ↔ stats ↔ trace join, the /stats endpoints, the
-/cluster/metrics fan-in, the span-name catalog lint (tier-1), the
-sampling knob, and the bench budget (rc 0 + partial evidence under a
-tiny BENCH_BUDGET_S)."""
+/cluster/metrics fan-in, the span-name catalog lint (tier-1), and the
+sampling knob. What a request pays the stats plane is counted in
+tests/test_plane_overhead.py."""
 
 import io
 import json
-import os
-import subprocess
-import sys
 import time
 import urllib.request
 
@@ -26,9 +23,6 @@ from orientdb_tpu.obs.stats import (
     stats,
 )
 from orientdb_tpu.utils.config import config
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 @pytest.fixture(autouse=True)
 def _clean_stats():
@@ -449,105 +443,3 @@ class TestSurfaces:
         assert b["profile"]["traces"] >= 1
         stages = {s["name"] for s in b["profile"]["stages"]}
         assert "query" in stages
-
-
-class TestOverheadGuard:
-    def test_full_sampling_overhead_is_bounded(self, monkeypatch):
-        """With stats_sample_rate=1.0 a 1k-query loop through the
-        engine stays close to a stats-disabled run. Best-of-3 reps per
-        config, interleaved, and a generous threshold: this asserts the
-        mechanism (thread-local accumulator + cached fingerprint + one
-        short lock per query — not a per-event search), not the
-        microbenchmark."""
-        from orientdb_tpu.models.database import Database
-        from orientdb_tpu.models.schema import PropertyType
-
-        db = Database("overhead")
-        P = db.schema.create_vertex_class("P")
-        P.create_property("age", PropertyType.LONG)
-        for i in range(10):
-            db.new_vertex("P", uid=i, age=20 + i)
-        q = "SELECT count(*) AS n FROM P WHERE age > 25"
-        n = 1000
-
-        def loop():
-            t0 = time.perf_counter()
-            for _ in range(n):
-                db.query(q).to_dicts()
-            return time.perf_counter() - t0
-
-        # critpath rides the same sampled() gate but has its own guard
-        # (tests/test_critpath.py) — keep this one measuring stats only
-        monkeypatch.setattr(config, "critpath_enabled", False)
-        monkeypatch.setattr(config, "stats_sample_rate", 1.0)
-        loop()  # warm parse/plan caches
-        on, off = [], []
-        for _ in range(3):
-            monkeypatch.setattr(config, "stats_sample_rate", 1.0)
-            on.append(loop())
-            monkeypatch.setattr(config, "stats_sample_rate", 0.0)
-            off.append(loop())
-        ratio = min(on) / min(off)
-        assert ratio < 1.35, (
-            f"stats overhead {ratio:.2f}x (on={min(on):.3f}s "
-            f"off={min(off):.3f}s for {n} queries)"
-        )
-
-
-class TestBenchBudget:
-    def test_tiny_budget_exits_rc0_with_partial_evidence(self, tmp_path):
-        """The VERDICT r5 regression (rc 124, zero numbers) cannot
-        recur: under an exhausted budget every block skips with a
-        {"skipped": "budget"} evidence record, the round-stamped detail
-        artifact is on disk, and the run exits 0."""
-        ev = str(tmp_path / "ev.jsonl")
-        # a configured regression gate must NOT turn the partial run's
-        # 0.0 headline into a false GATE REGRESSION (exit 2)
-        gate = tmp_path / "BENCH_r01.json"
-        gate.write_text(json.dumps({"value": 100.0, "extras": {}}))
-        # a completed earlier run of the SAME round must be preserved
-        # (the incremental flush rewrites from the first record)
-        import glob
-        import re
-
-        ns = [
-            int(re.search(r"BENCH_r(\d+)\.json$", p).group(1))
-            for p in glob.glob(os.path.join(REPO, "BENCH_r*.json"))
-        ]
-        detail_name = f"BENCH_DETAIL_r{(max(ns) + 1) if ns else 1:02d}.json"
-        detail_dir = tmp_path / "rounds" / "r"
-        detail_dir.mkdir(parents=True)
-        (detail_dir / detail_name).write_text(json.dumps({"value": 42.0}))
-        env = dict(
-            os.environ,
-            JAX_PLATFORMS="cpu",
-            BENCH_BUDGET_S="0",
-            BENCH_DETAIL_DIR=str(detail_dir),
-            BENCH_EVIDENCE=ev,
-            BENCH_GATE=str(gate),
-        )
-        proc = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py")],
-            env=env,
-            cwd=str(tmp_path),
-            capture_output=True,
-            text=True,
-            timeout=240,
-        )
-        assert proc.returncode == 0, proc.stderr[-500:]
-        assert "SKIPPED (budget-skipped blocks" in proc.stderr
-        line = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert line["metric"] == "demodb_match_2hop_count_qps"
-        with open(str(detail_dir / detail_name)) as f:
-            detail = json.load(f)
-        # the earlier completed run's numbers survived as .prev
-        with open(str(detail_dir / (detail_name + ".prev"))) as f:
-            assert json.load(f) == {"value": 42.0}
-        skipped = detail["extras"]["skipped_blocks"]
-        assert "parity" in skipped and "batched_2hop" in skipped
-        from orientdb_tpu.obs.evidence import read_evidence
-
-        recs = read_evidence(ev)
-        by_block = {r["block"]: r["data"] for r in recs}
-        assert by_block["parity"] == {"skipped": "budget"}
-        assert "final" in by_block

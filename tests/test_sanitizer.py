@@ -1,9 +1,9 @@
 """The runtime lock-order sanitizer (analysis/sanitizer): mutation
 tests proving the detector fires — a seeded ABBA acquisition must fail
 with BOTH witness stacks — plus proxy/Condition integration, the
-dynamic-vs-static locklint cross-check, the edge dump bench.py reads,
-the pytest-plugin end-to-end path (subprocess), and the overhead guard
-(< 1.5x on a test_concurrency-shaped workload)."""
+dynamic-vs-static locklint cross-check, the edge dump, and the
+pytest-plugin end-to-end path (subprocess). What the proxies cost a
+single-threaded workload is counted in tests/test_plane_overhead.py."""
 
 import importlib.util
 import json
@@ -340,25 +340,20 @@ class TestCrossCheck:
             }
         ]
         assert doc["cross_check"]["coverage"] == 1.0
-        # bench.py summarizes the same file into its evidence record
-        spec = importlib.util.spec_from_file_location(
-            "bench", os.path.join(REPO, "bench.py")
-        )
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
+        # a reader finds the dump where ORIENTTPU_SANITIZER_EDGES says
+        # and gets every figure as plain JSON
+        from orientdb_tpu.analysis.sanitizer import edges_path
+
         os.environ["ORIENTTPU_SANITIZER_EDGES"] = p
         try:
-            summary = bench._read_sanitizer_edges()
+            assert edges_path() == p
         finally:
             del os.environ["ORIENTTPU_SANITIZER_EDGES"]
-        age = summary.pop("age_s")
-        assert 0 <= age < 60  # freshness stamp: stale dumps are visible
-        assert summary == {
-            "edges": 1,
-            "repo_edges": 1,
-            "violations": 0,
-            "long_holds": 1,
-            "cross_check": doc["cross_check"],
+        assert len(doc["repo_edges"]) == 1
+        assert doc["violations"] == 0
+        assert len(doc["long_holds"]) == 1
+        assert set(doc["cross_check"]) >= {
+            "dynamic_edges", "coverage", "static_edges",
         }
 
 
@@ -458,52 +453,3 @@ class TestPluginEndToEnd:
             timeout=180,
         )
         assert proc.returncode == 0, proc.stdout + proc.stderr
-
-
-class TestOverheadGuard:
-    def test_overhead_under_1_5x_on_concurrency_shaped_workload(self):
-        """The sanitizer rides tier-1 over the concurrency suites: its
-        wrapper must stay under 1.5x on the save-heavy multi-threaded
-        pattern test_concurrency exercises (locks are a fraction of
-        each op; a pure lock microbenchmark would measure only the
-        proxy)."""
-        from orientdb_tpu import Database
-
-        def workload():
-            db = Database("ovh")
-            db.schema.create_vertex_class("P")
-
-            def worker(base):
-                for i in range(150):
-                    db.new_vertex("P", uid=base + i)
-
-            threads = [
-                threading.Thread(target=worker, args=(k * 1000,))
-                for k in range(4)
-            ]
-            t0 = time.perf_counter()
-            for t in threads:
-                t.start()
-            for t in threads:
-                t.join()
-            return time.perf_counter() - t0
-
-        was_installed = global_sanitizer.installed
-        was_active = global_sanitizer.active
-        try:
-            global_sanitizer.uninstall()
-            global_sanitizer.active = False
-            t_off = min(workload() for _ in range(3))
-            global_sanitizer.install()
-            global_sanitizer.active = True
-            t_on = min(workload() for _ in range(3))
-        finally:
-            global_sanitizer.active = was_active
-            if was_installed:
-                global_sanitizer.install()
-            else:
-                global_sanitizer.uninstall()
-        assert t_on <= t_off * 1.5 + 0.05, (
-            f"sanitizer overhead {t_on / max(t_off, 1e-9):.2f}x "
-            f"(off={t_off * 1000:.1f}ms on={t_on * 1000:.1f}ms)"
-        )

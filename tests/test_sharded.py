@@ -209,7 +209,7 @@ def _reattach(db, shards):
 @pytest.mark.parametrize("shards", [2, 4, 8])
 def test_shard_sweep_match_parity(sweep_db, shards):
     """2/4/8-shard MATCH row sets identical to unsharded, sorted canon —
-    the result-parity half of the mesh_scaling acceptance gate."""
+    the result-parity half of the mesh acceptance gate."""
     db, want_rows, want_count = sweep_db
     _reattach(db, shards)
     got = canon(db.query(SWEEP_ROWS_SQL, engine="tpu", strict=True).to_dicts())

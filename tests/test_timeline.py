@@ -1,9 +1,9 @@
 """Dispatch flight recorder (obs/timeline): per-dispatch lifecycle
 rings, overlap accounting (device-idle / transfer-hidden / ring
 savings / lane decomposition), Chrome-trace export over every dispatch
-path, the tpu.page_prefetch.* counter contract (PR 13), the
-overlap_regression alert rule, the perfdiff tool, and the tier-1
-overhead guard (<1.35x with sampling on)."""
+path, the tpu.page_prefetch.* counter contract (PR 13), and the
+overlap_regression alert rule. What a request pays the recorder is
+counted in tests/test_plane_overhead.py."""
 
 import json
 import time
@@ -713,173 +713,4 @@ class TestOverlapRegressionRule:
         assert "overlap_regression" in RULE_CATALOG
         assert any(
             r.name == "overlap_regression" for r in BUILTIN_RULES
-        )
-
-
-# ---------------------------------------------------------------------------
-# perfdiff (satellite: the bench trajectory's diffing tool)
-# ---------------------------------------------------------------------------
-
-
-class TestPerfdiff:
-    BASE = {
-        "value": 100.0,
-        "extras": {
-            "single_query_qps": 10.0,
-            "ldbc_is": {"IS1": {"qps": 50.0}},
-            "phase_split_ms_per_query": {
-                "match_2hop": {"device_ms": 2.0, "host_ms": 4.0}
-            },
-            "concurrent_sessions": {
-                "overlap": {
-                    "records": 40,
-                    "device_idle_fraction": 0.3,
-                    "transfer": {"transfer_hidden_fraction": 0.8},
-                }
-            },
-            "mesh_scaling": [
-                {
-                    "shards": 2,
-                    "overlap": {
-                        "records": 5,
-                        "device_idle_fraction": 0.4,
-                        "transfer_hidden_fraction": 0.5,
-                    },
-                }
-            ],
-        },
-    }
-
-    def test_identical_rounds_pass(self):
-        from orientdb_tpu.tools.perfdiff import diff
-
-        rep = diff(self.BASE, json.loads(json.dumps(self.BASE)))
-        assert rep["verdict"] == "pass"
-        assert rep["regressions"] == []
-        assert rep["headline"]["ratio"] == 1.0
-        assert (
-            "concurrent_sessions.device_idle_fraction"
-            in rep["overlap"]["deltas"]
-        )
-        assert (
-            "mesh_scaling.2.device_idle_fraction"
-            in rep["overlap"]["deltas"]
-        )
-
-    def test_qps_drop_and_ms_rise_flag_regression(self):
-        from orientdb_tpu.tools.perfdiff import diff
-
-        cur = json.loads(json.dumps(self.BASE))
-        cur["value"] = 20.0  # 0.2x < 0.55 tolerance
-        cur["extras"]["phase_split_ms_per_query"]["match_2hop"][
-            "device_ms"
-        ] = 10.0
-        rep = diff(self.BASE, cur)
-        assert rep["verdict"] == "regression"
-        kinds = {r["kind"] for r in rep["regressions"]}
-        assert {"qps", "ms"} <= kinds
-        names = {r["metric"] for r in rep["regressions"]}
-        assert "headline" in names
-        assert "match_2hop.device_ms" in names
-
-    def test_overlap_degradation_flags_regression(self):
-        from orientdb_tpu.tools.perfdiff import diff
-
-        cur = json.loads(json.dumps(self.BASE))
-        ov = cur["extras"]["concurrent_sessions"]["overlap"]
-        ov["device_idle_fraction"] = 0.9  # +0.6 > 0.2 tolerance
-        ov["transfer"]["transfer_hidden_fraction"] = 0.1  # -0.7
-        rep = diff(self.BASE, cur)
-        assert rep["verdict"] == "regression"
-        names = {
-            r["metric"]
-            for r in rep["regressions"]
-            if r["kind"] == "overlap"
-        }
-        assert "concurrent_sessions.device_idle_fraction" in names
-        assert "concurrent_sessions.transfer_hidden_fraction" in names
-
-    def test_noise_inside_tolerance_passes(self):
-        from orientdb_tpu.tools.perfdiff import diff
-
-        cur = json.loads(json.dumps(self.BASE))
-        cur["value"] = 70.0  # 0.7x, inside the 0.55 envelope
-        rep = diff(self.BASE, cur)
-        assert rep["verdict"] == "pass"
-
-    def test_cli_round_trip_and_exit_codes(self, tmp_path):
-        from orientdb_tpu.tools.perfdiff import main
-
-        b = tmp_path / "base.json"
-        c = tmp_path / "cur.json"
-        b.write_text(json.dumps(self.BASE))
-        cur = json.loads(json.dumps(self.BASE))
-        cur["value"] = 10.0
-        c.write_text(json.dumps(cur))
-        assert main([str(b), str(b), "--json"]) == 0
-        assert main([str(b), str(c), "--json"]) == 2
-        assert main([str(b)]) == 1  # usage
-        assert main([str(b), str(tmp_path / "missing.json")]) == 1
-
-    def test_cli_emits_machine_readable_verdict(self, tmp_path, capsys):
-        from orientdb_tpu.tools.perfdiff import main
-
-        b = tmp_path / "base.json"
-        b.write_text(json.dumps(self.BASE))
-        rc = main([str(b), str(b), "--json"])
-        doc = json.loads(capsys.readouterr().out)
-        assert rc == 0
-        assert doc["verdict"] == "pass"
-        assert doc["base"] == str(b)
-        assert "thresholds" in doc
-
-    def test_driver_wrapper_shape_accepted(self, tmp_path):
-        from orientdb_tpu.tools.perfdiff import main
-
-        w = tmp_path / "wrapped.json"
-        w.write_text(json.dumps({"parsed": self.BASE}))
-        assert main([str(w), str(w), "--json"]) == 0
-
-
-# ---------------------------------------------------------------------------
-# overhead guard (the PR-4 stats-plane pattern, same 1.35x bar)
-# ---------------------------------------------------------------------------
-
-
-class TestOverheadGuard:
-    def test_recorder_overhead_is_bounded(self, monkeypatch):
-        """With the recorder on (full sampling) a 1k-query loop stays
-        close to a recorder-disabled run: begin/commit is one small
-        object + one short lock, hooks are one thread-local read.
-        Best-of-3 interleaved reps; asserts the mechanism, not the
-        microbenchmark."""
-        from orientdb_tpu.models.schema import PropertyType
-
-        db = Database("tl_overhead")
-        P = db.schema.create_vertex_class("P")
-        P.create_property("age", PropertyType.LONG)
-        for i in range(10):
-            db.new_vertex("P", uid=i, age=20 + i)
-        q = "SELECT count(*) AS n FROM P WHERE age > 25"
-        n = 1000
-
-        def loop():
-            t0 = time.perf_counter()
-            for _ in range(n):
-                db.query(q).to_dicts()
-            return time.perf_counter() - t0
-
-        monkeypatch.setattr(config, "stats_sample_rate", 1.0)
-        monkeypatch.setattr(config, "timeline_capacity", 2048)
-        loop()  # warm parse/plan caches
-        on, off = [], []
-        for _ in range(3):
-            monkeypatch.setattr(config, "timeline_capacity", 2048)
-            on.append(loop())
-            monkeypatch.setattr(config, "timeline_capacity", 0)
-            off.append(loop())
-        ratio = min(on) / min(off)
-        assert ratio < 1.35, (
-            f"timeline overhead {ratio:.2f}x (on={min(on):.3f}s "
-            f"off={min(off):.3f}s for {n} queries)"
         )

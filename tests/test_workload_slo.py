@@ -4,8 +4,7 @@ stats-histogram deltas, alert/burn policy, failures naming their
 rule/key), per-fingerprint latency quantiles + ``?by=p99``, the
 closed-loop chaos scenario end-to-end (real cluster, HTTP + binary
 sessions, CDC consumers, replica kill/restart, breaker trip, settle,
-verdict), the `GET /slo`/console `SLO` surfaces, and the bench
-mixed-workload block persisting ``BENCH_SLO_r{N}.json``."""
+verdict), and the `GET /slo`/console `SLO` surfaces."""
 
 import base64
 import io
@@ -365,45 +364,3 @@ class TestSurfaces:
         buf = io.StringIO()
         Console(stdout=buf).onecmd("STATS QUERIES 5")
         assert "p99 ms" in buf.getvalue()
-
-
-# ---------------------------------------------------------------------------
-# bench wiring: the mixed-workload block + headline extras
-# ---------------------------------------------------------------------------
-
-
-class TestBenchWiring:
-    def test_mixed_slo_block_persists_report(self, tmp_path, monkeypatch):
-        import bench
-
-        monkeypatch.setenv("BENCH_SLO_SEED", "5")
-        monkeypatch.setenv("BENCH_SLO_PERSONS", "50")
-        monkeypatch.setenv("BENCH_SLO_SESSIONS", "3")
-        monkeypatch.setenv("BENCH_SLO_OPS", "8")
-        block = bench.run_mixed_slo_block(99, str(tmp_path))
-        assert block["verdict"] in ("pass", "fail")
-        assert "burn" in block and "schedule_digest" in block
-        path = tmp_path / "BENCH_SLO_r99.json"
-        assert path.exists()
-        doc = json.loads(path.read_text())
-        assert doc["slo"]["verdict"] == block["verdict"]
-        assert doc["schedule_digest"] == block["schedule_digest"]
-        assert doc["chaos"]["seed"] == 5
-
-    def test_headline_carries_verdict_and_burn(self):
-        import bench
-
-        out = {
-            "metric": "m", "value": 1.0, "unit": "q/s",
-            "vs_baseline": 1.0,
-            "extras": {
-                "slo": {
-                    "verdict": "pass", "burn": 0.4,
-                    "failures": [], "calls": 100,
-                },
-            },
-        }
-        line = json.loads(bench.compact_line(out))
-        assert line["extras"]["slo"] == {
-            "verdict": "pass", "burn": 0.4, "failures": [],
-        }
