@@ -1425,6 +1425,8 @@ class TpuMatchSolver:
         # padding) — observing there would record tracing artifacts as if
         # they were query execution
         rec = self.sched.recording
+        if pushdown and self._folds_root(steps, pushdown):
+            return self._apply_count_pushdown(None, pushdown, root=steps[0])
         table = Table(count=1, width=0)
         for step in steps:
             if table.empty():
@@ -1550,9 +1552,13 @@ class TpuMatchSolver:
         projection); here every terminal hop collapses to one O(E)
         segment-sum pass — ``w_k[v] = Σ_{edges v→u} emask(e)·mask(u)·
         w_{k+1}[u]`` (a sparse matvec over the edge list) — and the count
-        is ``Σ_rows w_1[src]``. This keeps the per-query device program at
-        O(E + V) instead of O(result rows), which is what makes batched
-        COUNT throughput independent of fan-out.
+        is ``w_1`` summed over the chain's sources: ``Σ_rows w_1[src]``
+        over the rows of the table the plan's prefix built, or, where
+        that prefix is the source's root step alone (`_folds_root`),
+        ``Σ_{v in the root's range} mask(v)·w_1[v]`` with no table at
+        all (`_apply_count_pushdown`). This keeps the per-query device
+        program at O(E + V) instead of O(result rows), which is what
+        makes batched COUNT throughput independent of fan-out.
         """
         if self.count_only_name() is None or self.stmt.group_by or self._not_compiled:
             return []
@@ -1660,17 +1666,65 @@ class TpuMatchSolver:
                 return None
         return step
 
-    def _apply_count_pushdown(self, table: Table, steps: List[PlanStep]) -> Table:
+    @staticmethod
+    def _pushdown_source(steps: List[PlanStep]) -> str:
         first = steps[0]
-        src_alias = (
-            first.edge.to_alias if first.reverse else first.edge.from_alias
+        return first.edge.to_alias if first.reverse else first.edge.from_alias
+
+    def _folds_root(self, prefix: List[PlanStep], steps: List[PlanStep]) -> bool:
+        """Whether the plan's ``prefix`` (what precedes the pushdown's
+        ``steps``) is exactly the root step of the pushdown's source: the
+        count then needs no row of that root, only its mask (see
+        `_apply_count_pushdown`). A cartesian with an earlier table, an
+        expansion before the chain and a path length all leave a longer
+        prefix or a table to read, and keep the rows."""
+        return (
+            len(prefix) == 1
+            and prefix[0].kind == "root"
+            and prefix[0].alias == self._pushdown_source(steps)
+            and not self._path_lens
         )
-        srcs = table.cols.get(src_alias)
-        if srcs is None:
-            raise Uncompilable(f"alias {src_alias} not bound before expansion")
+
+    def _apply_count_pushdown(
+        self,
+        table: Optional[Table],
+        steps: List[PlanStep],
+        root: Optional[PlanStep] = None,
+    ) -> Table:
+        """The count of a chain of pushed-down ``steps``: the weight
+        chain's ``w_1`` (`_pushdown_weights`) summed over its sources.
+
+        With ``table`` the sources are the rows the plan's prefix bound:
+        ``Σ_rows w_1[src]``, a gather through the source column. With
+        ``root`` (the prefix is that root step alone, `_folds_root`) the
+        root is folded and never materialised: its candidates are a
+        contiguous range under a mask (or an index's seeds under one,
+        `_root_index`), and the same sum, term for term, is
+        ``Σ_idx mask(idx)·w_1[idx]``: two slices, a ``where`` and a
+        reduction. No compaction, no table column and no observed size,
+        so nothing a replay with other parameters can overflow. Counted
+        where Python lowers it (a recording, and each trace of a replay):
+        ``plan.count.root_fold`` / ``plan.count.root_rows``."""
+        if root is not None:
+            metrics.incr("plan.count.root_fold")
+            srcs, mask = self._folded_root(root)
+        else:
+            metrics.incr("plan.count.root_rows")
+            src_alias = self._pushdown_source(steps)
+            srcs, mask = table.cols.get(src_alias), None
+            if srcs is None:
+                raise Uncompilable(
+                    f"alias {src_alias} not bound before expansion"
+                )
+
+        def summed(w, zero):
+            per_src = K.take_pad(w, srcs, zero)
+            if mask is not None:
+                per_src = jnp.where(mask, per_src, zero)
+            return per_src.sum()
+
         w = self._pushdown_weights(steps, jnp.int32)
-        per_row = K.take_pad(w, srcs, jnp.int32(0))
-        total_dev = per_row.sum()
+        total_dev = summed(w, jnp.int32(0))
         if self.sched.recording:
             # int32 overflow guard (x64 is disabled on TPU): a float32 twin
             # of the whole weight chain detects wraps anywhere in the
@@ -1679,7 +1733,7 @@ class TpuMatchSolver:
             # Record-time only: the snapshot is immutable, so replay sees
             # the same data.
             wf = self._pushdown_weights(steps, jnp.float32)
-            approx = float(K.take_pad(wf, srcs, jnp.float32(0)).sum())
+            approx = float(summed(wf, jnp.float32(0)))
             exact = int(total_dev)
             if not (
                 0 <= approx < 2**31 * 0.99
@@ -1807,14 +1861,41 @@ class TpuMatchSolver:
                 new_w = new_w + K.indptr_segment_sum(vals, ip, vb, hull)
         return new_w
 
+    def _folded_root(self, root: PlanStep):
+        """`_root_index` of a root step a COUNT folds, under the
+        ``tpu.step`` span and frontier histogram `solve_table` gives a
+        materialised root at the recording (where the mask's count is a
+        free sync), so PROFILE keeps its line for the step."""
+        if not self.sched.recording:
+            return self._root_index(root.alias)
+        from orientdb_tpu.obs.registry import obs as _obs
+        from orientdb_tpu.obs.trace import span as _span
+
+        with _span("tpu.step", step=root.describe(), stage="count-fold") as sp:
+            idx, mask = self._root_index(root.alias)
+            n = int(K.mask_count(mask))
+            sp.set("frontier_rows", n)
+        _obs.observe_size("tpu.frontier_rows", n)
+        return idx, mask
+
     def _root_candidates(self, alias: str):
+        """The root's admitted vertices, compacted: the indices of
+        `_root_index` where its mask holds, sized by the observed count.
+        Returns (candidates, host count, device count)."""
+        idx, mask = self._root_index(alias)
+        cand, n, n_dev = self._compact(mask)
+        return K.index_at(idx, cand), n, n_dev
+
+    def _root_index(self, alias: str):
         """Candidate scan for a root alias, restricted to the dense-index
         HULL of its class filters' polymorphic closures — the snapshot
         lays each concrete class out contiguously, so a `{class:Person}`
         root scans |Person|-ish slots instead of all V (the device analog
         of [E] FetchFromClassExecutionStep iterating only the class's
         clusters). Admission masks still run in full (the hull can
-        contain foreign vertices)."""
+        contain foreign vertices). Returns ``(idx, mask)``: the scanned
+        indices (a ``K.IndexRange``, or the seed array of an index-seeded
+        root) and the admission mask over them."""
         node = self.pattern.nodes[alias]
         if alias in self._root_seeds:
             if self.sched.recording:
@@ -1826,10 +1907,7 @@ class TpuMatchSolver:
                 idx = jnp.asarray(arr)
             else:
                 idx = self.seed_box.current[alias]  # [cap] replay input
-            mask = self._node_masks[alias](idx) & (idx >= 0)
-            cand, n, n_dev = self._compact(mask)
-            cand = K.take_pad(idx, cand, jnp.int32(-1))
-            return cand, n, n_dev
+            return idx, self._node_masks[alias](idx) & (idx >= 0)
         V = self.dg.num_vertices
         start, end = 0, V
         has_class = False
@@ -1851,9 +1929,7 @@ class TpuMatchSolver:
             self.dg, self.tier, start, size,
             K.bucket(max(size + slab, 1)), slo if slab else 0, slab,
         )
-        mask = self._node_masks[alias](idx)
-        cand, n, n_dev = self._compact(mask)
-        return K.index_at(idx, cand), n, n_dev
+        return idx, self._node_masks[alias](idx)
 
     def _root(self, table: Table, alias: str) -> Table:
         cand, n, n_dev = self._root_candidates(alias)

@@ -4,7 +4,9 @@ Terminal chain expansions under a lone COUNT(*) collapse to segment-sum
 weight passes (`TpuMatchSolver._apply_count_pushdown`) instead of
 materializing binding tables; these tests pin result parity vs the oracle
 across directions, edge predicates, reversed arrows, multi-hop chains,
-self-loops, and confirm the optimization actually engages.
+self-loops, and confirm the optimization actually engages; and, where the
+chain begins at the plan's only root, that the count folds that root as a
+mask over its range (`_folds_root`) and every other plan keeps its rows.
 """
 
 import pytest
@@ -430,3 +432,258 @@ class TestAHullUnderDeltas:
         finally:
             drain_warmups()
             db.detach_snapshot()
+
+
+# -- a COUNT folds its root as a mask over the root's range ---------------------
+
+
+FOLD, ROWS = "plan.count.root_fold", "plan.count.root_rows"
+
+
+def _counted(*names):
+    """The named counters, read after every background trace finished
+    (a plan's AOT warm-up lowers it once more, and a lowering counts)."""
+    from orientdb_tpu.exec.tpu_engine import drain_warmups
+    from orientdb_tpu.utils.metrics import metrics
+
+    drain_warmups()
+    return [metrics.counter(n) for n in names]
+
+
+_rerecords = lambda: sum(
+    _counted("plan_cache.miss", "plan_cache.overflow_rerecord")
+)
+
+#: a COUNT rooted at the messages, %s the root's alias (a name of its own
+#: gives each case a plan of its own in the module's graph)
+MSG_COUNT = (
+    "MATCH {class:Message, as:%s, where:(length > :minLen)}-hasCreator->"
+    "{as:p, where:(age < :maxAge)} RETURN count(*) AS n"
+)
+NONE, FEW, EVERY = 2500, 1950, 0  # lengths lie in [1, 2000)
+
+
+def _msg_count(snap, p):
+    P = int(snap.class_vertex_range["person"][1])
+    hc = snap.edge_classes["hasCreator"]
+    long_msg = snap.v_columns["length"].values[P:] > p["minLen"]
+    return int((long_msg & (snap.v_columns["age"].values[hc.dst] < p["maxAge"])).sum())
+
+
+def _hull_root_with_a_predicate(request):
+    db, snap, _ = request.getfixturevalue("snb_counts")
+    p = {"minLen": 900, "maxAge": 30}
+    got = db.query(MSG_COUNT % "hull", p, engine="tpu", strict=True).to_dicts()
+    return got, [{"n": _msg_count(snap, p)}]
+
+
+def _root_with_no_predicate(request):
+    db, snap, _ = request.getfixturevalue("snb_counts")
+    sql = "MATCH {class:Person, as:bare}-knows->{as:f} RETURN count(*) AS n"
+    got = db.query(sql, engine="tpu", strict=True).to_dicts()
+    return got, [{"n": int(snap.edge_classes["knows"].num_edges)}]
+
+
+def _slab_segment_after_a_write(request):
+    """A property write leaves the topology clean, so the pushdown
+    answers, and the root's range is the class hull and then the slab."""
+    from orientdb_tpu.exec.tpu_engine import TpuMatchSolver, drain_warmups
+    from orientdb_tpu.ops import csr as K
+    from orientdb_tpu.storage.deltas import arm_delta_maintenance
+
+    db = Database("fold_slab")
+    db.schema.create_vertex_class("Writer")
+    db.schema.create_vertex_class("Msg")
+    db.schema.create_edge_class("Wrote")
+    writers = [db.new_vertex("Writer", age=20 + i) for i in range(6)]
+    msgs = [db.new_vertex("Msg", length=i) for i in range(10)]
+    for i, m in enumerate(msgs):
+        db.new_edge("Wrote", m, writers[i % 6])
+    arm_delta_maintenance(db, spare_vertices=16, spare_edges=16)
+    sql = (
+        "MATCH {class:Msg, as:m, where:(length > :l)}-Wrote->"
+        "{as:p, where:(age < :a)} RETURN count(*) AS n"
+    )
+    params = {"l": 4, "a": 24}
+    ask = lambda engine: db.query(
+        sql, params, engine=engine, strict=(engine == "tpu")
+    ).to_dicts()
+    try:
+        assert ask("tpu") == ask("oracle") == [{"n": 4}]  # messages 6 to 9
+        msgs[1].set("length", 50)
+        db.save(msgs[1])
+        snap = db.current_snapshot(require_fresh=True)
+        assert not snap._overlay.topology_dirty
+        idx, _mask = TpuMatchSolver(db, parse_cached(sql), params)._root_index("m")
+        assert isinstance(idx, K.IndexRange) and idx.slab_size == 16
+        assert ask("oracle") == [{"n": 5}]
+        return ask("tpu"), [{"n": 5}]
+    finally:
+        drain_warmups()
+        db.detach_snapshot()
+
+
+def _root_seeded_from_an_index(request):
+    """An indexed ``uid`` seeds the root from the host's index: the fold
+    sums under the mask over the seed array, at most its capacity long."""
+    from orientdb_tpu.exec.tpu_engine import drain_warmups
+
+    db = Database("fold_seeded")
+    person = db.schema.create_vertex_class("Person")
+    person.create_property("uid", PropertyType.LONG)
+    db.schema.create_edge_class("knows")
+    vs = [db.new_vertex("Person", uid=i, age=20 + i) for i in range(30)]
+    for i in range(29):
+        db.new_edge("knows", vs[i], vs[i + 1])
+        db.new_edge("knows", vs[i], vs[(i * 7 + 3) % 30])
+    db.command("CREATE INDEX Person.uid ON Person (uid) UNIQUE")
+    snap = attach_fresh_snapshot(db)
+    sql = (
+        "MATCH {class:Person, as:p, where:(uid = :u)}-knows->{as:f}-knows->"
+        "{as:g, where:(age > :a)} RETURN count(*) AS n"
+    )
+    try:
+        first = db.query(sql, {"u": 99, "a": 0}, engine="tpu", strict=True)
+        assert first.to_dicts() == [{"n": 0}]  # recorded on no such person
+        (variants,) = snap._plan_cache.values()
+        assert variants.plans[0].seed_spec, "the root was not seeded"
+        before = _rerecords()
+        p = {"u": 4, "a": 25}
+        got = db.query(sql, p, engine="tpu", strict=True).to_dicts()
+        assert _rerecords() == before
+        return got, db.query(sql, p, engine="oracle").to_dicts()
+    finally:
+        drain_warmups()
+        db.detach_snapshot()
+
+
+def _replayed_on_every_root(recorded_on, alias):
+    def case(request):
+        db, snap, _ = request.getfixturevalue("snb_counts")
+        sql = MSG_COUNT % alias
+        first = {"minLen": recorded_on, "maxAge": 60}
+        got = db.query(sql, first, engine="tpu", strict=True).to_dicts()
+        assert got == [{"n": _msg_count(snap, first)}]
+        # before the fold the first recording skipped the pushdown for
+        # want of a row, and both sized a buffer by their few candidates:
+        # the widest parameters then forced a recording anew
+        before = _rerecords()
+        widest = {"minLen": EVERY, "maxAge": 60}
+        got = db.query(sql, widest, engine="tpu", strict=True).to_dicts()
+        assert _rerecords() == before, "the replay was recorded anew"
+        return got, [{"n": _msg_count(snap, widest)}]
+
+    return case
+
+
+def _vmapped_group_of_lanes(request):
+    import orientdb_tpu.obs.timeline as TL
+    from orientdb_tpu.exec.tpu_engine import _GROUP_MIN
+
+    db, snap, _ = request.getfixturevalue("snb_counts")
+    sql = MSG_COUNT % "lane"
+    plist = [
+        {"minLen": l, "maxAge": a}
+        for l, a in [(NONE, 60), (FEW, 60), (EVERY, 30), (900, 45), (EVERY, 60)]
+    ]
+    assert len(plist) >= _GROUP_MIN
+    ask = lambda: db.query_batch(
+        [sql] * len(plist), plist, engine="tpu", strict=True
+    )
+    ask()
+    before = _rerecords()  # and the first group's program is compiled
+    TL.recorder.reset()
+    got = [rs.to_dicts()[0] for rs in ask()]
+    assert [r["path"] for r in TL.recorder.records()] == ["group"]
+    assert _rerecords() == before
+    return got, [{"n": _msg_count(snap, p)} for p in plist]
+
+
+def _path_length_of_two_seeded_roots(request):
+    import numpy as np
+
+    db, snap, _ = request.getfixturevalue("snb_counts")
+    k = snap.edge_classes["knows"]
+    a = int(np.flatnonzero(np.diff(k.indptr_out))[0])
+    b = int(k.dst[k.indptr_out[a]])
+    assert a != b
+    sql = (
+        "MATCH {class:Person, as:a, where:(uid = :person1Id)}, "
+        "{class:Person, as:b, where:(uid = :person2Id)} "
+        "RETURN shortestPath(a, b, 'BOTH', 'knows').size() - 1 AS len"
+    )
+    got = db.query(
+        sql, {"person1Id": a, "person2Id": b}, engine="tpu", strict=True
+    ).to_dicts()
+    return got, [{"len": 1}]
+
+
+def _on_the_social_graph(sql):
+    def case(request):
+        db = request.getfixturevalue("sdb")
+        got = db.query(sql, engine="tpu", strict=True).to_dicts()
+        return got, db.query(sql, engine="oracle").to_dicts()
+
+    return case
+
+
+#: case -> (what runs it, the counter its lowering moves: FOLD where the
+#: root is folded, ROWS where the pushdown reads a table, None without one)
+ROOT_CASES = {
+    "hull_root_with_a_predicate": (_hull_root_with_a_predicate, FOLD),
+    "root_with_no_predicate": (_root_with_no_predicate, FOLD),
+    "slab_segment_after_a_write": (_slab_segment_after_a_write, FOLD),
+    "root_seeded_from_an_index": (_root_seeded_from_an_index, FOLD),
+    "recorded_on_no_root": (_replayed_on_every_root(NONE, "none"), FOLD),
+    "recorded_on_few_roots": (_replayed_on_every_root(FEW, "few"), FOLD),
+    "vmapped_group_of_lanes": (_vmapped_group_of_lanes, FOLD),
+    # the plans that keep their rows
+    "two_components": (
+        _on_the_social_graph(
+            "MATCH {class:Profiles, as:a, where:(uid < 2)}, "
+            "{class:Profiles, as:p}-HasFriend->{as:f} RETURN count(*) AS n"
+        ),
+        None,
+    ),
+    "row_returning_statement": (
+        _on_the_social_graph(
+            "MATCH {class:Profiles, as:p, where:(age > 26)}-HasFriend->{as:f} "
+            "RETURN p.name AS p, f.name AS f"
+        ),
+        None,
+    ),
+    "closing_edge": (
+        _on_the_social_graph(
+            "MATCH {class:Profiles, as:p}-HasFriend->{as:f}-HasFriend->{as:g}, "
+            "{as:p}-HasFriend->{as:g} RETURN count(*) AS n"
+        ),
+        None,
+    ),
+    "path_length_of_two_seeded_roots": (_path_length_of_two_seeded_roots, None),
+    "an_expansion_before_the_chain": (
+        _on_the_social_graph(
+            "MATCH {class:Profiles, as:p}.outE('HasFriend'){as:e}.inV(){as:f}"
+            "-HasFriend->{as:g} RETURN count(*) AS n"
+        ),
+        ROWS,
+    ),
+}
+
+
+class TestACountFoldsItsRoot:
+    """Where a COUNT pushdown's chain begins at the plan's only other
+    step, the root of its source, the count is the weight chain summed
+    under the root's mask over the root's range
+    (`TpuMatchSolver._folds_root`): no candidate is compacted, no size
+    observed. Every other plan reads the table its prefix built."""
+
+    @pytest.mark.parametrize("case", sorted(ROOT_CASES))
+    def test_the_count_is_the_references(self, request, case):
+        run, moves = ROOT_CASES[case]
+        before = _counted(FOLD, ROWS)
+        got, want = run(request)
+        moved = [b > a for a, b in zip(before, _counted(FOLD, ROWS))]
+        key = lambda rows: sorted(str(sorted(r.items())) for r in rows)
+        assert key(got) == key(want), case
+        assert any(v for r in want for v in r.values()), "nothing was counted"
+        assert moved == [moves == FOLD, moves == ROWS]

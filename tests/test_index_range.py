@@ -7,7 +7,10 @@ in the replay's jaxpr); and the ``plan.read.range`` / ``plan.read.gather``
 counters. Beside them, the COUNT pushdown's segment sums over an edge
 class's vertex hull (``ops/device_graph.vertex_hull``): what their
 replays gather, what ``plan.segsum.hull`` / ``plan.segsum.full`` count,
-and that a seed does not move a hull. No chip and no time in any of it.
+and that a seed does not move a hull; and a COUNT that folds its root
+(``plan.count.root_fold``): the replays of the benchmark's four scan
+statements compact nothing and gather through no candidate. No chip and
+no time in any of it.
 """
 
 import numpy as np
@@ -240,9 +243,15 @@ CREATOR_1HOP = (
 LOWERED = {
     # statement, parameters, the root's class
     "friends": (FRIENDS, {"personId": 17}, "Person"),
-    # few candidates: the COUNT's own gather through them, at their
-    # capacity, stays well under the hull's bucket
+    # a COUNT folds its root (TestACountsRootIsFolded): whether its
+    # parameters admit few messages or every one, nothing is gathered
+    # through the candidates, at the hull's bucket or at their capacity
     "creator_1hop": (CREATOR_1HOP, {"minLen": 1950, "maxAge": 60}, "Message"),
+    "creator_1hop_widest": (
+        CREATOR_1HOP.replace("as:m", "as:msg"),
+        {"minLen": 0, "maxAge": 60},
+        "Message",
+    ),
 }
 
 
@@ -286,6 +295,13 @@ class TestLowering:
         if shape == "friends":
             # a rooted row read: nothing in it is sized by the class
             assert max(lengths) < hull // 8, sorted(set(lengths))
+        else:
+            from orientdb_tpu.exec.tpu_engine import _cap_of
+
+            length = snap.v_columns["length"].values[lo:hi]
+            cap = _cap_of(int((length > params["minLen"]).sum()))
+            assert (cap > hull) == (shape == "creator_1hop_widest")
+            assert cap not in lengths, sorted(set(lengths))
         assert ranged > 0 and gathered > 0
 
     def test_the_vmapped_group_replay_has_no_hull_gather_either(self, snb):
@@ -338,7 +354,7 @@ class TestLowering:
             db.detach_snapshot()
 
 
-# -- the COUNT pushdown's segment sums run over the edge class's vertex hull ------
+# -- a COUNT folds its root: no candidate is compacted or gathered through --------
 
 KNOWS_1HOP = (
     "MATCH {class:Person, as:p, where:(age > :minAge)}-knows->"
@@ -348,6 +364,82 @@ KNOWS_2HOP = (
     "MATCH {class:Person, as:p, where:(age > :minAge)}-knows->{as:f}-knows->"
     "{as:g, where:(age < :maxAge)} RETURN count(*) AS n"
 )
+CONFIG5 = (
+    "MATCH {class:Person, as:p, where:(age > :minAge)}.outE('knows')"
+    "{where:(creationDate > :d)}.inV(){as:f, where:(age < :maxAge)}, "
+    "{class:Message, as:m}-hasCreator->{as:f} RETURN count(*) AS n"
+)
+#: the four statements of the benchmark's scan_4s mix: statement, the
+#: parameters that lead its pool (the widest), the root's class
+SCAN_4S = {
+    "config5": (CONFIG5, {"minAge": 40, "d": 12_000, "maxAge": 30}, "Person"),
+    "creator_1hop": (CREATOR_1HOP, {"minLen": 200, "maxAge": 60}, "Message"),
+    "knows_2hop": (KNOWS_2HOP, {"minAge": 20, "maxAge": 70}, "Person"),
+    "knows_1hop": (KNOWS_1HOP, {"minAge": 20, "maxAge": 70}, "Person"),
+}
+
+
+def _folds():
+    """(roots folded, tables read) by the COUNTs lowered so far, every
+    background trace finished."""
+    from orientdb_tpu.exec.tpu_engine import drain_warmups
+
+    drain_warmups()
+    return metrics.counter("plan.count.root_fold"), metrics.counter(
+        "plan.count.root_rows"
+    )
+
+
+def _calls(jaxpr):
+    """The names of the jitted functions a jaxpr calls, nested ones too."""
+    return {
+        e.params.get("name")
+        for e in _eqns(jaxpr)
+        if e.primitive.name in ("pjit", "jit")
+    }
+
+
+class TestACountsRootIsFolded:
+    @pytest.mark.parametrize("shape", sorted(SCAN_4S))
+    def test_a_scan_replay_compacts_and_gathers_no_root(self, snb, shape):
+        from orientdb_tpu.exec.tpu_engine import _cap_of
+
+        db, snap = snb
+        sql, params, root_class = SCAN_4S[shape]
+        fold0, rows0 = _folds()
+        # a name of its own, so that the statement is recorded here
+        rows, plan, _reads_counted = _record(
+            db, snap, sql.replace("AS n", "AS folded"), params
+        )
+        assert rows[0]["folded"] > 0
+        jaxpr = jax.make_jaxpr(plan._replay)(
+            plan._arg_subset(), plan._dyn_args(params)
+        ).jaxpr
+        # one a lowering: the eager recording (its float32 twin folds
+        # under the same count) and one trace of the replay, which the
+        # background warm-up and this jaxpr share
+        assert _folds() == (fold0 + 2, rows0)
+        # no sort (jnp.nonzero's) and no prefix-sum-and-search compaction
+        assert "sort" not in {e.primitive.name for e in _eqns(jaxpr)}
+        called = _calls(jaxpr)
+        assert "compact_indices" not in called and "_segment_sum" in called
+        # and no gather through the root: neither as long as its hull's
+        # bucket nor as its candidates' capacity would have been
+        lo, hi = snap.vertex_hull(root_class)
+        column, bound = ("age", "minAge") if root_class == "Person" else ("length", "minLen")
+        admitted = int((snap.v_columns[column].values[lo:hi] > params[bound]).sum())
+        assert admitted > (hi - lo) // 2, "the widest parameters admit most roots"
+        lengths = _gather_index_lengths(jaxpr)
+        assert lengths
+        assert not {K.bucket(hi - lo), _cap_of(admitted)} & set(lengths), sorted(
+            set(lengths)
+        )
+        # nothing was sized by what the recording saw
+        assert plan.solver.sched.values == [rows[0]["folded"]]
+
+
+# -- the COUNT pushdown's segment sums run over the edge class's vertex hull ------
+
 #: statement, its weight passes (one a hop)
 SEGSUMS = {"knows_1hop": (KNOWS_1HOP, 1), "knows_2hop": (KNOWS_2HOP, 2)}
 AGES = {"minAge": 40, "maxAge": 30}
