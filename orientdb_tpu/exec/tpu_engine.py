@@ -633,6 +633,12 @@ def build_bitmap_hops(dg: DeviceGraph, items, sched=None, tier=None,
 # ---------------------------------------------------------------------------
 
 
+#: a plan's argument beside the graph's arrays: the constant tail of its
+#: COUNT's weight chain (`TpuMatchSolver._pushdown_weights`). No graph
+#: array has a key under ``plan:``
+_COUNT_W = "plan:count_w"
+
+
 class TpuMatchSolver:
     def __init__(
         self,
@@ -650,6 +656,12 @@ class TpuMatchSolver:
         # numeric parameters compile to reads of this box so one cached
         # plan replays for any value (predicates.ParamBox)
         self.param_box = ParamBox(params)
+        #: compiled edge predicates, one a (class, WHERE, visible aliases)
+        self._edge_where_fns: Dict[tuple, tuple] = {}
+        #: device arrays the recording computed once for every replay
+        #: (`_pushdown_weights`): the plan hands them to its replays as
+        #: jit arguments beside the graph's (`_CompiledPlan._arg_subset`)
+        self.plan_consts: Dict[str, jnp.ndarray] = {}
         snap = db.current_snapshot(require_fresh=True)
         if snap is None:
             raise Uncompilable("no fresh snapshot attached")
@@ -1051,7 +1063,9 @@ class TpuMatchSolver:
         Mirrors oracle.check_node: class closure ∧ rid ∧ WHERE. A WHERE
         referencing earlier bindings (``alias.prop``) compiles against the
         alias-visibility set at this node's first bind; the mask then
-        needs env["bindings"] at evaluation (``mask.uses_bindings``)."""
+        needs env["bindings"] at evaluation (``mask.uses_bindings``).
+        ``mask.uses_params`` says whether its WHERE reads a dynamic
+        parameter: without one the mask is the same on every replay."""
         parts = []
         uses_bindings = False
         has_class = any(f.class_name for f in node.filters)
@@ -1101,6 +1115,7 @@ class TpuMatchSolver:
             return m
 
         mask.uses_bindings = uses_bindings
+        mask.uses_params = any(getattr(p, "uses_params", False) for p in parts)
         return mask
 
     def _class_mask_fn(self, ids: jnp.ndarray):
@@ -1118,7 +1133,13 @@ class TpuMatchSolver:
         """Edge-property predicate over edge ids; with ``visible`` given,
         ``alias.prop`` references to those (vertex) aliases compile too —
         the returned fn then carries ``uses_bindings`` and needs
-        env["bindings"] arrays aligned with its idx slots."""
+        env["bindings"] arrays aligned with its idx slots. Compiled once
+        a solver: every lowering of a pass asks for it again, and
+        ``uses_params`` (`compile_predicate`) is asked before any."""
+        key = (concrete, id(where), frozenset(visible or ()))
+        hit = self._edge_where_fns.get(key)
+        if hit is not None:
+            return hit[1]
         dec = self.dg.edges[concrete]
         scope = ColumnScope(
             dec.columns,
@@ -1129,10 +1150,9 @@ class TpuMatchSolver:
             visible_aliases=visible or set(),
         )
         fn = compile_predicate(where, scope, self.param_box)
-        try:
-            fn.uses_bindings = scope.uses_bindings
-        except AttributeError:  # pragma: no cover - plain closures accept attrs
-            pass
+        fn.uses_bindings = scope.uses_bindings
+        # the expression rides along: its id is the key while it lives
+        self._edge_where_fns[key] = (where, fn)
         return fn
 
     # -- execution ----------------------------------------------------------
@@ -1749,7 +1769,73 @@ class TpuMatchSolver:
         t.count_dev = total_dev
         return t
 
+    def _pass_shape(self, step: PlanStep):
+        """A pushed-down step's weight pass: ``(dst alias, edge classes,
+        directions)``, the directions as the pass walks them."""
+        e = step.edge
+        direction = e.item.direction
+        if step.reverse:
+            direction = _REVERSE_DIR[direction]
+        return (
+            e.from_alias if step.reverse else e.to_alias,
+            self._resolve_edge_classes(e.item),
+            ("out", "in") if direction == "both" else (direction,),
+        )
+
+    def _const_passes(self, steps: List[PlanStep]) -> int:
+        """How many passes at the END of a COUNT's weight chain give the
+        same weights on every replay: the pass after the last hop starts
+        from all-ones, and a pass whose edge filter and destination mask
+        read no dynamic parameter (``uses_params``, observed while the
+        predicate compiled; a literal and a static parameter are
+        constants of the plan) turns constant weights into constant
+        weights. Only over what cannot change under the plan: a
+        delta-maintained snapshot patches columns in place between two
+        replays, and a mesh's pass has a sharding of its own: there
+        every pass stays live (a tiered snapshot has no pushdown)."""
+        if self.overlay is not None or self.dg.mesh_graph is not None:
+            return 0
+        n = 0
+        for step in reversed(steps):
+            dst, classes, _dirs = self._pass_shape(step)
+            f = step.edge.item.edge_filter
+            if self._node_masks[dst].uses_params or (
+                f is not None
+                and f.where is not None
+                and any(self._edge_where(c, f.where).uses_params for c in classes)
+            ):
+                break
+            n += 1
+        return n
+
+    def _pass_hull(self, step: PlanStep) -> Tuple[int, int]:
+        """``[lo, hi)`` outside which a pass's weights are zero: its
+        segment sums land on the vertex hulls of its edge classes
+        (`ops/device_graph.vertex_hull`), this is their union."""
+        _dst, classes, dirs = self._pass_shape(step)
+        hulls = [
+            dec.hull_out if d == "out" else dec.hull_in
+            for dec in (self.dg.edges[c] for c in classes)
+            if dec.num_edges
+            for d in dirs
+        ]
+        if not hulls:
+            return (0, 0)
+        return min(lo for lo, _ in hulls), max(hi for _, hi in hulls)
+
     def _pushdown_weights(self, steps: List[PlanStep], dtype) -> jnp.ndarray:
+        """``w_1`` of the chain of pushed-down ``steps``, built pass by
+        pass from the last hop to the first.
+
+        The int32 chain's constant tail (`_const_passes`) is evaluated
+        once, at the recording, and kept on the solver trimmed to its
+        vertex hull (`plan_consts`); a replay reads it as a jit argument
+        (``dg.arrays``, see `_CompiledPlan._arg_subset`), pads it where
+        it is read and lowers only the passes before it. The float32
+        twin of the overflow guard is recording-only and stays whole.
+        Counted where Python lowers the int32 chain (a recording, and
+        each trace of a replay): ``plan.count.pass_const`` a pass taken
+        from the plan, ``plan.count.pass_live`` a pass lowered."""
         V = self.dg.num_vertices
         vb = K.bucket(max(V, 1))
         mg = self.dg.mesh_graph
@@ -1767,28 +1853,42 @@ class TpuMatchSolver:
         # recording-only spans, like solve_table: replays re-trace this
         # under jax.jit, where a span would time XLA tracing, not work
         rec = self.sched.recording
+
+        def chain(part, w):
+            for step in reversed(part):
+                # one span per PatternEdge hop: the COUNT pushdown fuses all
+                # hops into one weight chain, so the honest per-hop timing is
+                # each hop's weight-pass build/dispatch
+                with _span(
+                    "tpu.step", step=step.describe(), stage="count-pushdown"
+                ) if rec else nullcontext():
+                    w = self._pushdown_weight_step(step, w, univ, mg, vb, dtype)
+            return w
+
+        kept = self._const_passes(steps) if dtype == jnp.int32 else 0
+        live, const = steps[: len(steps) - kept], steps[len(steps) - kept :]
         w = None  # None ≡ all-ones (the implicit weight after the last hop)
-        for step in reversed(steps):
-            # one span per PatternEdge hop: the COUNT pushdown fuses all
-            # hops into one weight chain, so the honest per-hop timing is
-            # each hop's weight-pass build/dispatch
-            with _span(
-                "tpu.step", step=step.describe(), stage="count-pushdown"
-            ) if rec else nullcontext():
-                w = self._pushdown_weight_step(step, w, univ, mg, vb, dtype)
-        return w
+        if const:
+            lo, hi = self._pass_hull(const[0])
+            if rec:
+                self.plan_consts[_COUNT_W] = chain(const, None)[lo:hi]
+            # the recording reads what a replay will: a hull that missed
+            # a vertex would fail the float32 twin's comparison there
+            tail = (self.plan_consts if rec else self.dg.arrays)[_COUNT_W]
+            w = jnp.pad(tail, (lo, vb - hi))
+        if dtype == jnp.int32:
+            metrics.incr_many(
+                {
+                    "plan.count.pass_const": len(const),
+                    "plan.count.pass_live": len(live),
+                }
+            )
+        return chain(live, w)
 
     @jax.named_scope("count.weight_pass")
     def _pushdown_weight_step(self, step, w, univ, mg, vb, dtype):
-        item = step.edge.item
-        direction = item.direction
-        if step.reverse:
-            direction = _REVERSE_DIR[direction]
-        dst_alias = (
-            step.edge.from_alias if step.reverse else step.edge.to_alias
-        )
+        dst_alias, classes, dirs = self._pass_shape(step)
         node_mask = self._node_masks[dst_alias]
-        classes = self._resolve_edge_classes(item)
         # the [vb]-wide precompute only pays for itself where a consumer
         # exists: the mesh path always reads it, the single-device path
         # only for classes whose edge list outnumbers the vertices —
@@ -1799,7 +1899,7 @@ class TpuMatchSolver:
             or any(self.dg.edges[c].num_edges >= vb for c in classes)
             else None
         )
-        f = item.edge_filter
+        f = step.edge.item.edge_filter
         new_w = jnp.zeros(vb, dtype)
         for cname in classes:
             dec = self.dg.edges[cname]
@@ -1812,7 +1912,7 @@ class TpuMatchSolver:
                 if (f is not None and f.where is not None)
                 else jnp.ones(E, bool)
             )
-            for d in ("out", "in") if direction == "both" else (direction,):
+            for d in dirs:
                 # scanning the full out-CSR edge list covers both
                 # directions: eid == position for either walk
                 if mg is not None:
@@ -3268,7 +3368,18 @@ class _CompiledPlan(_AotWarmup):
         #: every dispatch re-ensures them resident (pin + async
         #: prefetch) before grabbing its argument pytree
         self.tier_footprint = frozenset(solver.tier_touched)
+        #: what the recording computed once for every replay
+        #: (`TpuMatchSolver.plan_consts`): jit ARGUMENTS beside the
+        #: graph's arrays, never closed-over constants of the executable
+        self.consts = dict(solver.plan_consts)
+        for key, arr in self.consts.items():
+            solver.dg.adopt_plan_const(self, key, arr)
         self.jitted = jax.jit(self._replay)
+
+    def _arg_subset(self):
+        args = super()._arg_subset()
+        args.update(self.consts)
+        return args
 
     @jax.named_scope("match.core")
     def _replay_core(self, arrays, dyn):

@@ -41,7 +41,9 @@ param_ring      device-resident parameter ring slots
                 (``tpu_engine.ParamRing.stage``)
 prefetched_page speculatively prefetched result pages (transient)
 plan_const      per-class id sets baked into plan executables
-                (``DeviceGraph.class_ids``)
+                (``DeviceGraph.class_ids``); arrays a plan's recording
+                kept for its replays (``DeviceGraph.adopt_plan_const``,
+                unregistered when the plan is collected)
 result_page     elected result pages awaiting host copy (transient)
 ========== ==============================================================
 

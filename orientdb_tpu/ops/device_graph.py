@@ -17,6 +17,7 @@ constants.
 from __future__ import annotations
 
 import threading
+import weakref
 from typing import Dict, List, Optional, Set, Tuple
 
 import jax.numpy as jnp
@@ -286,6 +287,9 @@ class DeviceGraph:
         #: key -> (host_array, shard_pad, fill)
         self._pending: Dict[str, tuple] = {}
         self._pending_lock = threading.Lock()
+        #: weak references to the arrays compiled plans keep beside the
+        #: graph's own (`adopt_plan_const`); replaced, never mutated
+        self._plan_consts: Dict[str, "weakref.ref"] = {}
         self._tls = threading.local()
         v_pad = self._shard_pad_rows(self.num_vertices)
         self._put("v_class", snap.v_class, shard_pad=v_pad, fill=-1)
@@ -538,11 +542,17 @@ class DeviceGraph:
             "adjacency": 0,
             "vertex_columns": 0,
             "edge_columns": 0,
+            "plan_consts": 0,
             "other": 0,
         }
         logical = dict(cats)
-        for key, arr in self._arrays.items():
-            if key.startswith("sh:"):
+        consts = [
+            (k, a) for k, r in self._plan_consts.items() if (a := r()) is not None
+        ]
+        for key, arr in list(self._arrays.items()) + consts:
+            if key.startswith("plan:"):
+                cat = "plan_consts"
+            elif key.startswith("sh:"):
                 cat = "adjacency"
             elif key.startswith("t:") or key.startswith("bk:"):
                 # tier pools/indexes (storage/tiering) and overlay slab
@@ -582,6 +592,23 @@ class DeviceGraph:
             "pruned_bytes": pruned_bytes,
             "pruned_arrays": len(self._pending),
         }
+
+    def adopt_plan_const(self, plan, key: str, arr) -> None:
+        """Account for a device array a compiled ``plan`` keeps beside
+        the graph's own and hands its replays as a jit argument (a
+        COUNT's constant weights, ``exec/tpu_engine``): `memory_report`
+        counts it under ``plan_consts`` while it lives, and the ledger
+        holds it as ``plan_const`` until the plan is collected."""
+        from orientdb_tpu.obs.memledger import memledger
+
+        ident = f"{key}:{id(plan):x}"
+        live = {k: r for k, r in self._plan_consts.items() if r() is not None}
+        live[ident] = weakref.ref(arr)
+        self._plan_consts = live
+        memledger.register("plan_const", self._ledger_owner, ident, arr=arr)
+        weakref.finalize(
+            plan, memledger.unregister, "plan_const", self._ledger_owner, ident
+        )
 
     def class_ids(self, class_name: str) -> jnp.ndarray:
         key = class_name.lower()

@@ -259,6 +259,9 @@ class Compiler:
         self.scope = scope
         self.params = params
         self.allow_depth = allow_depth
+        #: set when a dynamic parameter compiled to a read of the box:
+        #: the predicate's mask then differs from replay to replay
+        self.uses_params = False
 
     # -- entry -------------------------------------------------------------
 
@@ -388,6 +391,7 @@ class Compiler:
             return _const_val(v)
         kind = box.dynamic[key]
         box.used[key] = kind
+        self.uses_params = True
         dtype = jnp.float32 if kind == "float" else jnp.int32
 
         def emit(idx, env, box=box, key=key, dtype=dtype):
@@ -743,5 +747,12 @@ def compile_predicate(
 ) -> BoolFn:
     """Compile a WHERE AST into `fn(idx_array, env) -> bool mask`.
 
+    ``fn.uses_params`` says whether the mask reads a dynamic parameter of
+    a `ParamBox` (what ``box.used`` gained here, or had already): a
+    literal and a static parameter are constants of the compiled plan.
+
     Raises Uncompilable outside the columnar subset."""
-    return Compiler(scope, params, allow_depth=allow_depth).compile_bool(expr)
+    c = Compiler(scope, params, allow_depth=allow_depth)
+    fn = c.compile_bool(expr)
+    fn.uses_params = c.uses_params
+    return fn

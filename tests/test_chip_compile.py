@@ -188,6 +188,11 @@ def test_whole_count_plan_at_real_shapes(one_chip, blocked_cumsum, shape):
                 sharding=one_chip,
             )
 
+        # what the recording kept for its replays (config5: the messages
+        # of each person; the two hops of literals: the whole chain) is
+        # as long as a vertex hull, and grows with it
+        assert sorted(plan.consts) == ["plan:count_w"]
+        dims.update({c.shape[0]: c.shape[0] * scale for c in plan.consts.values()})
         arrays = {k: real(v) for k, v in plan._arg_subset().items()}
         assert max(s.shape[0] for s in arrays.values()) >= EDGES
         dyn = {k: real(v) for k, v in plan._dyn_args(params).items()}

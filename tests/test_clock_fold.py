@@ -485,7 +485,12 @@ class TestKernelScopes:
             db.new_edge("K", vs[i], vs[i + 1])
         snap = attach_fresh_snapshot(db)
         rows = "MATCH {class:P, as:a, where:(n < :k)}-K->{as:b} RETURN a.n AS a, b.n AS b"
-        count = "MATCH {class:P, as:a, where:(n < :k)}-K->{as:b} RETURN count(*) AS c"
+        # the hop reads the parameter, so a replay lowers its weight pass
+        # (one that reads none is the plan's, computed at the recording)
+        count = (
+            "MATCH {class:P, as:a, where:(n < :k)}-K->{as:b, where:(n <= :k)} "
+            "RETURN count(*) AS c"
+        )
         out = {}
         for key, sql in (("rows", rows), ("count", count)):
             known = set(getattr(snap, "_plan_cache", ()))

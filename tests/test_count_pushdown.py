@@ -687,3 +687,293 @@ class TestACountFoldsItsRoot:
         assert key(got) == key(want), case
         assert any(v for r in want for v in r.values()), "nothing was counted"
         assert moved == [moves == FOLD, moves == ROWS]
+
+
+# -- the passes of a weight chain that read no parameter are the plan's -----------
+
+
+CONST, LIVE = "plan.count.pass_const", "plan.count.pass_live"
+
+
+def _lowered(run):
+    """``run()``, and the passes (taken from a plan, lowered) of the
+    chains lowered meanwhile, every background trace finished."""
+    before = _counted(CONST, LIVE)
+    got = run()
+    return got, tuple(b - a for a, b in zip(before, _counted(CONST, LIVE)))
+
+
+def _dense_db(n):
+    """Every vertex to every vertex, loops too: ``n ** (k + 1)`` chains
+    of ``k`` hops."""
+    db = Database(f"dense_{n}")
+    db.schema.create_vertex_class("N")
+    db.schema.create_edge_class("L")
+    vs = [db.new_vertex("N", uid=i) for i in range(n)]
+    for a in vs:
+        for b in vs:
+            db.new_edge("L", a, b)
+    attach_fresh_snapshot(db)
+    return db
+
+
+class _Knows:
+    """numpy over the module graph's knows edges: a hop is a join."""
+
+    def __init__(self, snap):
+        import numpy as np
+
+        k = snap.edge_classes["knows"]
+        self.src = np.repeat(np.arange(len(k.indptr_out) - 1), np.diff(k.indptr_out))
+        self.dst = k.dst
+        self.date = k.edge_columns["creationDate"].values
+        age = snap.v_columns["age"]
+        self.person = age.present  # a message has no age
+        self.age = np.where(age.present, age.values, np.nan)  # compares false
+
+    def hop1(self, root, end, edge=True):
+        return int((root[self.src] & end[self.dst] & edge).sum())
+
+    def hop2(self, root, mid, end):
+        import numpy as np
+
+        per_f = np.bincount(self.src, weights=end[self.dst], minlength=len(end))
+        return int((root[self.src] * mid[self.dst] * per_f[self.dst]).sum())
+
+
+class TestConstantPasses:
+    """A pass of the weight chain whose edge filter and destination mask
+    read no dynamic parameter, and whose incoming weights are constant,
+    is evaluated once, at the recording, and kept on the plan
+    (`TpuMatchSolver._const_passes`); a replay lowers the passes before
+    it. Observed from the compiled predicates, not declared."""
+
+    SWEEP = (13_000, 15_000, 17_000, 18_500, 12_500)
+
+    def test_config5_recorded_once_is_the_numpy_count_on_every_cut(self, snb_counts):
+        from orientdb_tpu.storage import bigshape as B
+
+        db, snap, _ = snb_counts
+        sql = SNB_COUNTS["config5"][0].replace("AS n", "AS swept")
+        ask = lambda d: db.query(sql, {"d": d}, engine="tpu", strict=True).to_dicts()
+        first, passes = _lowered(lambda: ask(12_000))
+        assert first == [{"swept": B.numpy_config5_count(snap, 12_000)}]
+        # the recording and its replay's one trace: the messages of each
+        # person from the plan, the knows pass (it reads :d) lowered
+        assert passes == (2, 2)
+        before = _rerecords()
+        counts = [ask(d)[0]["swept"] for d in self.SWEEP]
+        assert counts == [B.numpy_config5_count(snap, d) for d in self.SWEEP]
+        assert len(set(counts)) == len(counts) and min(counts) > 0
+        assert _rerecords() == before, "a replay was recorded anew"
+
+    def test_the_kept_weights_are_counted_as_device_bytes(self, snb_counts):
+        """`memory_report` (the benchmark's ``hbm_state_gb``) and the
+        ledger count what a plan keeps while the plan lives, and no
+        longer."""
+        import gc
+
+        import jax.numpy as jnp
+
+        from orientdb_tpu.obs.memledger import memledger
+        from orientdb_tpu.ops.device_graph import device_graph
+
+        db, snap, _ = snb_counts
+        dg = device_graph(snap)
+        kept = lambda: dg.memory_report()["per_device"]["plan_consts"]
+        entries = lambda: {
+            k: e.nbytes
+            for (kind, _owner, k), e in memledger._entries.items()
+            if kind == "plan_const" and k.startswith("plan:")
+        }
+        before, ledger = kept(), entries()
+        sql = SNB_COUNTS["config5"][0].replace("AS n", "AS bytes")
+        db.query(sql, {"d": 14_000}, engine="tpu", strict=True)
+        # one int32 a person: the hull of hasCreator's targets
+        assert kept() - before == 4 * 400
+        (new,) = set(entries()) - set(ledger)
+        assert entries()[new] == 4 * 400
+
+        class Plan:  # whatever owns the array: a plan is collected the same
+            pass
+
+        plan, arr = Plan(), jnp.ones(25, jnp.int32)
+        dg.adopt_plan_const(plan, "plan:of_the_test", arr)
+        ident = f"plan:of_the_test:{id(plan):x}"
+        assert kept() - before == 4 * 400 + 100 and entries()[ident] == 100
+        del plan, arr
+        gc.collect()
+        assert kept() - before == 4 * 400 and ident not in entries()
+
+    #: statement, the passes of one lowering (from the plan, lowered), and
+    #: its count in numpy (`_Knows`) under parameters ``p``
+    LAST_HOPS = {
+        "a_parameter_is_live": (
+            "MATCH {class:Person, as:p1, where:(age > :minAge)}-knows->"
+            "{as:f, where:(age < :maxAge)} RETURN count(*) AS n",
+            (0, 1),
+            lambda g, p: g.hop1(g.age > p["minAge"], g.age < p["maxAge"]),
+        ),
+        "a_literal_is_constant": (
+            "MATCH {class:Person, as:p2, where:(age > :minAge)}-knows->"
+            "{as:f, where:(age < 30)} RETURN count(*) AS n",
+            (1, 0),
+            lambda g, p: g.hop1(g.age > p["minAge"], g.age < 30),
+        ),
+        "a_literal_behind_a_parameter_is_constant": (
+            "MATCH {class:Person, as:p3, where:(age > :minAge)}-knows->"
+            "{as:f, where:(age < :maxAge)}-knows->{as:g, where:(age > 50)} "
+            "RETURN count(*) AS n",
+            (1, 1),
+            lambda g, p: g.hop2(g.age > p["minAge"], g.age < p["maxAge"], g.age > 50),
+        ),
+        "a_parameter_behind_a_literal_keeps_both_live": (
+            "MATCH {class:Person, as:p4, where:(age > :minAge)}-knows->"
+            "{as:f, where:(age < 30)}-knows->{as:g, where:(age > :minAge)} "
+            "RETURN count(*) AS n",
+            (0, 2),
+            lambda g, p: g.hop2(g.age > p["minAge"], g.age < 30, g.age > p["minAge"]),
+        ),
+        "a_parameter_on_the_edge_is_live": (
+            "MATCH {class:Person, as:p5, where:(age > :minAge)}"
+            ".outE('knows'){where:(creationDate > :d)}.inV(){as:f} "
+            "RETURN count(*) AS n",
+            (0, 1),
+            lambda g, p: g.hop1(g.age > p["minAge"], g.person, g.date > p["d"]),
+        ),
+        "a_literal_on_the_edge_is_constant": (
+            "MATCH {class:Person, as:p6, where:(age > :minAge)}"
+            ".outE('knows'){where:(creationDate > 14000)}.inV(){as:f} "
+            "RETURN count(*) AS n",
+            (1, 0),
+            lambda g, p: g.hop1(g.age > p["minAge"], g.person, g.date > 14_000),
+        ),
+        "a_whole_chain_of_literals_is_constant": (
+            "MATCH {class:Person, as:p7, where:(age > 40)}-knows->{as:f}-knows->"
+            "{as:g, where:(age < 30)} RETURN count(*) AS n",
+            (2, 0),
+            lambda g, p: g.hop2(g.age > 40, g.person, g.age < 30),
+        ),
+    }
+
+    @pytest.mark.parametrize("case", sorted(LAST_HOPS))
+    def test_a_pass_is_constant_where_nothing_it_reads_is_a_parameter(
+        self, snb_counts, case
+    ):
+        db, snap, _ = snb_counts
+        sql, passes, count = self.LAST_HOPS[case]
+        g = _Knows(snap)
+        first = {"minAge": 40, "maxAge": 30, "d": 14_000}
+        ask = lambda p: db.query(sql, p, engine="tpu", strict=True).to_dicts()
+        got, lowered = _lowered(lambda: ask(first))
+        assert got == [{"n": count(g, first)}] and got[0]["n"] > 0
+        assert lowered == tuple(2 * n for n in passes)
+        # the kept passes serve other parameters too
+        other = {"minAge": 25, "maxAge": 60, "d": 16_000}
+        before = _rerecords()
+        assert ask(other) == [{"n": count(g, other)}]
+        assert _rerecords() == before
+        assert count(g, other) != count(g, first) or "> :" not in sql
+
+    def test_a_static_parameter_is_a_constant_of_the_plan(self, sdb):
+        """A string parameter is baked into the compiled predicate and
+        joins the plan's key: each value records a plan of its own."""
+        sql = (
+            "MATCH {class:Profiles, as:p, where:(age > :a)}-HasFriend->"
+            "{as:f, where:(name <> :who)} RETURN count(*) AS n"
+        )
+        for who in ("carol", "alice"):
+            p = {"a": 20, "who": who}
+            ask = lambda engine: sdb.query(
+                sql, p, engine=engine, strict=(engine == "tpu")
+            ).to_dicts()
+            got, lowered = _lowered(lambda: ask("tpu"))
+            assert got == ask("oracle") and got[0]["n"] in (4, 5)
+            assert lowered == (2, 0)
+
+    @pytest.mark.parametrize(
+        "last_hop", ["(age < 24)", "(age < :a)"], ids=["literal", "parameter"]
+    )
+    def test_under_an_overlay_every_pass_is_live(self, last_hop):
+        """A delta-maintained snapshot patches columns in place between
+        two replays (`TestAHullUnderDeltas`' graph): nothing is kept,
+        and a COUNT after a write to the property its last hop reads is
+        the oracle's."""
+        from orientdb_tpu.exec.tpu_engine import drain_warmups
+        from orientdb_tpu.storage.deltas import arm_delta_maintenance
+
+        db = Database(f"const_deltas_{'a' in last_hop[-3:]}")
+        db.schema.create_vertex_class("Writer")
+        db.schema.create_vertex_class("Msg")
+        db.schema.create_edge_class("Wrote")
+        writers = [db.new_vertex("Writer", age=20 + i) for i in range(6)]
+        for i in range(10):
+            db.new_edge("Wrote", db.new_vertex("Msg", length=i), writers[i % 6])
+        arm_delta_maintenance(db, spare_vertices=16, spare_edges=16)
+        sql = (
+            "MATCH {class:Msg, as:m}-Wrote->{as:p, where:%s} RETURN count(*) AS n"
+            % last_hop
+        )
+        ask = lambda engine: db.query(
+            sql, {"a": 24}, engine=engine, strict=(engine == "tpu")
+        ).to_dicts()
+        try:
+            got, lowered = _lowered(lambda: ask("tpu"))
+            assert got == ask("oracle") == [{"n": 8}]
+            assert lowered == (0, 2)
+            # the last writer turns 21: two more messages count. A
+            # property write leaves the topology clean, the plan replays
+            writers[5].set("age", 21)
+            db.save(writers[5])
+            assert not db.current_snapshot(require_fresh=True)._overlay.topology_dirty
+            before = _rerecords()
+            got, lowered = _lowered(lambda: ask("tpu"))
+            assert got == ask("oracle") == [{"n": 9}]
+            assert lowered == (0, 0) and _rerecords() == before
+        finally:
+            drain_warmups()
+            db.detach_snapshot()
+
+    def test_a_group_of_config5_lanes_gets_each_lanes_own_count(self, snb_counts):
+        import orientdb_tpu.obs.timeline as TL
+        from orientdb_tpu.exec.tpu_engine import _GROUP_MIN
+        from orientdb_tpu.storage import bigshape as B
+
+        db, snap, _ = snb_counts
+        sql = SNB_COUNTS["config5"][0].replace("AS n", "AS lane")
+        plist = [{"d": d} for d in (12_000,) + self.SWEEP]
+        assert len(plist) >= _GROUP_MIN
+        ask = lambda: db.query_batch([sql] * len(plist), plist, engine="tpu", strict=True)
+        ask()
+        before = _rerecords()  # and the first group's program is compiled
+        TL.recorder.reset()
+        (got, (kept, live)) = _lowered(lambda: [rs.to_dicts()[0] for rs in ask()])
+        assert [r["path"] for r in TL.recorder.records()] == ["group"]
+        assert got == [{"lane": B.numpy_config5_count(snap, p["d"])} for p in plist]
+        assert _rerecords() == before and (kept, live) == (0, 0)
+
+    @pytest.mark.parametrize(
+        "last_hop", ["{as:h}", "{as:h, where:(uid >= :u)}"], ids=["kept", "live"]
+    )
+    def test_the_float32_twin_still_refuses_a_chain_past_int32(self, last_hop):
+        """40 ** 7 chains wrap int32 several times over. The twin runs
+        the whole chain in float32 at the recording, kept passes too."""
+        from orientdb_tpu.exec.tpu_engine import drain_warmups
+        from orientdb_tpu.ops.predicates import Uncompilable
+
+        db = _dense_db(40)
+        hops = "".join("-L->{as:%s}" % a for a in "bcdef")
+        sql = "MATCH {class:N, as:a}%s-L->%s RETURN count(*) AS n" % (hops, last_hop)
+        try:
+            assert sql.count("-L->") == 6
+            with pytest.raises(Uncompilable, match="overflows int32"):
+                db.query(sql, {"u": 0}, engine="tpu", strict=True)
+            short = "MATCH {class:N, as:a}-L->{as:b}-L->%s RETURN count(*) AS n" % last_hop
+            got, lowered = _lowered(
+                lambda: db.query(short, {"u": 0}, engine="tpu", strict=True).to_dicts()
+            )
+            assert got == [{"n": 40**3}]
+            assert lowered == ((4, 0) if last_hop == "{as:h}" else (0, 4))
+        finally:
+            drain_warmups()
+            db.detach_snapshot()

@@ -9,8 +9,11 @@ class's vertex hull (``ops/device_graph.vertex_hull``): what their
 replays gather, what ``plan.segsum.hull`` / ``plan.segsum.full`` count,
 and that a seed does not move a hull; and a COUNT that folds its root
 (``plan.count.root_fold``): the replays of the benchmark's four scan
-statements compact nothing and gather through no candidate. No chip and
-no time in any of it.
+statements compact nothing and gather through no candidate; and the
+passes of a COUNT's weight chain that read no parameter
+(``plan.count.pass_const``): config5's replay computes nothing as long
+as ``hasCreator``, and the other five statements lower the gathers they
+lowered before. No chip and no time in any of it.
 """
 
 import numpy as np
@@ -539,6 +542,117 @@ class TestSegmentSumsOverTheHull:
             for (indptr, vals), hull, full in zip(graphs, hulls, want):
                 _same(K.indptr_segment_sum(vals, indptr, 1024, hull), full)
             assert K._segment_sum._cache_size() - compiled == programs
+
+
+# -- a COUNT's passes that read no parameter are the plan's, not the replay's -----
+
+SHORTEST_PATH_LEN = (
+    "MATCH {class:Person, as:a, where:(uid = :person1Id)}, "
+    "{class:Person, as:b, where:(uid = :person2Id)} "
+    "RETURN shortestPath(a, b, 'BOTH', 'knows').size() - 1 AS len"
+)
+#: the benchmark's six statements on the module's graph: statement,
+#: parameters, the passes a lowering of its COUNT (takes from the plan,
+#: lowers), and the index length of every gather of its replay, in order.
+#: The lists of all but config5 are the ones the parent of PR 37 lowered
+#: (3 000 persons, 9 000 messages and hasCreator edges, 17 927 knows
+#: edges); config5's lost [9000, 9000, 3000, 3000] at its head: the
+#: reorder of an all-true edge mask, v_class at every creator edge's
+#: message, and the two boundary gathers of the pass's segment sum
+KEPT = {
+    "config5": (*SCAN_4S["config5"][:2], (1, 1), [17927, 17927, 3000, 3000]),
+    "creator_1hop": (*SCAN_4S["creator_1hop"][:2], (0, 1), [9000] * 4),
+    "knows_2hop": (
+        *SCAN_4S["knows_2hop"][:2],
+        (0, 2),
+        [17927, 3000, 3000, 17927, 17927, 3000, 3000],
+    ),
+    "knows_1hop": (*SCAN_4S["knows_1hop"][:2], (0, 1), [17927, 3000, 3000]),
+    "friends": (FRIENDS, {"personId": 17}, (0, 0), [8] * 18 + [32] * 10 + [40] * 2),
+    "shortest_path_len": (
+        SHORTEST_PATH_LEN,
+        None,  # an adjacent pair, found in the graph
+        (0, 0),
+        [8] * 7 + [1] * 24 + [64] * 3 + [1] * 3 + [64, 64, 1, 64, 64, 64, 1, 1]
+        + [17927, 17927, 1] + [8] * 7,
+    ),
+}
+
+
+def _passes():
+    """(passes taken from a plan, passes lowered) by the COUNTs lowered
+    so far, every background trace finished."""
+    from orientdb_tpu.exec.tpu_engine import drain_warmups
+
+    drain_warmups()
+    return np.array(
+        [metrics.counter("plan.count.pass_const"), metrics.counter("plan.count.pass_live")]
+    )
+
+
+class TestConstantPassesAreThePlans:
+    @pytest.mark.parametrize("shape", sorted(KEPT))
+    def test_a_replay_lowers_the_passes_that_read_a_parameter(self, snb, shape):
+        db, snap = snb
+        sql, params, passes, gathers = KEPT[shape]
+        if params is None:
+            k = snap.edge_classes["knows"]
+            a = int(np.flatnonzero(np.diff(k.indptr_out))[0])
+            params = {"person1Id": a, "person2Id": int(k.dst[k.indptr_out[a]])}
+        before = _passes()
+        # a name of its own, so that the statement is recorded here
+        for name in (" AS n", " AS age", " AS len"):
+            sql = sql.replace(name, " AS kept")
+        rows, plan, _reads_counted = _record(db, snap, sql, params)
+        assert rows and all(v is not None for v in rows[0].values())
+        jaxpr = jax.make_jaxpr(plan._replay)(
+            plan._arg_subset(), plan._dyn_args(params)
+        ).jaxpr
+        # two lowerings: the eager recording (the float32 twin is not
+        # counted) and one trace of the replay, which the background
+        # warm-up and this jaxpr share
+        assert tuple(_passes() - before) == tuple(2 * n for n in passes)
+        assert _gather_index_lengths(jaxpr) == gathers
+        assert sorted(plan.consts) == (["plan:count_w"] if passes[0] else [])
+        if shape != "config5":
+            return
+        # the kept weights are as long as the pass's hull, the persons,
+        # and nothing the replay computes is as long as hasCreator (or
+        # as the messages: both 9 000 here): no gather, no prefix sum
+        hc = snap.edge_classes["hasCreator"].num_edges
+        assert plan.consts["plan:count_w"].shape == (3000,)
+        assert plan.consts["plan:count_w"].dtype == jnp.int32
+        wide = [
+            e.primitive.name
+            for e in _eqns(jaxpr)
+            if any(hc in getattr(v.aval, "shape", ()) for v in (*e.invars, *e.outvars))
+        ]
+        assert wide == [], wide
+        assert "cumsum" in {e.primitive.name for e in _eqns(jaxpr)}  # knows' own
+
+    def test_a_group_of_lanes_shares_the_kept_weights(self, snb):
+        """The group replay vmaps over the parameters alone: the kept
+        weights stay one array for all lanes, and no lane pays the pass."""
+        db, snap = snb
+        sql, params = KEPT["config5"][:2]
+        rows, plan, _reads_counted = _record(
+            db, snap, sql.replace(" AS n", " AS lanes"), params
+        )
+        args = plan._arg_subset()
+        dyn = {k: jnp.stack([v] * 4) for k, v in plan._dyn_args(params).items()}
+        closed = jax.make_jaxpr(jax.vmap(plan._replay, in_axes=(None, 0)))(args, dyn)
+        kept = closed.jaxpr.invars[sorted(args).index("plan:count_w")]
+        assert kept.aval.shape == (3000,)
+        readers = [e for e in _eqns(closed.jaxpr) if kept in e.invars]
+        assert readers and all(
+            len(v.aval.shape) == 1 for e in readers for v in e.outvars
+        )
+        hc = snap.edge_classes["hasCreator"].num_edges
+        assert not [
+            e.primitive.name
+            for e in _eqns(closed.jaxpr)
+            if any(hc in getattr(v.aval, "shape", ()) for v in (*e.invars, *e.outvars))
+        ]
 
 
 # -- who materialises: a mesh-sharded graph --------------------------------------
