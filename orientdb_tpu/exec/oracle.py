@@ -478,6 +478,15 @@ def execute_select(db, stmt: A.SelectStatement, params, parent_ctx=None) -> List
         )
         if pruned is not None:
             source = iter(pruned)
+    depths: Optional[Iterator] = None
+    if isinstance(stmt.target, A.SubQueryTarget) and isinstance(
+        stmt.target.query, A.TraverseStatement
+    ):
+        # [E] the results of a TRAVERSE carry their depth, and a SELECT
+        # over one may project, filter and group by $depth
+        found = execute_traverse(db, stmt.target.query, params, parent_ctx=base_ctx)
+        source = iter([r.element for r in found])
+        depths = iter([r.get_metadata("$depth") for r in found])
     if source is None:
         source = resolve_target_rows(db, stmt.target, base_ctx)
 
@@ -485,6 +494,8 @@ def execute_select(db, stmt: A.SelectStatement, params, parent_ctx=None) -> List
     def contexts() -> Iterator[Tuple[EvalContext, object]]:
         for row in source:
             ctx = _row_ctx(db, row, params, parent_ctx)
+            if depths is not None:
+                ctx.variables["depth"] = next(depths)
             for let in stmt.lets:
                 if isinstance(let.value, A.Statement):
                     sub = execute_statement(db, let.value, params, parent_ctx=ctx)
@@ -1512,7 +1523,11 @@ def execute_traverse(db, stmt: A.TraverseStatement, params, parent_ctx=None) -> 
         if not admit(doc, depth):
             continue
         visited.add(doc.rid)
-        out.append(Result(element=doc))
+        res = Result(element=doc)
+        # [E] a traverse result carries its depth: a SELECT over the
+        # TRAVERSE reads it as $depth (execute_select)
+        res.set_metadata("$depth", depth)
+        out.append(res)
         if limit is not None and len(out) >= limit:
             break
         children = _traverse_expand(db, doc, stmt.fields, base_ctx)

@@ -22,6 +22,10 @@ Projection-less ``SELECT FROM C`` returns *element* rows; the rewrite
 flags ``element_alias`` so the solver unwraps the binding back into a
 record row after ORDER/SKIP/LIMIT run.
 
+A second target compiles: a breadth-first ``TRAVERSE`` counted by
+``$depth`` (`rewrite_level_counts`), whose rewrite is a `LevelCounts`
+for the whole-graph search of ``exec/tpu_engine.TpuLevelsSolver``.
+
 Ineligible statements raise `Uncompilable`, and the engine front door
 falls back to the oracle interpreter — exactly the fallback contract the
 MATCH path uses for its own unsupported shapes.
@@ -85,13 +89,97 @@ def _rewrite_tuple(v: tuple) -> tuple:
     )
 
 
-def rewrite_select(
-    stmt: A.SelectStatement,
-) -> Tuple[A.MatchStatement, Optional[str]]:
-    """Translate an eligible class-target SELECT; returns the MATCH
+@dataclasses.dataclass(frozen=True)
+class LevelCounts(A.Statement):
+    """``SELECT $depth, count(*) FROM (TRAVERSE both('<class>') FROM
+    (<roots>) STRATEGY BREADTH_FIRST) GROUP BY $depth``, rewritten: one
+    row a depth of a whole-graph search from the vertices ``roots``
+    selects. ``columns`` are the projections in order, ``(name, "depth"
+    | "count")``."""
+
+    roots: A.SelectStatement
+    edge_class: str
+    columns: Tuple[Tuple[str, str], ...]
+
+    is_idempotent = True
+
+
+def _is_depth(e: A.Expression) -> bool:
+    return isinstance(e, A.ContextVar) and e.name == "depth"
+
+
+def _is_count_star(e: A.Expression) -> bool:
+    return (
+        isinstance(e, A.FunctionCall)
+        and e.name.lower() == "count"
+        and len(e.args) == 1
+        and isinstance(e.args[0], A.Star)
+    )
+
+
+def rewrite_level_counts(stmt: A.SelectStatement) -> LevelCounts:
+    """Translate a SELECT whose target is a compilable ``TRAVERSE``: the
+    vertices by ``$depth``. What compiles is the search of one edge
+    class walked both ways, breadth-first (so ``$depth`` is the least),
+    whole (no ``MAXDEPTH``, ``WHILE`` or ``LIMIT``), from the vertices a
+    plain ``SELECT FROM <class> [WHERE ...]`` admits, grouped by
+    ``$depth`` and projected as ``$depth`` and ``count(*)``."""
+    trav = stmt.target.query
+    if trav.strategy != "BREADTH_FIRST":
+        raise Uncompilable("$depth over a DEPTH_FIRST TRAVERSE is not the least depth")
+    if trav.max_depth is not None or trav.while_cond is not None:
+        raise Uncompilable("TRAVERSE MAXDEPTH/WHILE under a SELECT is not compiled")
+    if trav.limit is not None:
+        raise Uncompilable("TRAVERSE LIMIT slices in traversal order")
+    field = trav.fields[0] if len(trav.fields) == 1 else None
+    if not (
+        isinstance(field, A.FunctionCall)
+        and field.name.lower() == "both"
+        and len(field.args) == 1
+        and isinstance(field.args[0], A.Literal)
+        and isinstance(field.args[0].value, str)
+    ):
+        raise Uncompilable("TRAVERSE under a SELECT compiles for both('<class>') only")
+    roots = trav.target.query if isinstance(trav.target, A.SubQueryTarget) else None
+    if not isinstance(roots, A.SelectStatement) or (
+        roots.projections
+        or roots.group_by
+        or roots.order_by
+        or roots.skip is not None
+        or roots.limit is not None
+        or roots.distinct
+    ):
+        raise Uncompilable("TRAVERSE roots are not a plain SELECT FROM <class> WHERE")
+    rewrite_select(roots)  # a class scan the MATCH engine takes, or its refusal
+    if stmt.where is not None or stmt.lets or stmt.unwind or stmt.distinct:
+        raise Uncompilable("SELECT over a TRAVERSE compiles without WHERE/LET/UNWIND/DISTINCT")
+    if stmt.order_by or stmt.skip is not None or stmt.limit is not None:
+        raise Uncompilable("SELECT over a TRAVERSE compiles without ORDER BY/SKIP/LIMIT")
+    if len(stmt.group_by) != 1 or not _is_depth(stmt.group_by[0]):
+        raise Uncompilable("SELECT over a TRAVERSE compiles with GROUP BY $depth only")
+    columns = []
+    for i, p in enumerate(stmt.projections):
+        kind = "depth" if _is_depth(p.expr) else "count" if _is_count_star(p.expr) else None
+        if kind is None:
+            raise Uncompilable(
+                "SELECT over a TRAVERSE projects $depth and count(*) only"
+            )
+        columns.append((p.alias or expr_name(p.expr, i), kind))
+    if not columns:
+        raise Uncompilable("GROUP BY on whole-record SELECT")
+    return LevelCounts(roots, field.args[0].value, tuple(columns))
+
+
+def rewrite_select(stmt: A.SelectStatement):
+    """Translate an eligible SELECT. A class-target one becomes the MATCH
     statement and the element alias (set when the SELECT returns whole
-    records). Raises Uncompilable for shapes the MATCH engine cannot
+    records); one over a ``TRAVERSE`` becomes a `LevelCounts` (and no
+    alias). Raises Uncompilable for shapes the compiled engine cannot
     honor with oracle parity."""
+    if isinstance(stmt.target, A.SubQueryTarget) and isinstance(
+        stmt.target.query, A.TraverseStatement
+    ):
+        return rewrite_level_counts(stmt), None
     if not isinstance(stmt.target, A.ClassTarget) or not stmt.target.polymorphic:
         raise Uncompilable("SELECT target is not a polymorphic class scan")
     if stmt.lets:

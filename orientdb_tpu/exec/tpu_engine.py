@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import weakref
 from collections import OrderedDict
+from contextlib import contextmanager as _contextmanager
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -2994,6 +2995,8 @@ class TpuTraverseSolver:
     def solve(self) -> Tuple[jnp.ndarray, int]:
         """Returns (emitted vertex indices [bucketed], emitted count),
         level by level (depth-0 roots first, then each BFS level)."""
+        # deliberately at lowering: counts plans lowered, not dispatches
+        metrics.incr("plan.traverse.baked")  # lint: allow(jaxlint)
         V = self.dg.num_vertices
         vb = K.bucket(max(V, 1))
         univ = _index_range(self.dg, self.tier, 0, V, vb)
@@ -3044,6 +3047,119 @@ class TpuTraverseSolver:
             if doc is not None:
                 out.append(Result(element=doc))
         return out
+
+
+#: depths the table of a whole-graph search holds to begin with; a search
+#: with more levels records anew with four times as many
+LEVEL_SLOTS = 64
+
+
+class TpuLevelsSolver(TpuMatchSolver):
+    """The whole-graph breadth-first search behind ``SELECT $depth,
+    count(*) FROM (TRAVERSE both('<class>') FROM (SELECT FROM <class>
+    WHERE ...) STRATEGY BREADTH_FIRST) GROUP BY $depth``
+    (`select_compile.LevelCounts`), generic in its roots.
+
+    The roots are a MATCH root: the TRAVERSE's target rewrites to a
+    single-node MATCH (`select_compile.rewrite_select`), whose admission
+    mask over the class hull (`_root_index`) IS the search's first
+    frontier, so a parameter in its WHERE is a dynamic jit argument and
+    one recording serves every root; none, one or several vertices may
+    pass. The levels, the depth of every vertex and the counts by depth
+    are the device's (`ops/csr.bfs_levels`): nothing of a level crosses
+    to the host, and no buffer is sized by an answer: V, E, the switch
+    and the sparse step's buffers come from the snapshot
+    (`ops/csr.levels_caps`), the depth table from `LEVEL_SLOTS`.
+
+    Compiles for one concrete edge class walked both ways on a plain
+    snapshot (not delta-maintained, tiered or mesh-sharded)."""
+
+    def __init__(self, db, stmt, params: Dict) -> None:
+        from orientdb_tpu.exec.select_compile import ALIAS, rewrite_select
+
+        root_match, _ = rewrite_select(stmt.roots)
+        super().__init__(db, root_match, params)
+        self.levels = stmt
+        self.root_alias = ALIAS
+        concrete = self.snap.edge_closure.get(stmt.edge_class.lower(), [])
+        if len(concrete) != 1 or concrete[0] not in self.dg.edges:
+            raise Uncompilable(
+                f"TRAVERSE levels over {len(concrete)} concrete edge classes"
+            )
+        if (
+            self.overlay is not None
+            or self.tier is not None
+            or self.dg.mesh_graph is not None
+        ):
+            raise Uncompilable(
+                "TRAVERSE levels on a delta-maintained, tiered or sharded graph"
+            )
+        self.edge_class = concrete[0]
+        self.slots = LEVEL_SLOTS
+
+    def first_frontier(self) -> jnp.ndarray:
+        """``bool[V]``: the vertices the roots' SELECT admits."""
+        V = self.dg.num_vertices
+        idx, mask = self._root_index(self.root_alias)
+        if isinstance(idx, K.IndexRange) and not idx.slab_size:
+            return jnp.pad(
+                mask[: idx.size], (idx.start, V - idx.start - idx.size)
+            )
+        at = jnp.where(mask, K.as_index(idx), V)
+        return jnp.zeros(V, jnp.int32).at[at].add(1, mode="drop") > 0
+
+    def solve_levels(self) -> jnp.ndarray:
+        """The search as one int32 vector: `K.LEVEL_PARTS`, whether the
+        depth table ran out, the vertices at each of its depths."""
+        # deliberately at lowering: counts plans lowered, not dispatches
+        metrics.incr("plan.traverse.generic")  # lint: allow(jaxlint)
+        dec = self.dg.edges[self.edge_class]
+        threshold, caps = K.levels_caps(dec.num_edges)
+        _depth, counts, parts, more = K.bfs_levels(
+            dec.indptr_out,
+            dec.dst,
+            dec.indptr_in,
+            dec.src,
+            self.first_frontier(),
+            threshold=threshold,
+            caps=caps,
+            slots=self.slots,
+            hull_out=dec.hull_out,
+            hull_in=dec.hull_in,
+        )
+        return jnp.concatenate([parts, more.astype(jnp.int32)[None], counts])
+
+    def rows_from_levels(self, out: np.ndarray) -> List[Result]:
+        """One row a depth that holds a vertex, and the search's
+        counters, once an answer, from what the device returned with it:
+        ``traverse.queries``, ``traverse.levels``,
+        ``traverse.dense_levels``, ``traverse.edges_scanned`` (2E a dense
+        level, the frontier's ends a sparse one), ``traverse.overflow``,
+        ``traverse.reached``."""
+        n = len(K.LEVEL_PARTS)
+        part = dict(zip(K.LEVEL_PARTS, (int(x) for x in out[:n])))
+        a_level = 2 * self.dg.edges[self.edge_class].num_edges
+        metrics.incr_many(
+            {
+                "traverse.queries": 1,
+                "traverse.levels": part["levels"],
+                "traverse.dense_levels": part["dense_levels"],
+                "traverse.edges_scanned": part["sparse_ends"]
+                + a_level * part["dense_levels"],
+                "traverse.overflow": part["overflow"],
+                "traverse.reached": part["reached"],
+            }
+        )
+        counts = out[n + 1 :]
+        return [
+            Result(
+                props={
+                    name: d if kind == "depth" else int(counts[d])
+                    for name, kind in self.levels.columns
+                }
+            )
+            for d in np.flatnonzero(counts).tolist()
+        ]
 
 
 import threading as _threading
@@ -3381,6 +3497,27 @@ class _CompiledPlan(_AotWarmup):
         args.update(self.consts)
         return args
 
+    @_contextmanager
+    def _bound(self, arrays, dyn):
+        """The solver as a replay's trace sees it: the tracer pytree
+        swapped into the device graph, so the graph buffers become jit
+        ARGUMENTS (shared across every cached plan) rather than
+        per-executable HLO constants; the dynamic parameter scalars in
+        the param box and the seed arrays in the seed box likewise; the
+        size schedule replaying."""
+        solver = self.solver
+        solver.param_box.set_current(dyn)
+        solver.seed_box.current = {
+            a: dyn[f"__seed__:{a}"] for a in self.seed_spec
+        }
+        try:
+            with solver.dg.bound(arrays):
+                solver.sched.start_replay()
+                yield
+        finally:
+            solver.param_box.reset()
+            solver.seed_box.current = {}
+
     @jax.named_scope("match.core")
     def _replay_core(self, arrays, dyn):
         """Shared replay body: run the recorded solve and front-pack the
@@ -3393,25 +3530,9 @@ class _CompiledPlan(_AotWarmup):
         own ``csr.<name>``, ``ops/csr``): an operation's HLO ``op_name``
         says which plan entry and which kernel it came from. Trace time
         only."""
-        # swap the tracer pytree into the device graph for the trace so the
-        # graph buffers become jit ARGUMENTS (shared across every cached
-        # plan) rather than per-executable HLO constants; same for the
-        # dynamic parameter scalars via the param box
         solver = self.solver
-        dg = solver.dg
-        saved = dg.arrays
-        dg.arrays = arrays
-        solver.param_box.set_current(dyn)
-        solver.seed_box.current = {
-            a: dyn[f"__seed__:{a}"] for a in self.seed_spec
-        }
-        try:
-            solver.sched.start_replay()
+        with self._bound(arrays, dyn):
             table = solver.solve_table()
-        finally:
-            dg.arrays = saved
-            solver.param_box.reset()
-            solver.seed_box.current = {}
         overflow = solver.sched.overflow_flag().astype(jnp.int32)
         count_dev = table.count_device.astype(jnp.int32)
         if self.count_name is not None or self.width == 0:
@@ -3984,6 +4105,79 @@ class _CompiledPlan(_AotWarmup):
         return t
 
 
+class _CompiledLevels(_CompiledPlan):
+    """A `TpuLevelsSolver` as a replayable plan, with `_CompiledPlan`'s
+    dispatch protocol: the roots' parameters are dynamic jit arguments,
+    the replay is one program whose level loop ends on the device, and
+    its result is one small int32 vector (`solve_levels`).
+
+    Nothing runs eagerly at its recording: `probe` traces the replay
+    abstractly under the device graph's touch log (which arrays it
+    reads, which parameters, which seeds) and `record` runs the jitted
+    replay itself, with a longer depth table while the search runs out
+    of depths. A replay that runs out raises `ScheduleOverflow`, and the
+    front door records the next variant the same way."""
+
+    def __init__(self, solver: TpuLevelsSolver) -> None:
+        self.solver = solver
+        # what the inherited dispatch reads: no constants of the plan, no
+        # result page to prefetch; the specs are `probe`'s to fill
+        self.consts: Dict = {}
+        self._page_guess = None
+        self.dyn_spec: Dict = {}
+        self.seed_spec: Dict = {}
+        self.jitted = jax.jit(self._replay)
+
+    def probe(self) -> None:
+        solver = self.solver
+        jax.eval_shape(solver.solve_levels)
+        self.dyn_spec = dict(solver.param_box.used)
+        self.seed_spec = dict(solver.seed_box.spec)
+        self.arg_keys = solver.dg.touched()
+
+    def record(self, params: Optional[Dict]) -> List[Result]:
+        while True:
+            dyn = jax.device_put(self._dyn_args(params))
+            devicefault.dispatch_point()
+            dev = self.jitted(self._arg_subset(), dyn)
+            devicefault.transfer_point()
+            out = np.asarray(dev)
+            if not out[len(K.LEVEL_PARTS)]:
+                return self.solver.rows_from_levels(out)
+            self.solver.slots *= 4
+            self.jitted = jax.jit(self._replay)
+
+    @jax.named_scope("traverse.levels")
+    def _replay(self, arrays, dyn):
+        with self._bound(arrays, dyn):
+            return self.solver.solve_levels()
+
+    def batchable(self) -> bool:
+        return True
+
+    def dispatch_many(self, dyns: List[Dict], ring: "ParamRing" = None):
+        """A lane's searches one after another, stacked: each is the
+        whole device's work, so there is nothing to share under a
+        ``vmap`` and no second program to compile."""
+        self.wait_compiled()
+        args = self._arg_subset()
+        devicefault.dispatch_point()
+        return jnp.stack([self.jitted(args, jax.device_put(d)) for d in dyns])
+
+    def materialize(self, fetched, params: Optional[Dict] = None) -> List[Result]:
+        out = np.asarray(fetched)
+        if out[len(K.LEVEL_PARTS)]:
+            raise ScheduleOverflow(
+                f"more than {self.solver.slots} levels: {self.solver.levels}"
+            )
+        return self.solver.rows_from_levels(out)
+
+    def rows(self, params: Optional[Dict] = None) -> List[Result]:
+        arr = _fetch_profiled([self.dispatch(params)], split_sync=False)[0]
+        with timed("tpu.host_s"):
+            return self.materialize(arr, params)
+
+
 def _params_key(params) -> Optional[Tuple]:
     """Plan-cache key fragment: STATIC parameter values plus the
     names/kinds of dynamic (numeric) ones — dynamic values are jit
@@ -4131,6 +4325,12 @@ def _record_leased(db, stmt, params, snap, element_alias):
                 with _span("tpu.marshal"):
                     rows = solver.rows_from_table(table)
                 plan: object = _CompiledPlan(solver, table)
+            elif not isinstance(stmt, A.TraverseStatement):
+                # a SELECT over a TRAVERSE (select_compile.LevelCounts)
+                plan = _CompiledLevels(TpuLevelsSolver(db, stmt, params))
+                plan.probe()
+                with _span("tpu.solve"):
+                    rows = plan.record(params)
             else:
                 tsolver = TpuTraverseSolver(db, stmt, params)
                 with _span("tpu.solve"):
@@ -4290,9 +4490,6 @@ def _run_variants(
     if fresh is not None:
         fresh.append(plan_obj)
     return rows
-
-
-from contextlib import contextmanager as _contextmanager
 
 
 @_contextmanager
@@ -5235,9 +5432,11 @@ def profile_execute(db, stmt, params) -> Tuple[List[Result], Dict]:
             if steps:
                 phases["steps"] = [s.describe() for s in steps]
             # the replay has no per-hop boundaries: re-solve eagerly under
-            # the tracer so the spans show real per-hop stage timings
+            # the tracer so the spans show real per-hop stage timings (a
+            # levels plan records through its replay: nothing is eager)
             try:
-                _record(db, stmt, params)
+                if not isinstance(plan, _CompiledLevels):
+                    _record(db, stmt, params)
             except Exception as e:  # noqa: BLE001 - diagnostic only
                 # rows are already computed; a failing diagnostic
                 # re-solve must not fail the PROFILE itself

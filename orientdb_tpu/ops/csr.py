@@ -732,3 +732,153 @@ def bfs_pair_len(
     pairs does: a ``lax.cond`` under ``vmap`` would run both arms for
     every lane."""
     return _pair_search(front, chunk)(indptr_out, dst, edge_src, indptr_in, src, s, t)
+
+
+#: what one whole-graph search returns beside its counts by depth, in the
+#: order of its int32 result
+LEVEL_PARTS = ("levels", "dense_levels", "sparse_ends", "overflow", "reached")
+#: Beamer's switch: a level runs dense where its frontier holds more than
+#: one in this many of the class's 2E edge ends (both directions)
+LEVELS_DENSE_ONE_IN = 16
+#: the sparse step's buffers as shares of the largest, smallest first: a
+#: level takes the smallest that holds its frontier's ends a direction
+LEVELS_TIERS = (64, 8, 1)
+
+
+def _round_up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+def levels_caps(num_edges: int) -> Tuple[int, Tuple[int, ...]]:
+    """``(threshold, caps)`` of :func:`bfs_levels` for an edge class of
+    ``num_edges`` edges, from that number alone (so from the snapshot,
+    never from an answer): a level is sparse while its frontier holds at
+    most ``threshold = 2E / 16`` edge ends; a sparse step expands each
+    direction into a buffer of half that many slots (a pair's direction
+    is as good as a coin's, so the ends split evenly), or into a smaller
+    one of :data:`LEVELS_TIERS` where that holds them. Buffers are whole
+    blocks of the prefix sum once they are long enough to be blocked."""
+    threshold = 2 * num_edges // LEVELS_DENSE_ONE_IN
+    top = -(-threshold // 2)
+    caps = sorted(
+        {
+            _round_up(c, _CS_BLOCK if c >= 2 * _CS_BLOCK else MIN_BUCKET)
+            for c in (max(-(-top // t), MIN_BUCKET) for t in LEVELS_TIERS)
+        }
+    )
+    return threshold, tuple(caps)
+
+
+@partial(
+    jax.jit,
+    static_argnames=("threshold", "caps", "slots", "hull_out", "hull_in"),
+)
+@jax.named_scope("csr.bfs_levels")
+def bfs_levels(
+    indptr_out: jnp.ndarray,
+    dst: jnp.ndarray,
+    indptr_in: jnp.ndarray,
+    src: jnp.ndarray,
+    start: jnp.ndarray,
+    *,
+    threshold: int,
+    caps: Tuple[int, ...],
+    slots: int,
+    hull_out: Tuple[int, int],
+    hull_in: Tuple[int, int],
+):
+    """A whole-graph breadth-first search over one edge class walked both
+    ways, from the vertices of ``start`` (``bool[V]``; none, one or
+    several). Returns ``(depth, counts, parts, more)``: ``int32[V]`` the
+    depth of every vertex (-1 unreached), ``int32[slots]`` the vertices
+    at each depth, ``int32[5]`` in the order of :data:`LEVEL_PARTS` (the
+    frontiers expanded, how many of them dense, the edge ends the sparse
+    ones held, the sparse ones that outgrew their buffer and ran dense,
+    the vertices reached), and whether the search still had a frontier
+    when the ``slots`` depths of the table were used up (the caller runs
+    it again with a longer table; what it returned is then short).
+
+    One ``lax.while_loop`` whose test is the frontier's population, so
+    no level's count crosses to the host. A level's cost follows
+    Beamer's switch (``threshold``, ``caps``: :func:`levels_caps`):
+
+    - sparse, the frontier's ends a direction fit a buffer: each
+      direction's lists are expanded (count → scan → rank → gather,
+      :func:`gather_expand` over every vertex, the others with a count of
+      0) into the smallest buffer of ``caps`` that holds them, and the
+      ends are marked by an int32 scatter-add;
+    - dense: one pass over the class in each stored order, the frontier
+      bit gathered at ``src`` in in-order and at ``dst`` in out-order and
+      summed by segment over the class's vertex hulls
+      (:func:`_segment_sum`): a vertex is reached where an edge's other
+      end is in the frontier. No scatter.
+
+    Both mask by unreached. The counts by depth are one reduction over
+    ``depth`` after the loop."""
+    i32 = jnp.int32
+    V = indptr_out.shape[0] - 1
+    if V <= 0:
+        z = jnp.zeros(0, i32)
+        return z, jnp.zeros(slots, i32), jnp.zeros(5, i32), jnp.zeros((), bool)
+    degrees = (indptr_out[1:] - indptr_out[:-1], indptr_in[1:] - indptr_in[:-1])
+    verts = jnp.arange(V, dtype=i32)
+    dirs = ((indptr_out, dst), (indptr_in, src))
+
+    def sparse(cap):
+        def step(frontier, counts):
+            hit = jnp.zeros(V, i32)
+            for (indptr, nbrs), cnt in zip(dirs, counts):
+                _, _, nbr = gather_expand(
+                    indptr, nbrs, verts, exclusive_cumsum(cnt), jnp.sum(cnt), cap
+                )
+                # -1 would wrap to the last vertex: V is out of range
+                hit = hit.at[jnp.where(nbr >= 0, nbr, V)].add(1, mode="drop")
+            return hit > 0
+
+        return step
+
+    def dense(frontier, _counts):
+        f = frontier.astype(i32)
+        hit = _segment_sum(jnp.take(f, src), indptr_in, V, hull_in)
+        hit = hit + _segment_sum(jnp.take(f, dst), indptr_out, V, hull_out)
+        return hit > 0
+
+    steps = [sparse(c) for c in caps] + [dense]
+    limits = jnp.asarray(caps, i32)
+
+    def cond(st):
+        _depth, frontier, level, *_ = st
+        return jnp.any(frontier) & (level < slots)
+
+    def body(st):
+        depth, frontier, level, dense_n, sparse_ends, outgrew = st
+        # the frontier's edge ends, a vertex and a direction
+        counts = tuple(jnp.where(frontier, deg, 0) for deg in degrees)
+        ends_o, ends_i = (jnp.sum(c) for c in counts)
+        few = ends_o + ends_i <= threshold
+        tier = jnp.sum(jnp.maximum(ends_o, ends_i) > limits)
+        branch = jnp.where(few, tier, len(caps))
+        ran_dense = branch == len(caps)
+        nxt = jax.lax.switch(branch, steps, frontier, counts) & (depth < 0)
+        return (
+            jnp.where(nxt, level + 1, depth),
+            nxt,
+            level + 1,
+            dense_n + ran_dense,
+            sparse_ends + jnp.where(ran_dense, 0, ends_o + ends_i),
+            outgrew + (few & ran_dense),
+        )
+
+    zero = i32(0)
+    depth, frontier, level, dense_n, sparse_ends, outgrew = jax.lax.while_loop(
+        cond,
+        body,
+        (jnp.where(start, 0, -1).astype(i32), start, zero, zero, zero, zero),
+    )
+    counts = jnp.sum(
+        depth[:, None] == jnp.arange(slots, dtype=i32)[None, :], axis=0, dtype=i32
+    )
+    parts = jnp.stack(
+        [level, dense_n, sparse_ends, outgrew, jnp.sum(depth >= 0, dtype=i32)]
+    )
+    return depth, counts, parts.astype(i32), jnp.any(frontier)

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import threading
 import weakref
+from contextlib import contextmanager
 from typing import Dict, List, Optional, Set, Tuple
 
 import jax.numpy as jnp
@@ -362,15 +363,33 @@ class DeviceGraph:
     def arrays(self, value) -> None:
         self._tls.override = None if value is self._arrays else value
 
+    @contextmanager
+    def bound(self, arrays):
+        """This thread's view swapped to ``arrays`` (a trace's tracer
+        pytree) and back to what it was: the canonical store, or a
+        recording's tracking view, which a replay traced at its own
+        recording (`tpu_engine._CompiledLevels.record`) must find again."""
+        prev = getattr(self._tls, "override", None)
+        self._tls.override = arrays
+        try:
+            yield
+        finally:
+            self._tls.override = prev
+
     # -- recording touch log (per-plan jit-arg subsets) ---------------------
 
     def start_touch_log(self) -> None:
         self._tls.tracker = _TouchTracker(self)
 
-    def stop_touch_log(self) -> frozenset:
+    def touched(self) -> frozenset:
+        """The keys this thread's running touch log holds so far."""
         trk = getattr(self._tls, "tracker", None)
-        self._tls.tracker = None
         return frozenset(trk.log) if trk is not None else frozenset()
+
+    def stop_touch_log(self) -> frozenset:
+        keys = self.touched()
+        self._tls.tracker = None
+        return keys
 
     @property
     def mesh(self):
@@ -491,7 +510,12 @@ class DeviceGraph:
         shard_pad: Optional[int] = None,
         fill: int = 0,
     ) -> str:
-        a = jnp.asarray(arr)
+        import jax
+
+        # an upload asked for under a trace (a plan probed abstractly,
+        # `tpu_engine._CompiledLevels.probe`) is an upload all the same
+        with jax.ensure_compile_time_eval():
+            a = jnp.asarray(arr)
         if (
             self.mesh_graph is not None
             and shard_pad is not None
@@ -500,7 +524,6 @@ class DeviceGraph:
         ):
             # row-shard over the mesh's shard axis (vertex- or edge-range
             # ownership); padding rows carry `fill` and a False presence
-            import jax
             from jax.sharding import NamedSharding, PartitionSpec
 
             from orientdb_tpu.utils.config import config as _cfg
@@ -518,8 +541,6 @@ class DeviceGraph:
             self._scrub_mark(key)
             return key
         if self._replicated_spec is not None:
-            import jax
-
             a = jax.device_put(a, self._replicated_spec)
         self._arrays[key] = a
         from orientdb_tpu.obs.memledger import memledger

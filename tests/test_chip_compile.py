@@ -261,3 +261,51 @@ def test_sharded_bitmap_hop_on_4_device_mesh(mesh4):
         _replicated(mesh4, (c, vb), jnp.bool_),
     )
     assert "all-reduce" in compiled.as_text()
+
+
+def test_whole_graph_search_at_graph500_22(one_chip, blocked_cumsum):
+    """``g500_s22_bfs_1s``'s program: the levels plan recorded on a
+    256-label Kronecker graph, its whole jitted replay lowered at
+    graph500-22's sizes (2.4 M vertices, 64.2 M ``Link`` edges): the
+    level loop, the three sparse steps at the snapshot's buffer sizes
+    and the dense pass's ``[E]`` gathers and prefix sums, beside ~1 GB of
+    arguments."""
+    from benchmark import run
+    from orientdb_tpu.exec import tpu_engine
+
+    V, E = 2_400_000, 64_200_000
+    g = run.load_module("kinds", "graph500")
+    raw = g.make_raw(
+        {"scale": 8, "edge_factor": 16, "a": 0.57, "b": 0.19, "c": 0.19, "graph_seed": 1}, 1
+    )
+    db, snap = g._handed_over(raw, "g500_compile")
+    try:
+        db.query(g.STATEMENT, params={"source": 3}, engine="tpu", strict=True)
+        tpu_engine.drain_warmups()
+        (variants,) = snap._plan_cache.values()
+        plan = variants.plans[0]
+        dims = {raw.V: V, raw.V + 1: V + 1, raw.E: E}
+
+        def real(a):
+            a = np.asarray(a) if not hasattr(a, "shape") else a
+            return jax.ShapeDtypeStruct(
+                tuple(dims.get(d, d) for d in a.shape), a.dtype, sharding=one_chip
+            )
+
+        arrays = {k: real(v) for k, v in plan._arg_subset().items()}
+        assert sorted(s.shape[0] for s in arrays.values())[-2:] == [E, E]
+        dyn = {k: real(v) for k, v in plan._dyn_args({"source": 5}).items()}
+        # the replay reads these sizes from host metadata
+        dg = plan.solver.dg
+        dg.num_vertices = V
+        snap.class_vertex_range["node"] = (0, V)
+        dec = dg.edges["Link"]
+        dec.num_edges, dec.hull_out, dec.hull_in = E, (0, V), (0, V)
+        compiled = _compile(plan._replay, arrays, dyn)
+        mem = compiled.memory_analysis()
+        # two [E] int32 arrays and two pointer arrays in, a few [E]
+        # temporaries of the dense pass beside them
+        assert 0.5e9 < mem.argument_size_in_bytes < 0.6e9, mem
+        assert mem.temp_size_in_bytes < 4e9, mem
+    finally:
+        db.detach_snapshot()

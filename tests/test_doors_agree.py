@@ -7,10 +7,10 @@ behind ``db.query_batch`` (singles, and the vmapped group from
 coalescer calls, and ``profile_execute`` behind ``PROFILE``. The
 benchmark's cells use the lane door alone. Before a door may be deleted
 something has to say that all of them answer alike: this file does, on
-the six statements of ``benchmark/traffic/{scan_4s,rooted_16s,
-ic13_16s}.json`` (the SQL is copied here, nothing of ``benchmark`` is
-imported), over one small array-native graph, against plain numpy over
-the snapshot's own arrays.
+the seven statements of ``benchmark/traffic/{scan_4s,rooted_16s,
+ic13_16s,bfs_1s}.json`` (the SQL is copied here, over this graph's
+classes; nothing of ``benchmark`` is imported), over one small
+array-native graph, against plain numpy over the snapshot's own arrays.
 """
 
 import numpy as np
@@ -59,6 +59,11 @@ STATEMENTS = {
         "MATCH {class:Person, as:a, where:(uid = :person1Id)}, "
         "{class:Person, as:b, where:(uid = :person2Id)} "
         "RETURN shortestPath(a, b, 'BOTH', 'knows').size() - 1 AS len"
+    ),
+    "bfs_levels": (
+        "SELECT $depth AS depth, count(*) AS n FROM (TRAVERSE both('knows') "
+        "FROM (SELECT FROM Person WHERE uid = :source) STRATEGY BREADTH_FIRST) "
+        "GROUP BY $depth"
     ),
 }
 
@@ -134,6 +139,22 @@ class Reference:
             hops += 1
         return [(hops if seen[b] else -1,)]
 
+    def bfs_levels(self, p):
+        depth = np.full(self.snap.num_vertices, -1)
+        depth[p["source"]] = 0
+        level, hops = np.array([p["source"]]), 0
+        while level.size:
+            nxt = np.concatenate(
+                [
+                    self.knows.dst[np.isin(self.knows_src, level)],
+                    self.knows_src[np.isin(self.knows.dst, level)],
+                ]
+            )
+            level = np.unique(nxt[depth[nxt] < 0])
+            hops += 1
+            depth[level] = hops
+        return list(enumerate(np.bincount(depth[depth >= 0]).tolist()))
+
 
 def _canon(dicts):
     """Rows as sorted tuples, columns in the order of RETURN."""
@@ -177,6 +198,8 @@ def _draws(name: str, ref: Reference, n: int):
             }
         elif name == "friends":
             p = {"personId": int(rng.choice(persons))}
+        elif name == "bfs_levels":
+            p = {"source": int(rng.choice(persons))}
         else:
             a, b = rng.choice(persons, 2, replace=False)
             p = {"person1Id": int(a), "person2Id": int(b)}

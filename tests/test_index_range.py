@@ -272,8 +272,13 @@ def snb():
 def _record(db, snap, sql, params):
     known = set(getattr(snap, "_plan_cache", ()))
     before = _reads()
+    from orientdb_tpu.exec.tpu_engine import drain_warmups
+
     rows = db.query(sql, params, engine="tpu", strict=True).to_dicts()
     after = _reads()
+    # the background warm-up traces the same solver: let it end before a
+    # test traces the replay itself (two traces share one size schedule)
+    drain_warmups()
     (new,) = set(snap._plan_cache) - known
     plan = snap._plan_cache[new].plans[0]
     return rows, plan, (after[0] - before[0], after[1] - before[1])
