@@ -1939,16 +1939,17 @@ class TpuMatchSolver:
                     )
                     continue
                 # both CSR orders exist in HBM, so either direction
-                # sums via cumsum+boundary-gather (indptr_segment_sum)
+                # sums via cumsum+boundary-gather (indptr_segment_sum;
+                # a slice where the hull holds one edge a vertex)
                 # instead of the ~7x-costlier TPU scatter-add; the
                 # in-direction reorders the out-order edge mask
                 # through the in-CSR's edge-id map first
                 if d == "out":
                     emit, ip, hull = dec.dst, dec.indptr_out, dec.hull_out
-                    em = emask
+                    unit, em = dec.unit_out, emask
                 else:
                     emit, ip, hull = dec.src, dec.indptr_in, dec.hull_in
-                    em = jnp.take(emask, dec.edge_id_in)
+                    unit, em = dec.unit_in, jnp.take(emask, dec.edge_id_in)
                 if E >= vb:
                     # [vb] mask precompute + one bool gather beats
                     # re-evaluating the predicate's column gathers
@@ -1959,7 +1960,7 @@ class TpuMatchSolver:
                 vals = contrib.astype(dtype)
                 if w is not None:
                     vals = vals * K.take_pad(w, emit, dtype(0))
-                new_w = new_w + K.indptr_segment_sum(vals, ip, vb, hull)
+                new_w = new_w + K.indptr_segment_sum(vals, ip, vb, hull, unit)
         return new_w
 
     def _folded_root(self, root: PlanStep):

@@ -366,14 +366,23 @@ def mask_count(mask: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(mask.astype(jnp.int32))
 
 
-@partial(jax.jit, static_argnames=("out_size", "hull"))
+@partial(jax.jit, static_argnames=("out_size", "hull", "unit"))
 def _segment_sum(
-    vals: jnp.ndarray, indptr: jnp.ndarray, out_size: int, hull: Tuple[int, int]
+    vals: jnp.ndarray,
+    indptr: jnp.ndarray,
+    out_size: int,
+    hull: Tuple[int, int],
+    unit: bool = False,
 ) -> jnp.ndarray:
     lo, hi = hull
-    tot = jnp.concatenate([jnp.zeros(1, vals.dtype), value_cumsum(vals)])
-    ends = jax.lax.slice(indptr, (lo,), (hi + 1,))
-    seg = jnp.take(tot, ends[1:]) - jnp.take(tot, ends[:-1])
+    if unit:
+        # one edge a vertex, and none outside the hull: vertex lo + i
+        # holds edge i
+        seg = jax.lax.slice(vals, (0,), (hi - lo,))
+    else:
+        tot = jnp.concatenate([jnp.zeros(1, vals.dtype), value_cumsum(vals)])
+        ends = jax.lax.slice(indptr, (lo,), (hi + 1,))
+        seg = jnp.take(tot, ends[1:]) - jnp.take(tot, ends[:-1])
     seg = jnp.pad(seg, (lo, max(out_size - hi, 0)))
     return seg[:out_size]
 
@@ -384,6 +393,7 @@ def indptr_segment_sum(
     indptr: jnp.ndarray,
     out_size: int,
     hull: Optional[Tuple[int, int]] = None,
+    unit: bool = False,
 ) -> jnp.ndarray:
     """Segment sums of CSR-ordered values: cumsum + boundary gathers.
 
@@ -402,14 +412,21 @@ def indptr_segment_sum(
     ``ops/device_graph.vertex_hull`` finds it from the pointer array:
     only the hull's boundaries are gathered (a TPU gather of scalars is
     serial, 6-7 ns a boundary) and the other sums are the zeros of a
-    static pad. Counted as :func:`count_read` counts, by how the pass
-    lowers: ``plan.segsum.hull`` where the hull is narrower than the
-    pointer array, else ``plan.segsum.full``. Result is zero-padded to
-    the static `out_size`."""
+    static pad. ``unit`` (static; ``ops/device_graph.unit_degree``) says
+    that every vertex of the hull holds exactly one edge: the sums are
+    then ``vals`` itself, a static slice, and neither the prefix sum nor
+    a boundary gather runs. Counted as :func:`count_read` counts, by how
+    the pass lowers: ``plan.segsum.unit`` a slice, ``plan.segsum.hull``
+    a hull narrower than the pointer array, else ``plan.segsum.full``.
+    Result is zero-padded to the static `out_size`."""
     full = (0, indptr.shape[0] - 1)
     hull = full if hull is None else hull
-    metrics.incr("plan.segsum.full" if hull == full else "plan.segsum.hull")
-    return _segment_sum(vals, indptr, out_size, hull)
+    metrics.incr(
+        "plan.segsum.unit"
+        if unit
+        else "plan.segsum.full" if hull == full else "plan.segsum.hull"
+    )
+    return _segment_sum(vals, indptr, out_size, hull, unit)
 
 
 @partial(jax.jit, static_argnames=("vb",))

@@ -111,6 +111,19 @@ def vertex_hull(indptr: np.ndarray, bounds: np.ndarray) -> Tuple[int, int]:
     )
 
 
+def unit_degree(indptr: np.ndarray, hull: Tuple[int, int]) -> bool:
+    """Whether every vertex of ``hull`` (:func:`vertex_hull`'s) has
+    exactly one edge in the CSR whose pointer array is ``indptr``, as a
+    many-to-one relationship stored from its "many" side has: a segment
+    sum over the hull is then its values in order
+    (``ops/csr.indptr_segment_sum``). One pass over the hull; the empty
+    hull is not unit."""
+    lo, hi = hull
+    if hi <= lo or int(indptr[hi]) - int(indptr[lo]) != hi - lo:
+        return False
+    return bool((np.diff(indptr[lo : hi + 1]) == 1).all())
+
+
 class DeviceEdgeClass:
     """One edge class's CSR adjacency (both directions) in HBM.
 
@@ -123,7 +136,7 @@ class DeviceEdgeClass:
 
     __slots__ = (
         "class_name", "columns", "non_columnar", "num_edges", "hull_out",
-        "hull_in", "_g", "_p",
+        "hull_in", "unit_out", "unit_in", "_g", "_p",
     )
 
     def __init__(self, csr, g: "DeviceGraph") -> None:
@@ -136,6 +149,18 @@ class DeviceEdgeClass:
         #: (``ops/csr.indptr_segment_sum``)
         self.hull_out = vertex_hull(csr.indptr_out, g.class_bounds)
         self.hull_in = vertex_hull(csr.indptr_in, g.class_bounds)
+        #: whether each hull holds one edge a vertex (`unit_degree`).
+        #: Static only where nothing patches, pages or shards the CSR
+        #: under a recorded plan: never with a delta overlay or slab, a
+        #: tier or a mesh
+        fixed = (
+            g.mesh_graph is None
+            and getattr(g.snap, "_overlay", None) is None
+            and getattr(g.snap, "_tier", None) is None
+            and getattr(csr, "live", None) is None
+        )
+        self.unit_out = fixed and unit_degree(csr.indptr_out, self.hull_out)
+        self.unit_in = fixed and unit_degree(csr.indptr_in, self.hull_in)
         if g.mesh_graph is None:
             # tiered snapshots (storage/tiering) page the four [E]
             # value arrays between a hot device pool and host-pinned

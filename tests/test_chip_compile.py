@@ -151,10 +151,20 @@ def test_pair_search_on_sf100s_friendship_graph_sixteen_lanes(
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
-@pytest.mark.parametrize("shape", ["two_hop_count", "config5_count"])
+CREATOR_1HOP = (
+    "MATCH {class:Message, as:m, where:(length > :minLen)}-hasCreator->"
+    "{as:p, where:(age < :maxAge)} RETURN count(*) AS n"
+)
+
+
+@pytest.mark.parametrize(
+    "shape", ["two_hop_count", "config5_count", "creator_1hop_count"]
+)
 def test_whole_count_plan_at_real_shapes(one_chip, blocked_cumsum, shape):
     """Record the plan on a tiny graph, then lower its whole jitted
-    replay with every graph array at the scale tier's shape."""
+    replay with every graph array at the scale tier's shape. The
+    creator 1-hop's pass is a slice (one edge a message) and keeps
+    nothing."""
     import chip_smoke
     from orientdb_tpu.exec import tpu_engine
     from orientdb_tpu.storage import bigshape
@@ -168,7 +178,11 @@ def test_whole_count_plan_at_real_shapes(one_chip, blocked_cumsum, shape):
         db, snap = bigshape.build_snb_shape(
             TINY, msgs_per_person=2, avg_knows=10, seed=7
         )
-        sql, params = chip_smoke.Q_CONFIG5, {"d": 15_000}
+        sql, params = (
+            (chip_smoke.Q_CONFIG5, {"d": 15_000})
+            if shape == "config5_count"
+            else (CREATOR_1HOP, {"minLen": 200, "maxAge": 60})
+        )
     try:
         db.query(sql, params=params, engine="tpu", strict=True)
         tpu_engine.drain_warmups()
@@ -191,10 +205,16 @@ def test_whole_count_plan_at_real_shapes(one_chip, blocked_cumsum, shape):
         # what the recording kept for its replays (config5: the messages
         # of each person; the two hops of literals: the whole chain) is
         # as long as a vertex hull, and grows with it
-        assert sorted(plan.consts) == ["plan:count_w"]
+        sliced = shape == "creator_1hop_count"
+        assert sorted(plan.consts) == ([] if sliced else ["plan:count_w"])
+        assert plan.solver.dg.edges["knows"].unit_out is False
+        if "hasCreator" in plan.solver.dg.edges:
+            assert plan.solver.dg.edges["hasCreator"].unit_out is True
         dims.update({c.shape[0]: c.shape[0] * scale for c in plan.consts.values()})
         arrays = {k: real(v) for k, v in plan._arg_subset().items()}
-        assert max(s.shape[0] for s in arrays.values()) >= EDGES
+        # knows' edges, or (creator 1-hop) the 24 M persons and messages
+        widest = 3 * PERSONS if sliced else EDGES
+        assert max(s.shape[0] for s in arrays.values()) >= widest
         dyn = {k: real(v) for k, v in plan._dyn_args(params).items()}
         # the replay reads these sizes from host metadata
         dg = plan.solver.dg

@@ -216,20 +216,24 @@ SEG_HULLS = {
     "in_the_middle": (13, 29),
     "at_the_end": (31, V_SEG),
     "the_whole_universe": (0, V_SEG),
+    "one_vertex": (20, 21),
     "empty": (0, 0),
 }
 
 
-def _seeded_csr(hull, dtype, seed, lanes=None):
+def _seeded_csr(hull, dtype, seed, lanes=None, unit=False):
     """A pointer array whose degrees are zero outside ``hull`` (and at
-    some vertices inside it, its two ends never), and values in its
-    order: whole numbers, so a float32 prefix sum is exact."""
+    some vertices inside it, its two ends never; or, ``unit``, one at
+    every vertex inside it), and values in its order: whole numbers, so
+    a float32 prefix sum is exact."""
     import numpy as np
 
     rng = np.random.default_rng(seed)
     lo, hi = hull
     deg = np.zeros(V_SEG, np.int64)
-    if hi > lo:
+    if hi > lo and unit:
+        deg[lo:hi] = 1
+    elif hi > lo:
         deg[lo:hi] = rng.integers(0, 5, hi - lo)
         deg[lo] = deg[hi - 1] = 3
     indptr = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
@@ -237,27 +241,49 @@ def _seeded_csr(hull, dtype, seed, lanes=None):
     return indptr, rng.integers(0, 9, shape).astype(dtype)
 
 
+def _primitive_names(closed):
+    """Every primitive of a closed jaxpr, nested jits' too."""
+    out, todo = [], [closed.jaxpr]
+    while todo:
+        for e in todo.pop().eqns:
+            out.append(e.primitive.name)
+            todo += [
+                getattr(p, "jaxpr", p)
+                for p in e.params.values()
+                if hasattr(getattr(p, "jaxpr", p), "eqns")
+            ]
+    return out
+
+
 class TestSegmentSumOverAHull:
+    @pytest.mark.parametrize("degrees", ["any", "one"])
     @pytest.mark.parametrize("lanes", [None, 3], ids=["one", "vmapped"])
     @pytest.mark.parametrize("dtype", ["int32", "float32"])
     @pytest.mark.parametrize("name", sorted(SEG_HULLS))
-    def test_the_hull_form_is_the_full_form(self, name, dtype, lanes):
+    def test_the_hull_form_is_the_full_form(self, name, dtype, lanes, degrees):
+        """Over a hull whose vertices hold one edge each (``degrees``
+        "one"), the slice form is the prefix-sum form too, and lowers no
+        prefix sum and no gather."""
         import jax
         import numpy as np
 
         from orientdb_tpu.ops import csr as K
-        from orientdb_tpu.ops.device_graph import vertex_hull
+        from orientdb_tpu.ops.device_graph import unit_degree, vertex_hull
+        from orientdb_tpu.utils.metrics import metrics
 
         hull = SEG_HULLS[name]
-        indptr, vals = _seeded_csr(hull, dtype, seed=len(name), lanes=lanes)
-        # the hull the attach finds is the one the case was built on
+        unit = degrees == "one"
+        indptr, vals = _seeded_csr(hull, dtype, seed=len(name), lanes=lanes, unit=unit)
+        # the hull the attach finds is the one the case was built on, and
+        # it is unit where every vertex of it holds one edge
         assert vertex_hull(indptr, np.arange(V_SEG + 1)) == hull
+        assert unit_degree(indptr, hull) == (unit and hull[1] > hull[0])
         for out_size in (64, V_SEG):
-            def seg(h):
-                one = lambda v: K.indptr_segment_sum(v, indptr, out_size, h)
-                return np.asarray((jax.vmap(one) if lanes else one)(vals))
+            def seg(h, u=False):
+                one = lambda v: K.indptr_segment_sum(v, indptr, out_size, h, u)
+                return (jax.vmap(one) if lanes else one)
 
-            got, full = seg(hull), seg(None)
+            got, full = np.asarray(seg(hull)(vals)), np.asarray(seg(None)(vals))
             assert got.dtype == full.dtype == np.dtype(dtype)
             assert got.shape == full.shape == vals.shape[:-1] + (out_size,)
             assert np.array_equal(got, full)
@@ -266,6 +292,40 @@ class TestSegmentSumOverAHull:
             for i in range(V_SEG):
                 want[:, i] = rows[:, indptr[i] : indptr[i + 1]].sum(axis=1)
             assert np.array_equal(got.reshape(want.shape), want)
+            if not unit:
+                continue
+            sliced = seg(hull, True)
+            before = metrics.counter("plan.segsum.unit")
+            names = _primitive_names(jax.make_jaxpr(sliced)(vals))
+            assert metrics.counter("plan.segsum.unit") == before + 1
+            assert not {"gather", "cumsum"} & set(names), names
+            got = np.asarray(sliced(vals))
+            assert got.dtype == full.dtype and np.array_equal(got, full)
+
+    #: name -> the degrees of the hull (13, 29), by vertex, where they are
+    #: not one
+    NOT_UNIT = {
+        "an_empty_segment_at_the_start": {13: 0},
+        "two_edges_in_the_middle": {20: 2},
+        "two_edges_at_the_end": {28: 2},
+        "an_empty_and_a_doubled_segment_balance": {15: 0, 25: 2},
+    }
+
+    @pytest.mark.parametrize("case", sorted(NOT_UNIT))
+    def test_one_segment_of_another_size_unsets_the_bit(self, case):
+        import numpy as np
+
+        from orientdb_tpu.ops.device_graph import unit_degree
+
+        lo, hi = 13, 29
+        deg = np.zeros(V_SEG, np.int64)
+        deg[lo:hi] = 1
+        for v, d in self.NOT_UNIT[case].items():
+            deg[v] = d
+        indptr = np.concatenate([[0], np.cumsum(deg)]).astype(np.int32)
+        assert not unit_degree(indptr, (lo, hi))
+        deg[list(self.NOT_UNIT[case])] = 1
+        assert unit_degree(np.concatenate([[0], np.cumsum(deg)]), (lo, hi))
 
     def test_a_hull_snaps_outwards_to_the_class_boundaries(self):
         import numpy as np
@@ -322,6 +382,11 @@ def _segsums():
     return c.get("plan.segsum.hull", 0), c.get("plan.segsum.full", 0)
 
 
+#: (statement, end) whose weight chain walks hasCreator from the messages,
+#: one edge a message: that pass is a slice of its values
+SLICED = {("creator_1hop", 0), ("config5", 1)}
+
+
 @pytest.fixture(scope="module")
 def snb_counts():
     import numpy as np
@@ -358,21 +423,29 @@ class TestSnbCountsOverHulls:
         assert (edges["knows"].hull_out, edges["knows"].hull_in) == ((0, P), (0, P))
         assert edges["hasCreator"].hull_out == (P, V)
         assert edges["hasCreator"].hull_in == (0, P)
+        # one creator a message; a person writes none, one or several, and
+        # knows has persons without a friend and persons with many
+        assert edges["hasCreator"].unit_out and not edges["hasCreator"].unit_in
+        assert not edges["knows"].unit_out and not edges["knows"].unit_in
 
     @pytest.mark.parametrize("end", [0, 1], ids=["as_written", "from_the_other_end"])
     @pytest.mark.parametrize("shape", sorted(SNB_COUNTS))
     def test_a_scan_count_is_the_numpy_count(self, snb_counts, shape, end):
         db, _snap, want = snb_counts
         assert want[shape] > 0, "a count of nothing tests nothing"
-        hull0, full0 = _segsums()
+        names = ("plan.segsum.hull", "plan.segsum.full", "plan.segsum.unit")
+        before = _counted(*names)
         got = db.query(
             SNB_COUNTS[shape][end], SNB_PARAMS, engine="tpu", strict=True
         ).to_dicts()
-        hull1, full1 = _segsums()
+        moved = [b > a for a, b in zip(before, _counted(*names))]
         assert got == [{"n": want[shape]}]
         # the pushdown answered, and every pass of it ran over a class's
-        # hull: no edge class of this layout spans the universe
-        assert hull1 > hull0 and full1 == full0
+        # hull: no edge class of this layout spans the universe. A pass
+        # from the messages over hasCreator is a slice, and creator_1hop
+        # as written has no other
+        sliced = (shape, end) in SLICED
+        assert moved == [(shape, end) != ("creator_1hop", 0), False, sliced]
 
 
 class TestAHullUnderDeltas:
@@ -396,8 +469,9 @@ class TestAHullUnderDeltas:
         db.schema.create_vertex_class("Msg")
         db.schema.create_edge_class("Wrote")
         writers = [db.new_vertex("Writer", age=20 + i) for i in range(6)]
-        for i in range(10):
-            db.new_edge("Wrote", db.new_vertex("Msg", length=i), writers[i % 6])
+        msgs = [db.new_vertex("Msg", length=i) for i in range(10)]
+        for i, msg in enumerate(msgs):
+            db.new_edge("Wrote", msg, writers[i % 6])
         m = arm_delta_maintenance(db, spare_vertices=16, spare_edges=16)
         q, a = getattr(self, sql), {"a": 24}
         ask = lambda engine: db.query(
@@ -409,6 +483,9 @@ class TestAHullUnderDeltas:
             # the two classes lie one after the other, the slab behind both
             assert {dec.hull_out, dec.hull_in} == {(0, 6), (6, 16)}
             assert snap.num_vertices == 32
+            # one edge a message, but the slab may give a message another:
+            # a maintained snapshot never slices
+            assert not dec.unit_out and not dec.unit_in
             before = hulls_counted()
             assert ask("tpu") == ask("oracle") == [{"n": 8}]
             assert hulls_counted() > before, "the pushdown did not answer"
@@ -426,12 +503,149 @@ class TestAHullUnderDeltas:
             dec2 = device_graph(snap2).edges["Wrote"]
             assert snap2 is not snap
             assert {dec2.hull_out, dec2.hull_in} == {(0, 6), (6, 17)}
+            assert not dec2.unit_out and not dec2.unit_in
             before = hulls_counted()
             assert ask("tpu") == ask("oracle") == [{"n": 9}]
             assert hulls_counted() > before
+            # a second creator for a message that has one: counted twice
+            db.new_edge("Wrote", msgs[0], writers[2])
+            assert ask("tpu") == ask("oracle") == [{"n": 10}]
+            m.compact("a message with two edges")
+            dec3 = device_graph(db.current_snapshot(require_fresh=True)).edges["Wrote"]
+            assert not dec3.unit_out and not dec3.unit_in
+            sliced = _counted("plan.segsum.unit")
+            assert ask("tpu") == ask("oracle") == [{"n": 10}]
+            assert _counted("plan.segsum.unit") == sliced
         finally:
             drain_warmups()
             db.detach_snapshot()
+
+
+def _attach_plain(db, _monkeypatch):
+    return attach_fresh_snapshot(db)
+
+
+def _attach_maintained(db, _monkeypatch):
+    from orientdb_tpu.storage.deltas import arm_delta_maintenance
+
+    arm_delta_maintenance(db, spare_vertices=16, spare_edges=16)
+    return db.current_snapshot(require_fresh=True)
+
+
+def _attach_on_a_mesh(db, _monkeypatch):
+    from orientdb_tpu.parallel.sharded import make_mesh
+
+    return attach_fresh_snapshot(db, mesh=make_mesh(8))
+
+
+def _attach_tiered(db, monkeypatch):
+    from orientdb_tpu.storage import tiering
+    from orientdb_tpu.utils.config import config
+
+    monkeypatch.setattr(config, "tier_block_edges", 4)
+    adj = tiering.adjacency_bytes(attach_fresh_snapshot(db))
+    db.detach_snapshot()
+    monkeypatch.setattr(config, "tier_hbm_cap_bytes", max(1, adj // 2))
+    snap = attach_fresh_snapshot(db)
+    assert snap._tier is not None
+    return snap
+
+
+class TestASliceOnlyWhereTheCsrCannotChange:
+    """One edge a message, on four attachments of one graph: the unit bit
+    is set on a plain snapshot alone. A delta-maintained one may give a
+    message a second edge, a tier pages the edge arrays and a mesh shards
+    them; there the pass runs as before, with the same answer."""
+
+    MSGS = TestAHullUnderDeltas.MSGS
+    ATTACH = {
+        "plain": (_attach_plain, True),
+        "delta_maintained": (_attach_maintained, False),
+        "mesh_sharded": (_attach_on_a_mesh, False),
+        "tiered": (_attach_tiered, False),
+    }
+
+    @pytest.mark.parametrize("kind", sorted(ATTACH))
+    def test_the_bit_is_set_on_a_plain_snapshot_alone(self, monkeypatch, kind):
+        from orientdb_tpu.exec.tpu_engine import drain_warmups
+        from orientdb_tpu.ops.device_graph import device_graph
+
+        attach, slices = self.ATTACH[kind]
+        db = Database(f"slice_{kind}")
+        db.schema.create_vertex_class("Writer")
+        db.schema.create_vertex_class("Msg")
+        db.schema.create_edge_class("Wrote")
+        writers = [db.new_vertex("Writer", age=20 + i) for i in range(6)]
+        for i in range(10):
+            db.new_edge("Wrote", db.new_vertex("Msg", length=i), writers[i % 6])
+        try:
+            dec = device_graph(attach(db, monkeypatch)).edges["Wrote"]
+            assert (dec.unit_out, dec.unit_in) == (slices, False)
+            before = _counted("plan.segsum.unit")
+            ask = lambda engine: db.query(
+                self.MSGS, {"a": 24}, engine=engine, strict=(engine == "tpu")
+            ).to_dicts()
+            assert ask("tpu") == ask("oracle") == [{"n": 8}]
+            assert (_counted("plan.segsum.unit") > before) == slices
+        finally:
+            drain_warmups()
+            db.detach_snapshot()
+
+
+@pytest.fixture(scope="module", params=[3, 2**31 + 5], ids=["seed_3", "seed_2e31_5"])
+def snb_raw(request):
+    """The benchmark's own SNB arrays at a small scale, attached as its
+    hand-over attaches them, with its reference."""
+    from benchmark import run
+    from orientdb_tpu.exec.tpu_engine import drain_warmups
+
+    S = run.load_module("kinds", "snb_arrays")
+    scale = {"persons": 300, "avg_knows": 5, "msgs_per_person": 3}
+    raw = S.make_raw({**scale, "supernodes": 0, "supernode_degree": 0}, request.param)
+    db, _snap = S.attach(raw, f"snb_ref_{request.param}")
+    yield db, S.Reference(raw)
+    drain_warmups()
+    db.detach_snapshot()
+
+
+def _scan_4s_shapes():
+    """name -> (statement, reference kind, two parameter sets: the pool's
+    lead, and each range's upper end) of ``benchmark/traffic/scan_4s.json``."""
+    import json
+    import os
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "traffic", "scan_4s.json",
+    )
+    with open(path) as f:
+        shapes = json.load(f)["shapes"]
+    pick = lambda spec, end: spec["const"] if "const" in spec else (
+        spec["lead"] if end is None else spec["int"][end]
+    )
+    return {
+        s["name"]: (
+            s["sql"],
+            s["reference"],
+            [{k: pick(v, end) for k, v in s["params"].items()} for end in (None, 1)],
+        )
+        for s in shapes
+    }
+
+
+SCAN_4S_SHAPES = _scan_4s_shapes()
+
+
+class TestTheScanStatementsAreTheBenchmarksReference:
+    @pytest.mark.parametrize("shape", sorted(SCAN_4S_SHAPES))
+    def test_a_scan_answer_is_the_references(self, snb_raw, shape):
+        db, ref = snb_raw
+        sql, kind, params = SCAN_4S_SHAPES[shape]
+        for p in params:
+            want = ref.answer(kind, p)
+            got = db.query(sql, p, engine="tpu", strict=True).to_dicts()
+            assert [tuple(r.values()) for r in got] == want, (shape, p)
+            assert want[0][0] > 0, "a count of nothing tests nothing"
 
 
 # -- a COUNT folds its root as a mask over the root's range ---------------------

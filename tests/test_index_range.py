@@ -455,7 +455,9 @@ AGES = {"minAge": 40, "maxAge": 30}
 
 def _segsums():
     c = metrics.snapshot()["counters"]
-    return c.get("plan.segsum.hull", 0), c.get("plan.segsum.full", 0)
+    return tuple(
+        c.get(f"plan.segsum.{form}", 0) for form in ("hull", "full", "unit")
+    )
 
 
 def _segment_sum_gathers(jaxpr):
@@ -472,7 +474,7 @@ class TestSegmentSumsOverTheHull:
     def test_a_knows_count_gathers_no_boundary_past_the_persons(self, snb, shape):
         db, snap = snb
         sql, passes = SEGSUMS[shape]
-        hull0, full0 = _segsums()
+        hull0, full0, unit0 = _segsums()
         rows, plan, _reads_counted = _record(db, snap, sql, AGES)
         assert rows[0]["n"] > 0
         lo, hi = snap.vertex_hull("Person")
@@ -481,9 +483,10 @@ class TestSegmentSumsOverTheHull:
         jaxpr = jax.make_jaxpr(plan._replay)(
             plan._arg_subset(), plan._dyn_args(AGES)
         ).jaxpr
-        hull1, full1 = _segsums()
+        hull1, full1, unit1 = _segsums()
         calls = _segment_sum_gathers(jaxpr)
-        # two boundary gathers a pass, each as long as the person hull
+        # two boundary gathers a pass, each as long as the person hull:
+        # knows has persons without a friend and persons with many
         assert calls == [[hi - lo, hi - lo]] * passes, calls
         # and nothing in the whole replay is as wide as the vertex universe
         # (or its bucket): what is left are the [E] gathers over knows and
@@ -494,7 +497,31 @@ class TestSegmentSumsOverTheHull:
         assert not [n for n in lengths if V <= n < E], sorted(set(lengths))
         # counted where Python lowers the pass: the eager recording (with
         # its float32 twin) and this trace, every one over a hull
-        assert hull1 - hull0 >= 3 * passes and full1 == full0
+        assert hull1 - hull0 >= 3 * passes and full1 == full0 and unit1 == unit0
+
+    def test_a_hull_of_one_edge_a_vertex_is_sliced(self, snb):
+        """creator_1hop walks hasCreator from the messages, one edge a
+        message: its pass sums nothing, the replay reads the edges' values
+        where they lie, and gathers only age and its presence at every
+        edge's creator."""
+        db, snap = snb
+        p = {"minLen": 900, "maxAge": 30}
+        before = _segsums()
+        rows, plan, _reads_counted = _record(
+            db, snap, CREATOR_1HOP.replace("AS n", "AS sliced"), p
+        )
+        assert rows[0]["sliced"] > 0
+        dec = plan.solver.dg.edges["hasCreator"]
+        assert dec.unit_out and dec.hull_out == snap.vertex_hull("Message")
+        jaxpr = jax.make_jaxpr(plan._replay)(plan._arg_subset(), plan._dyn_args(p)).jaxpr
+        # one pass a lowering, a slice: the eager recording, its float32
+        # twin and the replay's trace
+        hull, full, unit = np.subtract(_segsums(), before)
+        assert (hull, full) == (0, 0) and unit >= 3
+        assert _segment_sum_gathers(jaxpr) == [[]]
+        E = dec.num_edges
+        assert _gather_index_lengths(jaxpr) == [E, E]
+        assert "cumsum" not in {e.primitive.name for e in _eqns(jaxpr)}
 
     def test_a_class_that_spans_the_universe_counts_as_full(self):
         """A persons-only graph: the hull is the universe, and the pass is
@@ -507,14 +534,14 @@ class TestSegmentSumsOverTheHull:
         try:
             dec = device_graph(snap).edges["knows"]
             assert dec.hull_out == dec.hull_in == (0, 2000)
-            hull0, full0 = _segsums()
+            hull0, full0, unit0 = _segsums()
             _rows, plan, _counted = _record(db, snap, KNOWS_1HOP, AGES)
             jaxpr = jax.make_jaxpr(plan._replay)(
                 plan._arg_subset(), plan._dyn_args(AGES)
             ).jaxpr
-            hull1, full1 = _segsums()
+            hull1, full1, unit1 = _segsums()
             assert _segment_sum_gathers(jaxpr) == [[2000, 2000]]
-            assert full1 - full0 >= 3 and hull1 == hull0
+            assert full1 - full0 >= 3 and hull1 == hull0 and unit1 == unit0
         finally:
             drain_warmups()
             db.detach_snapshot()
@@ -559,14 +586,17 @@ SHORTEST_PATH_LEN = (
 #: the benchmark's six statements on the module's graph: statement,
 #: parameters, the passes a lowering of its COUNT (takes from the plan,
 #: lowers), and the index length of every gather of its replay, in order.
-#: The lists of all but config5 are the ones the parent of PR 37 lowered
-#: (3 000 persons, 9 000 messages and hasCreator edges, 17 927 knows
-#: edges); config5's lost [9000, 9000, 3000, 3000] at its head: the
-#: reorder of an all-true edge mask, v_class at every creator edge's
-#: message, and the two boundary gathers of the pass's segment sum
+#: The lists of the knows statements and the rooted ones are the ones
+#: lowered before any pass was kept (3 000 persons, 9 000 messages and
+#: hasCreator edges, 17 927 knows edges); config5's lost [9000, 9000,
+#: 3000, 3000] at its head: the reorder of an all-true edge mask, v_class
+#: at every creator edge's message, and the two boundary gathers of the
+#: pass's segment sum; creator_1hop's lost its two boundary gathers (one
+#: edge a message: the segment sum is a slice) and keeps age and its
+#: presence at every edge's creator
 KEPT = {
     "config5": (*SCAN_4S["config5"][:2], (1, 1), [17927, 17927, 3000, 3000]),
-    "creator_1hop": (*SCAN_4S["creator_1hop"][:2], (0, 1), [9000] * 4),
+    "creator_1hop": (*SCAN_4S["creator_1hop"][:2], (0, 1), [9000] * 2),
     "knows_2hop": (
         *SCAN_4S["knows_2hop"][:2],
         (0, 2),

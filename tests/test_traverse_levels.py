@@ -286,6 +286,48 @@ def test_the_level_loop_ends_on_the_device():
         db.detach_snapshot()
 
 
+def ring(n: int = 64):
+    """A ring stored one way round: every vertex holds one edge each way."""
+    at = np.arange(n)
+    return n, np.stack([at, (at + 1) % n], 1)
+
+
+@pytest.mark.parametrize("graph", ["kronecker", "ring"])
+def test_the_dense_pass_sums_by_prefix_sums_on_every_graph(graph):
+    """The level kernel is not handed the unit bit of the COUNT's weight
+    pass (``ops/device_graph.unit_degree``): on a Kronecker graph, where
+    the bit is unset, and on a ring, where it is set both ways, the dense
+    pass lowers its two segment sums as one prefix sum and two boundary
+    gathers each, and the search answers as numpy does."""
+    from orientdb_tpu.ops.device_graph import device_graph
+
+    V, edges = {"kronecker": kronecker, "ring": ring}[graph]()
+    db = array_native(V, edges)
+    try:
+        assert ask(db, 5) == numpy_search(V, edges, 5)[0]
+        dec = device_graph(db.current_snapshot()).edges["Link"]
+        assert (dec.unit_out, dec.unit_in) == ((True, True) if graph == "ring" else (False, False))
+        (variants,) = db.current_snapshot()._plan_cache.values()
+        plan = variants.plans[0]
+        closed = jax.make_jaxpr(plan._replay)(plan._arg_subset(), plan._dyn_args({"source": 9}))
+        sums, todo = [], [closed.jaxpr]
+        while todo:
+            for eqn in todo.pop().eqns:
+                for v in eqn.params.values():
+                    for sub in v if isinstance(v, (list, tuple)) else (v,):
+                        inner = getattr(sub, "jaxpr", sub)
+                        if hasattr(inner, "eqns"):
+                            todo.append(inner)
+                if eqn.params.get("name") == "_segment_sum":
+                    sums.append(_primitives(eqn.params["jaxpr"].jaxpr, []))
+        assert len(sums) == 2
+        for names in sums:
+            assert names.count("cumsum") == 1 and names.count("gather") == 2, names
+    finally:
+        drain_warmups()
+        db.detach_snapshot()
+
+
 def test_a_search_past_the_depth_table_records_the_next_size_and_answers_whole():
     V, edges = components()
     db = array_native(V, edges)
