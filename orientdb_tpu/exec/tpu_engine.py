@@ -89,10 +89,10 @@ def _copy_to_host_async(d) -> None:
 
 
 def _fetch_profiled(devs: List, split_sync: bool = True) -> List[np.ndarray]:
-    """Fetch dispatched device results with the 3-way accounting the
-    perf work aims by: device-sync time, transfer time, bytes moved
-    (`tpu.device_s` / `tpu.transfer_s` / `tpu.bytes_fetched`; host
-    marshalling is timed by callers as `tpu.host_s`). Execution is
+    """Fetch dispatched device results: the host's wait in the sync,
+    its copy after it, and the bytes moved (`tpu.bytes_fetched`), the
+    first two for the stats plane's per-fingerprint attribution and
+    the flight recorder's intervals. Execution is
     in-order per device, so blocking on the LAST dispatched result
     covers the whole batch with one sync instead of N. ``split_sync=
     False`` skips the separate sync wave — a lone query must not pay an
@@ -110,8 +110,6 @@ def _fetch_profiled(devs: List, split_sync: bool = True) -> List[np.ndarray]:
     arrs = [np.asarray(d) for d in devs]
     t2 = _time.perf_counter()
     if devs:
-        metrics.observe("tpu.device_s", t1 - t0)
-        metrics.observe("tpu.transfer_s", t2 - t1)
         nbytes = sum(int(a.nbytes) for a in arrs)
         metrics.incr("tpu.bytes_fetched", nbytes)
         # per-fingerprint attribution (obs/stats): one thread-local add
@@ -5113,10 +5111,6 @@ def _finish_pending(db, items, pending, out, fresh) -> None:
         nbytes += int(d.nbytes)
     t2 = _time.perf_counter()
     if pending:
-        # overlapped phases: the meta drain tracks device compute, the
-        # page drain is the transfer tail that didn't hide behind it
-        metrics.observe("tpu.device_s", t1 - t0)
-        metrics.observe("tpu.transfer_s", t2 - t1)
         # the part of the caller's turn (a lane worker's lane.finish
         # span) in which the host only waits for the device
         metrics.incr_many(

@@ -25,9 +25,9 @@ numbers the counters cannot express:
 - **overlap accounting** (:meth:`FlightRecorder.overlap`) — the
   derived metrics: *device-idle fraction* (1 − merged "device" time
   over the window span; a record's "device" interval is the HOST's
-  wait for the result (``tpu.device_s``), so this is the share of the
-  wall in which no host thread waited on the device, not a device
-  clock's reading), *transfer-hidden fraction* (bytes whose copy
+  wait for the result in ``block_until_ready``, so this is the share
+  of the wall in which no host thread waited on the device, not a
+  device clock's reading), *transfer-hidden fraction* (bytes whose copy
   interval overlapped device compute vs serialized after it — the
   number that proves or refutes the PR-13 prefetch and PR-12 double
   buffer), *lane queue/window vs service decomposition*, and *ring

@@ -22,6 +22,13 @@ in a profiler trace it lies on its own thread's host line, on the
 device operations' clock. A process that never imported JAX (the
 remote client) never does on a span's account.
 
+Two clocks no span can hold are read here too, and merged into every
+``metrics.snapshot()``: the CPU time of the serving threads by role
+(:data:`roles`, ``thread.<role>.cpu_us``), and the garbage collector's
+pauses (:data:`gc_clock`, ``gc.pause_us``, ``gc.collections.gen<N>``,
+``gc.pause_us.gen<N>``), a collection of generation 1 or 2 also an
+annotation ``gc.gen<N>`` on the trace's clock while a server runs.
+
 Usage::
 
     with span("tx.commit", creates=3) as sp:
@@ -33,6 +40,8 @@ Usage::
 
 from __future__ import annotations
 
+import _thread
+import gc
 import itertools
 import sys
 import threading
@@ -254,3 +263,156 @@ class Tracer:
 
 #: the process-wide span ring (sized by config.trace_capacity)
 tracer = Tracer(config.trace_capacity)
+
+
+# -- the host's own time: CPU by thread role, and the collector's pauses ------
+
+
+class RoleClocks:
+    """CPU time of the serving threads, summed by role.
+
+    A serving thread declares its role once, at the top of its loop
+    (``session``, ``lane``, ``watchdog``), and retires in the loop's
+    ``finally``. Nothing is stamped per request: :meth:`counters`, which
+    ``metrics.snapshot()`` calls, reads each live thread's CPU clock
+    (``time.pthread_getcpuclockid``) and adds what retired threads
+    left, so ``thread.<role>.cpu_us`` never falls and a window's delta
+    holds while sessions come and go. A thread that ends without
+    retiring (a pool's worker) keeps the last reading a snapshot took.
+    The clock counts the thread's time in the kernel too (a
+    ``sendall``), never its waits."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        #: thread ident -> [role, CPU clock id, ns at declare, last ns]
+        self._live: Dict[int, list] = {}
+        #: role -> ns its ended threads spent
+        self._retired: Dict[str, int] = {}
+
+    def declare(self, role: str) -> None:
+        getclock = getattr(time, "pthread_getcpuclockid", None)
+        if getclock is None:  # no per-thread clock on this platform
+            return
+        ident = threading.get_ident()
+        clock = getclock(ident)
+        base = time.thread_time_ns()
+        with self._lock:
+            old = self._live.get(ident)
+            if old is not None:
+                # this thread's former role, or a thread gone without
+                # retiring whose id this one was given
+                ended = base if old[1] == clock else old[3]
+                self._retired[old[0]] += ended - old[2]
+            self._retired.setdefault(role, 0)
+            self._live[ident] = [role, clock, base, base]
+
+    def retire(self) -> None:
+        ns = time.thread_time_ns()
+        with self._lock:
+            entry = self._live.pop(threading.get_ident(), None)
+            if entry is not None:
+                role, _clock, base, last = entry
+                self._retired[role] += max(ns, last) - base
+
+    def counters(self) -> Dict[str, int]:
+        with self._lock:
+            total = dict(self._retired)
+            for ident, entry in list(self._live.items()):
+                role, clock, base, last = entry
+                try:
+                    ns = time.clock_gettime_ns(clock)
+                except OSError:  # the thread is gone
+                    ns = -1
+                if ns < last:  # gone, or its id reused: keep what was read
+                    self._retired[role] += last - base
+                    del self._live[ident]
+                    ns = last
+                else:
+                    entry[3] = ns
+                total[role] += ns - base
+        return {f"thread.{role}.cpu_us": ns // 1000 for role, ns in total.items()}
+
+
+#: the serving threads' CPU clocks (server/binary_server, server/coalesce,
+#: obs/watchdog declare into it)
+roles = RoleClocks()
+
+
+class GcClock:
+    """Every garbage collection, counted and timed, while a server runs.
+
+    ``Server.startup`` installs one ``gc.callbacks`` hook and
+    ``shutdown`` removes it (counted, for several servers in one
+    process); nothing is installed at import, so the remote client and
+    the load generator pay nothing. At each collection's ``stop`` the
+    hook adds its pause to ``gc.pause_us`` and ``gc.pause_us.gen<N>``
+    and one to ``gc.collections.gen<N>``. A collection of generation 1
+    or 2 is also a ``TraceAnnotation`` named ``gc.gen<N>``, from
+    ``start`` to ``stop``: it nests inside whatever frame allocated,
+    so in a profiler trace it is the shortest host event over the
+    pause. Generation 0 runs many times a second, each well under a
+    millisecond, and is counted only.
+
+    The totals are this object's own and ``metrics.snapshot()`` reads
+    them: a collection starts inside any frame, one that holds the
+    registry's lock included, so the hook takes no lock but its own, a
+    raw re-entrant one that the lock sanitizer does not wrap."""
+
+    def __init__(self) -> None:
+        self._mu = _thread.RLock()
+        self._users = 0
+        self._totals: Dict[str, int] = {}
+        #: this thread's collection in progress: (start ns, annotation)
+        self._local = threading.local()
+
+    def install(self) -> None:
+        with self._mu:
+            self._users += 1
+            if self._users == 1:
+                gc.callbacks.append(self._hook)
+
+    def uninstall(self) -> None:
+        with self._mu:
+            if self._users == 0:
+                return
+            self._users -= 1
+            if self._users == 0 and self._hook in gc.callbacks:
+                gc.callbacks.remove(self._hook)
+
+    def _hook(self, phase: str, info: dict) -> None:
+        gen = info["generation"]
+        local = self._local
+        if phase == "start":
+            ann = None
+            if gen:
+                annotation = _annotation or _find_annotation()
+                if annotation is not None:
+                    ann = annotation(f"gc.gen{gen}")
+                    ann.__enter__()
+            local.open = (time.perf_counter_ns(), ann)
+            return
+        t0, ann = getattr(local, "open", None) or (None, None)
+        if t0 is None:  # installed between this collection's start and stop
+            return
+        us = (time.perf_counter_ns() - t0) // 1000
+        local.open = None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        with self._mu:
+            t = self._totals
+            for name, n in (
+                ("gc.pause_us", us),
+                (f"gc.pause_us.gen{gen}", us),
+                (f"gc.collections.gen{gen}", 1),
+            ):
+                t[name] = t.get(name, 0) + n
+
+    def counters(self) -> Dict[str, int]:
+        with self._mu:
+            return dict(self._totals)
+
+
+#: the collector's pauses (installed by server/server.Server.startup)
+gc_clock = GcClock()
+metrics.add_source(roles.counters)
+metrics.add_source(gc_clock.counters)

@@ -14,7 +14,8 @@ Each tick's rule evaluation runs under a ``watchdog.tick`` span (the
 scrub before it under ``scrub.sweep``), so the watchdog's own cost
 shows up in the profile plane like any other stage and, through the
 span fold (``obs/trace``), as the counters ``span.watchdog.tick.us``
-and ``span.scrub.sweep.us`` beside ``watchdog.ticks``.
+and ``span.scrub.sweep.us`` beside ``watchdog.ticks``. The thread's
+CPU time is ``thread.watchdog.cpu_us`` (``obs/trace.roles``).
 """
 
 from __future__ import annotations
@@ -23,7 +24,7 @@ import threading
 from typing import Dict, Optional
 
 from orientdb_tpu.obs.alerts import engine
-from orientdb_tpu.obs.trace import span
+from orientdb_tpu.obs.trace import roles, span
 from orientdb_tpu.utils.config import config
 from orientdb_tpu.utils.logging import get_logger
 from orientdb_tpu.utils.metrics import metrics
@@ -66,16 +67,20 @@ class HealthWatchdog:
             t.join(timeout=5)
 
     def _loop(self) -> None:
-        while not self._stop.is_set():
-            try:
-                self.tick()
-            except Exception:  # pragma: no cover - the loop must live
-                log.exception("watchdog tick failed")
-            self._stop.wait(
-                self.interval
-                if self.interval is not None
-                else config.watchdog_interval_s
-            )
+        roles.declare("watchdog")
+        try:
+            while not self._stop.is_set():
+                try:
+                    self.tick()
+                except Exception:  # pragma: no cover - the loop must live
+                    log.exception("watchdog tick failed")
+                self._stop.wait(
+                    self.interval
+                    if self.interval is not None
+                    else config.watchdog_interval_s
+                )
+        finally:
+            roles.retire()
 
     # -- one evaluation round -----------------------------------------------
 

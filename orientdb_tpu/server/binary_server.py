@@ -259,6 +259,9 @@ class _Session:
         critpath.commit(cp)
 
     def run(self) -> None:
+        from orientdb_tpu.obs.trace import roles
+
+        roles.declare("session")
         try:
             while True:
                 raw = recv_frame_raw(self.sock)
@@ -326,6 +329,7 @@ class _Session:
                 self.sock.close()
             except OSError:
                 pass
+            roles.retire()
 
     def _dispatch(self, req: dict) -> dict:
         # the envelope's "trace" field is the binary channel's
@@ -371,8 +375,13 @@ class _Session:
                 if req.get("pipeline") and self._pool is None:
                     from concurrent.futures import ThreadPoolExecutor
 
+                    from orientdb_tpu.obs.trace import roles
+
                     self._pool = ThreadPoolExecutor(
-                        max_workers=32, thread_name_prefix="binq"
+                        max_workers=32,
+                        thread_name_prefix="binq",
+                        initializer=roles.declare,
+                        initargs=("session",),
                     )
                 return {"ok": True, "serialization": (
                     "binary" if self.binser else "json"

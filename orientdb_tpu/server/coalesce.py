@@ -54,7 +54,7 @@ from typing import Dict, List, Optional, Tuple
 
 from orientdb_tpu.obs.propagation import continue_trace, current_context
 from orientdb_tpu.obs.registry import obs
-from orientdb_tpu.obs.trace import span
+from orientdb_tpu.obs.trace import roles, span
 from orientdb_tpu.utils.config import config
 from orientdb_tpu.utils.logging import get_logger
 from orientdb_tpu.utils.metrics import metrics
@@ -208,6 +208,7 @@ class _Lane:
     # -- worker side ---------------------------------------------------------
 
     def _run(self) -> None:
+        roles.declare("lane")
         try:
             self._run_loop()
         except BaseException as e:
@@ -228,6 +229,8 @@ class _Lane:
                 item.event.set()
             self.coal._drop_lane(self)
             raise
+        finally:
+            roles.retire()
 
     def _run_loop(self) -> None:
         inflight: Optional[Tuple[List[_Item], object, float]] = None

@@ -203,6 +203,12 @@ class Server:
             from orientdb_tpu.obs.watchdog import HealthWatchdog
 
             self._watchdog = HealthWatchdog(self).start()
+        # every garbage collection counted and timed while serving
+        # (obs/trace.gc_clock), never in a client's process
+        if not self.running:
+            from orientdb_tpu.obs.trace import gc_clock
+
+            gc_clock.install()
         self.running = True
         log.info(
             "server '%s' up: http=%d binary=%d",
@@ -213,6 +219,10 @@ class Server:
         return self
 
     def shutdown(self) -> None:
+        if self.running:
+            from orientdb_tpu.obs.trace import gc_clock
+
+            gc_clock.uninstall()
         self.running = False
         wd = self._watchdog
         if wd is not None:

@@ -10,12 +10,17 @@ Two primitive kinds, both thread-safe:
   several under one lock
 - durations  — ``observe("query.tpu.dispatch", seconds)`` keeping
   count/total/max so rates and tails are recoverable.
+
+A *source* (``add_source``) is a function whose counters ``snapshot``
+merges in: totals another module keeps and reads only when asked, as
+``obs/trace`` keeps the serving threads' CPU clocks and the
+collector's pauses.
 """
 
 from __future__ import annotations
 
 import threading
-from typing import Dict, Mapping
+from typing import Callable, Dict, List, Mapping
 
 
 class MetricsRegistry:
@@ -24,6 +29,12 @@ class MetricsRegistry:
         self._counters: Dict[str, int] = {}
         self._durations: Dict[str, Dict[str, float]] = {}
         self._gauges: Dict[str, float] = {}
+        self._sources: List[Callable[[], Mapping[str, int]]] = []
+
+    def add_source(self, fn: Callable[[], Mapping[str, int]]) -> None:
+        """Register once; ``reset`` keeps it (it holds no data here)."""
+        if fn not in self._sources:
+            self._sources.append(fn)
 
     def incr(self, name: str, n: int = 1) -> None:
         with self._lock:
@@ -69,11 +80,14 @@ class MetricsRegistry:
 
     def snapshot(self) -> Dict[str, object]:
         with self._lock:
-            return {
+            snap = {
                 "counters": dict(self._counters),
                 "durations": {k: dict(v) for k, v in self._durations.items()},
                 "gauges": dict(self._gauges),
             }
+        for fn in self._sources:  # outside the lock: a source has its own
+            snap["counters"].update(fn())
+        return snap
 
     def reset(self) -> None:
         with self._lock:

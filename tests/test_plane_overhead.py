@@ -16,6 +16,10 @@ worker thread), plus the lock sanitizer on a single-threaded workload.
 Each case asserts (a) with the plane switched off by its own setting
 and the others as they ship, and (b) with that plane alone switched on
 (beside the stats plane's sampling decision, which three of them ride).
+Beside the planes, the metrics registry every one of them folds into:
+its calls and lock acquisitions a door call, and no call at all into
+the host's clocks (``obs/trace.RoleClocks``, ``GcClock``), which are
+read at a snapshot and never on a request's path.
 
 ``READINGS`` holds what the code does today, a door call. The planes'
 code is not this file's to change: several planes that are "off" still
@@ -38,6 +42,7 @@ from orientdb_tpu.obs import critpath, memledger, stats, timeline
 from orientdb_tpu.storage.ingest import generate_demodb
 from orientdb_tpu.storage.snapshot import attach_fresh_snapshot
 from orientdb_tpu.utils.config import config
+from orientdb_tpu.utils.metrics import metrics
 
 SQL = (
     "MATCH {class:Profiles, as:p, where:(uid = :u)}"
@@ -119,6 +124,14 @@ READINGS = {
 }
 #: calls into analysis/sanitizer.py an insert, off and on
 SANITIZER_READING = (0, 9)
+#: door -> a door call's Python calls into utils/metrics.py and
+#: acquisitions of the registry's lock, the planes as they ship. A fetch
+#: observes no duration of its own: its wait and copy go to the stats
+#: plane and the flight recorder, and on the lane path to the counter
+#: ``tpu.fetch_wait_us``
+REGISTRY_READINGS = {"query": (9, 6), "query_batch": (11, 8), "lane": (9, 6)}
+#: the classes of obs/trace.py that keep the host's clocks
+HOST_CLOCKS = ("RoleClocks.", "GcClock.")
 
 
 def _bound(reading: int) -> float:
@@ -308,6 +321,41 @@ def test_a_door_call_pays_a_plane_a_counted_amount(
     assert on_locks <= _bound(want_on_locks) * N, said
     # the switch was really thrown: the plane on does what off did not
     assert on.total > off.total or on_locks > off_locks, said
+
+
+class _QualCalls:
+    """``sys.setprofile`` hook: calls into one file by qualified name."""
+
+    def __init__(self, suffix: str) -> None:
+        self.suffix = suffix
+        self.names = set()
+
+    def __call__(self, frame, event, arg) -> None:
+        if event == "call" and frame.f_code.co_filename.endswith(self.suffix):
+            self.names.add(frame.f_code.co_qualname)
+
+
+@pytest.mark.parametrize("door", ["query", "query_batch", "lane"])
+def test_a_door_call_pays_the_registry_a_counted_amount_and_no_host_clock(
+    doors, monkeypatch, door
+):
+    _set(monkeypatch, AS_SHIPPED)
+    run = doors[door]
+    calls, locks = _count("utils/metrics.py", [(metrics, "_lock")], run)
+    want_calls, want_locks = REGISTRY_READINGS[door]
+    said = f"registry at {door}, {N} door calls: {calls.by_name}, {locks} locks"
+    assert calls.total <= _bound(want_calls) * N, said
+    assert locks <= _bound(want_locks) * N, said
+    # one duration a call, ``tpu.host_s``
+    assert calls.by_name.get("observe", 0) <= N, said
+    clocks = _QualCalls("obs/trace.py")
+    sys.setprofile(clocks)
+    try:
+        for i in range(N):
+            run(i)
+    finally:
+        sys.setprofile(None)
+    assert not {n for n in clocks.names if n.startswith(HOST_CLOCKS)}, clocks.names
 
 
 def test_the_lock_sanitizer_pays_by_the_acquisition():
