@@ -636,6 +636,68 @@ def build_bitmap_hops(dg: DeviceGraph, items, sched=None, tier=None,
 #: COUNT's weight chain (`TpuMatchSolver._pushdown_weights`). No graph
 #: array has a key under ``plan:``
 _COUNT_W = "plan:count_w"
+#: the prefix of a plan's copies of a far end's columns (`_FarEnds`)
+_ENDS = "plan:ends"
+
+
+class _FarEnds:
+    """The vertex columns a weight pass's destination mask reads, as the
+    plan keeps them in the order of a hop whose hull holds one edge a
+    vertex (`ops/device_graph.unit_degree`): position ``e`` holds the
+    column at edge ``e``'s far end. The recording gathers each column
+    once, as the mask first reads it, into ``solver.plan_consts``; a
+    replay reads it back as a jit argument (``dg.arrays``), so the mask
+    over ``K.IndexRange(0, E)`` slices where it gathered ``[E]`` wide.
+    Quacks as the device graph to `TpuMatchSolver._compile_node`."""
+
+    def __init__(self, solver: "TpuMatchSolver", prefix: str, emit) -> None:
+        self.solver = solver
+        self.prefix = prefix
+        #: () -> the far end of every edge, in the hop's order
+        self.emit = emit
+        self.columns = {
+            n: _EndColumn(self, c) for n, c in solver.dg.columns.items()
+        }
+
+    def array(self, name: str, source, fill) -> jnp.ndarray:
+        key = f"{self.prefix}:{name}"
+        s = self.solver
+        if not s.sched.recording:
+            return s.dg.arrays[key]
+        if key not in s.plan_consts:
+            s.plan_consts[key] = K.take_pad(source(), self.emit(), fill)
+        return s.plan_consts[key]
+
+    @property
+    def v_class(self):
+        return self.array("v_class", lambda: self.solver.dg.v_class, jnp.int32(-1))
+
+
+class _EndColumn:
+    """A `DeviceColumn` as `_FarEnds` keeps it: its own name, kind and
+    host dictionary, its values and presence in the hop's edge order."""
+
+    __slots__ = ("_ends", "_col", "name", "kind", "dictionary")
+
+    def __init__(self, ends: _FarEnds, col) -> None:
+        self._ends, self._col = ends, col
+        self.name, self.kind, self.dictionary = col.name, col.kind, col.dictionary
+
+    @property
+    def values(self):
+        return self._ends.array(f"{self.name}:v", lambda: self._col.values, 0)
+
+    @property
+    def present(self):
+        return self._ends.array(f"{self.name}:p", lambda: self._col.present, False)
+
+    @property
+    def dict_unsorted(self) -> bool:
+        return self._col.dict_unsorted
+
+    @property
+    def dict_lookup(self):
+        return self._col.dict_lookup
 
 
 class TpuMatchSolver:
@@ -661,6 +723,8 @@ class TpuMatchSolver:
         #: (`_pushdown_weights`): the plan hands them to its replays as
         #: jit arguments beside the graph's (`_CompiledPlan._arg_subset`)
         self.plan_consts: Dict[str, jnp.ndarray] = {}
+        #: destination masks over a unit hop's copies (`_ends_mask`)
+        self._ends_masks: Dict[tuple, object] = {}
         snap = db.current_snapshot(require_fresh=True)
         if snap is None:
             raise Uncompilable("no fresh snapshot attached")
@@ -1054,10 +1118,12 @@ class TpuMatchSolver:
             )
         return self._vertex_scope_cache
 
-    def _compile_node(self, node: PatternNode):
+    def _compile_node(self, node: PatternNode, ends: Optional[_FarEnds] = None):
         """Node admission mask: fn(idx) -> bool mask over vertex ids,
         ``idx`` an int32 array or a ``K.IndexRange`` (whose column reads
-        are slices).
+        are slices). With ``ends`` (no rid filter, no binding) the mask
+        reads that hop's copies of the columns, and ``idx`` is a position
+        in its edge order.
 
         Mirrors oracle.check_node: class closure ∧ rid ∧ WHERE. A WHERE
         referencing earlier bindings (``alias.prop``) compiles against the
@@ -1065,6 +1131,7 @@ class TpuMatchSolver:
         needs env["bindings"] at evaluation (``mask.uses_bindings``).
         ``mask.uses_params`` says whether its WHERE reads a dynamic
         parameter: without one the mask is the same on every replay."""
+        graph = self.dg if ends is None else ends
         parts = []
         uses_bindings = False
         has_class = any(f.class_name for f in node.filters)
@@ -1081,7 +1148,7 @@ class TpuMatchSolver:
         for f in node.filters:
             if f.class_name:
                 ids = self.dg.class_ids(f.class_name)
-                parts.append(self._class_mask_fn(ids))
+                parts.append(self._class_mask_fn(ids, graph))
             if f.rid is not None:
                 want = self.snap.idx_of(RID(f.rid.cluster, f.rid.position))
                 wi = -2 if want is None else want  # -2 matches nothing (≠ -1 pad)
@@ -1101,9 +1168,12 @@ class TpuMatchSolver:
                     fn = compile_predicate(f.where, scope, self.param_box)
                     uses_bindings = uses_bindings or scope.uses_bindings
                 else:
-                    fn = compile_predicate(
-                        f.where, self._vertex_scope(), self.param_box
+                    scope = self._vertex_scope() if ends is None else ColumnScope(
+                        ends.columns,
+                        self.dg.non_columnar,
+                        reserved=set(self.pattern.nodes.keys()),
                     )
+                    fn = compile_predicate(f.where, scope, self.param_box)
                 parts.append(fn)
 
         def mask(idx, env=None, parts=parts):
@@ -1117,9 +1187,9 @@ class TpuMatchSolver:
         mask.uses_params = any(getattr(p, "uses_params", False) for p in parts)
         return mask
 
-    def _class_mask_fn(self, ids: jnp.ndarray):
+    def _class_mask_fn(self, ids: jnp.ndarray, graph):
         def fn(idx, env, ids=ids):
-            cls = K.take_pad(self.dg.v_class, idx, jnp.int32(-1))
+            cls = K.take_pad(graph.v_class, idx, jnp.int32(-1))
             if ids.shape[0] == 0:
                 return jnp.zeros(idx.shape, bool)
             return jnp.isin(cls, ids)
@@ -1853,7 +1923,7 @@ class TpuMatchSolver:
         # under jax.jit, where a span would time XLA tracing, not work
         rec = self.sched.recording
 
-        def chain(part, w):
+        def chain(part, w, ends=False):
             for step in reversed(part):
                 # one span per PatternEdge hop: the COUNT pushdown fuses all
                 # hops into one weight chain, so the honest per-hop timing is
@@ -1861,13 +1931,17 @@ class TpuMatchSolver:
                 with _span(
                     "tpu.step", step=step.describe(), stage="count-pushdown"
                 ) if rec else nullcontext():
-                    w = self._pushdown_weight_step(step, w, univ, mg, vb, dtype)
+                    w = self._pushdown_weight_step(
+                        step, w, univ, mg, vb, dtype, ends
+                    )
             return w
 
-        kept = self._const_passes(steps) if dtype == jnp.int32 else 0
+        kept = self._const_passes(steps)
         live, const = steps[: len(steps) - kept], steps[len(steps) - kept :]
         w = None  # None ≡ all-ones (the implicit weight after the last hop)
-        if const:
+        if const and dtype != jnp.int32:
+            w = chain(const, None)  # the float32 twin lowers every pass
+        elif const:
             lo, hi = self._pass_hull(const[0])
             if rec:
                 self.plan_consts[_COUNT_W] = chain(const, None)[lo:hi]
@@ -1882,10 +1956,40 @@ class TpuMatchSolver:
                     "plan.count.pass_live": len(live),
                 }
             )
-        return chain(live, w)
+        return chain(live, w, ends=True)
+
+    def _ends_mask(self, alias: str, cname: str, d: str):
+        """``alias``'s admission mask over the copies of its columns in
+        the order of ``cname`` walked ``d`` (`_FarEnds`), its argument a
+        position in that order; ``None`` where a rid filter compares the
+        vertex ids themselves. Compiled once a solver."""
+        key = (alias, cname, d)
+        if key not in self._ends_masks:
+            node = self.pattern.nodes[alias]
+            dec = self.dg.edges[cname]
+            ends = _FarEnds(
+                self,
+                f"{_ENDS}:{alias}:{cname}:{d}",
+                lambda: dec.dst if d == "out" else dec.src,
+            )
+            self._ends_masks[key] = (
+                None
+                if any(f.rid is not None for f in node.filters)
+                else self._compile_node(node, ends)
+            )
+        return self._ends_masks[key]
 
     @jax.named_scope("count.weight_pass")
-    def _pushdown_weight_step(self, step, w, univ, mg, vb, dtype):
+    def _pushdown_weight_step(self, step, w, univ, mg, vb, dtype, ends=False):
+        """One pass of the weight chain: ``w`` pulled back over the
+        step's edges and summed by source vertex. With ``ends`` (a pass
+        the int32 chain lowers on every replay, and its float32 twin) a
+        walk whose hull holds one edge a vertex reads its destination's
+        columns from the plan's copies in edge order (`_ends_mask`).
+        Counted where Python lowers the int32 chain:
+        ``plan.count.ends_sliced`` a pass whose every walk read copies,
+        ``plan.count.ends_gathered`` one that evaluated its mask at its
+        ends."""
         dst_alias, classes, dirs = self._pass_shape(step)
         node_mask = self._node_masks[dst_alias]
         # the [vb]-wide precompute only pays for itself where a consumer
@@ -1900,6 +2004,7 @@ class TpuMatchSolver:
         )
         f = step.edge.item.edge_filter
         new_w = jnp.zeros(vb, dtype)
+        sliced: List[bool] = []
         for cname in classes:
             dec = self.dg.edges[cname]
             E = dec.num_edges
@@ -1948,7 +2053,14 @@ class TpuMatchSolver:
                 else:
                     emit, ip, hull = dec.src, dec.indptr_in, dec.hull_in
                     unit, em = dec.unit_in, jnp.take(emask, dec.edge_id_in)
-                if E >= vb:
+                far = self._ends_mask(dst_alias, cname, d) if ends and unit else None
+                sliced.append(far is not None)
+                if far is not None:
+                    # one edge a vertex: position e of this order is the
+                    # hull's vertex lo + e, and its far end's columns are
+                    # the plan's in this order, each read a slice
+                    contrib = em & far(K.IndexRange(0, E, E))
+                elif E >= vb:
                     # [vb] mask precompute + one bool gather beats
                     # re-evaluating the predicate's column gathers
                     # [E]-wide (see _pushdown_weights)
@@ -1959,6 +2071,12 @@ class TpuMatchSolver:
                 if w is not None:
                     vals = vals * K.take_pad(w, emit, dtype(0))
                 new_w = new_w + K.indptr_segment_sum(vals, ip, vb, hull, unit)
+        if dtype == jnp.int32:
+            metrics.incr(
+                "plan.count.ends_sliced"
+                if sliced and all(sliced)
+                else "plan.count.ends_gathered"
+            )
         return new_w
 
     def _folded_root(self, root: PlanStep):

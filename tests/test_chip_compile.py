@@ -163,8 +163,9 @@ CREATOR_1HOP = (
 def test_whole_count_plan_at_real_shapes(one_chip, blocked_cumsum, shape):
     """Record the plan on a tiny graph, then lower its whole jitted
     replay with every graph array at the scale tier's shape. The
-    creator 1-hop's pass is a slice (one edge a message) and keeps
-    nothing."""
+    creator 1-hop's pass is a slice (one edge a message), and so are
+    its reads of the creators' ages: the plan keeps them in message
+    order and hands them to the replay as arguments."""
     import chip_smoke
     from orientdb_tpu.exec import tpu_engine
     from orientdb_tpu.storage import bigshape
@@ -203,15 +204,21 @@ def test_whole_count_plan_at_real_shapes(one_chip, blocked_cumsum, shape):
             )
 
         # what the recording kept for its replays (config5: the messages
-        # of each person; the two hops of literals: the whole chain) is
-        # as long as a vertex hull, and grows with it
+        # of each person; the two hops of literals: the whole chain;
+        # the creator 1-hop: age and its presence at every message's
+        # creator, in message order) is as long as a vertex hull, and
+        # grows with it
         sliced = shape == "creator_1hop_count"
-        assert sorted(plan.consts) == ([] if sliced else ["plan:count_w"])
+        ends = "plan:ends:p:hasCreator:out:age"
+        assert sorted(plan.consts) == (
+            [f"{ends}:p", f"{ends}:v"] if sliced else ["plan:count_w"]
+        )
         assert plan.solver.dg.edges["knows"].unit_out is False
         if "hasCreator" in plan.solver.dg.edges:
             assert plan.solver.dg.edges["hasCreator"].unit_out is True
         dims.update({c.shape[0]: c.shape[0] * scale for c in plan.consts.values()})
         arrays = {k: real(v) for k, v in plan._arg_subset().items()}
+        assert set(plan.consts) <= set(arrays)
         # knows' edges, or (creator 1-hop) the 24 M persons and messages
         widest = 3 * PERSONS if sliced else EDGES
         assert max(s.shape[0] for s in arrays.values()) >= widest

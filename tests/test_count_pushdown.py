@@ -6,7 +6,9 @@ materializing binding tables; these tests pin result parity vs the oracle
 across directions, edge predicates, reversed arrows, multi-hop chains,
 self-loops, and confirm the optimization actually engages; and, where the
 chain begins at the plan's only root, that the count folds that root as a
-mask over its range (`_folds_root`) and every other plan keeps its rows.
+mask over its range (`_folds_root`) and every other plan keeps its rows;
+and, over a hop of one edge a vertex, that a pass reads its far end's
+columns from the plan's copies in edge order (`_FarEnds`).
 """
 
 import pytest
@@ -1191,3 +1193,223 @@ class TestConstantPasses:
         finally:
             drain_warmups()
             db.detach_snapshot()
+
+
+# -- a unit hop reads its far end's columns from the plan, in edge order ---------
+
+
+SLICED_ENDS, GATHERED_ENDS = "plan.count.ends_sliced", "plan.count.ends_gathered"
+
+
+def _ends(run):
+    """``run()``, and the passes (whose far mask read the plan's copies,
+    that evaluated it at their ends) lowered meanwhile, every background
+    trace finished."""
+    before = _counted(SLICED_ENDS, GATHERED_ENDS)
+    got = run()
+    return got, tuple(b - a for a, b in zip(before, _counted(SLICED_ENDS, GATHERED_ENDS)))
+
+
+def _authors_db(name, doubled=False):
+    """Messages that each have one author (``Wrote``, stored from the
+    message, with a weight ``w``) and one signature (``Signed``, stored
+    from the author): both one edge a message. Every fourth writer has no
+    age; ``Bot`` is a subclass of ``Writer``. ``doubled``: the first
+    message has a second author."""
+    db = Database(name)
+    db.schema.create_vertex_class("Writer")
+    db.schema.create_class("Bot", superclasses=("Writer",))
+    db.schema.create_vertex_class("Msg")
+    db.schema.create_edge_class("Wrote")
+    db.schema.create_edge_class("Signed")
+    authors = [
+        db.new_vertex(
+            "Writer", name=f"w{i}", score=i % 3, **({"age": 20 + i} if i % 4 else {})
+        )
+        for i in range(8)
+    ] + [db.new_vertex("Bot", name=f"b{i % 5}", age=18 + i, score=i % 4) for i in range(16)]
+    # fewer messages than bots: every plan below is rooted at the messages
+    msgs = [db.new_vertex("Msg", length=i) for i in range(12)]
+    for i, m in enumerate(msgs):
+        a = authors[(5 * i) % len(authors)]
+        db.new_edge("Wrote", m, a, w=i % 5)
+        db.new_edge("Signed", a, m)
+    if doubled:
+        db.new_edge("Wrote", msgs[0], authors[1], w=2)
+    db._authors = authors
+    return db
+
+
+#: whether a case's lowerings moved (ends_sliced, ends_gathered)
+BY_COPIES, AT_THE_ENDS = (True, False), (False, True)
+BY_AUTHOR = (
+    "MATCH {class:Msg, as:m, where:(length > :l)}-Wrote->"
+    "{as:p, where:(age < :a)} RETURN count(*) AS n"
+)
+BY_AUTHOR_PARAMS = [{"l": 3, "a": 24}, {"l": -1, "a": 30}]
+ENDS_CASES = {
+    # name: (attach, doubled, the statement, the parameters it is asked
+    # with in turn, the counters its lowerings move)
+    "creators_with_an_absent_age": (
+        _attach_plain,
+        False,
+        "MATCH {class:Msg, as:m, where:(length > :l)}-Wrote->"
+        "{as:p, where:(age IS NULL OR age > :a)} RETURN count(*) AS n",
+        [{"l": 3, "a": 24}, {"l": -1, "a": 100}, {"l": 5, "a": 20}],
+        BY_COPIES,
+    ),
+    "a_class_filter_and_two_columns": (
+        _attach_plain,
+        False,
+        # two conjuncts at each end: the messages stay the root
+        "MATCH {class:Msg, as:m, where:(length > :l AND length < 99)}-Wrote->"
+        "{class:Bot, as:p, where:(age < :a AND score >= :s)} RETURN count(*) AS n",
+        [{"l": 2, "a": 26, "s": 1}, {"l": 0, "a": 99, "s": 0}, {"l": 9, "a": 22, "s": 2}],
+        BY_COPIES,
+    ),
+    "a_string_column": (
+        _attach_plain,
+        False,
+        "MATCH {class:Msg, as:m, where:(length > :l)}-Wrote->"
+        "{as:p, where:(name <> 'b2' AND age < :a)} RETURN count(*) AS n",
+        [{"l": 1, "a": 35}, {"l": -1, "a": 99}, {"l": 4, "a": 24}],
+        BY_COPIES,
+    ),
+    "a_parameter_free_mask_behind_a_live_edge_filter": (
+        _attach_plain,
+        False,
+        "MATCH {class:Msg, as:m}.outE('Wrote'){where:(w > :x)}.inV()"
+        "{as:p, where:(age < 24)} RETURN count(*) AS n",
+        [{"x": 1}, {"x": -1}, {"x": 3}],
+        BY_COPIES,
+    ),
+    "a_unit_hop_walked_in": (
+        _attach_plain,
+        False,
+        "MATCH {class:Msg, as:m, where:(length > :l)}<-Signed-"
+        "{as:p, where:(age < :a)} RETURN count(*) AS n",
+        [{"l": 3, "a": 24}, {"l": 0, "a": 100}, {"l": 12, "a": 27}],
+        BY_COPIES,
+    ),
+    # where the hop is not one edge a vertex, or the CSR may change under
+    # the plan, the mask is evaluated at the ends as before
+    "a_hull_where_one_vertex_holds_two_edges": (
+        _attach_plain, True, BY_AUTHOR, BY_AUTHOR_PARAMS, AT_THE_ENDS,
+    ),
+    "delta_maintained": (
+        _attach_maintained, False, BY_AUTHOR, BY_AUTHOR_PARAMS, AT_THE_ENDS,
+    ),
+    "mesh_sharded": (
+        _attach_on_a_mesh, False, BY_AUTHOR, BY_AUTHOR_PARAMS, AT_THE_ENDS,
+    ),
+    # a tiered snapshot takes no pushdown at all
+    "tiered": (
+        _attach_tiered, False, BY_AUTHOR, BY_AUTHOR_PARAMS, (False, False),
+    ),
+}
+
+
+class TestAUnitHopReadsItsEndsInEdgeOrder:
+    """A weight pass over a hop whose hull holds one edge a vertex reads
+    its destination's columns from copies the recording made in that
+    hop's edge order (`tpu_engine._FarEnds`): every read a slice, the
+    compare with the parameters in the replay. Observed from the
+    snapshot's CSR (``unit_out`` / ``unit_in``), never declared."""
+
+    def test_creator_1hop_swept_is_the_benchmarks_reference(self, snb_raw):
+        db, ref = snb_raw
+        sql, kind, _params = SCAN_4S_SHAPES["creator_1hop"]
+        sql = sql.replace(" AS n", " AS swept")
+        sweep = [
+            {"minLen": l, "maxAge": a}
+            for l in (200, 700, 1300, 1800)
+            for a in (25, 33, 47, 60)
+        ]
+        ask = lambda p: [
+            tuple(r.values())
+            for r in db.query(sql, p, engine="tpu", strict=True).to_dicts()
+        ]
+        got, lowered = _ends(lambda: ask(sweep[0]))
+        assert got == ref.answer(kind, sweep[0])
+        # the recording and its replay's one trace
+        assert lowered == (2, 0)
+        before = _rerecords()
+        got, lowered = _ends(lambda: [ask(p) for p in sweep[1:]])
+        want = [ref.answer(kind, p) for p in sweep[1:]]
+        assert got == want and len({w[0][0] for w in want}) > 8
+        assert lowered == (0, 0) and _rerecords() == before
+
+    @pytest.mark.parametrize("case", sorted(ENDS_CASES))
+    def test_the_count_is_the_oracles(self, monkeypatch, case):
+        from orientdb_tpu.exec.tpu_engine import drain_warmups
+
+        attach, doubled, sql, plist, moves = ENDS_CASES[case]
+        db = _authors_db(f"ends_{case}", doubled)
+        ask = lambda engine: [
+            db.query(sql, p, engine=engine, strict=(engine == "tpu")).to_dicts()
+            for p in plist
+        ]
+        try:
+            attach(db, monkeypatch)
+            got, lowered = _ends(lambda: ask("tpu"))
+            want = ask("oracle")
+            assert got == want and len({r[0]["n"] for r in want}) == len(plist)
+            assert tuple(n > 0 for n in lowered) == moves
+            if case != "delta_maintained":
+                return
+            # a write to the column the mask reads leaves the topology
+            # clean: the plan replays over the patched column
+            db._authors[5].set("age", 40)
+            db.save(db._authors[5])
+            assert not db.current_snapshot(require_fresh=True)._overlay.topology_dirty
+            before = _rerecords()
+            got, lowered = _ends(lambda: ask("tpu"))
+            assert got == ask("oracle") != want
+            assert lowered == (0, 0) and _rerecords() == before
+        finally:
+            drain_warmups()
+            db.detach_snapshot()
+
+    def test_a_group_of_lanes_reads_one_set_of_copies(self, snb_counts):
+        import orientdb_tpu.obs.timeline as TL
+        from orientdb_tpu.exec.tpu_engine import _GROUP_MIN
+
+        db, snap, _ = snb_counts
+        sql = MSG_COUNT % "ends"
+        plist = [
+            {"minLen": l, "maxAge": a}
+            for l, a in [(FEW, 60), (EVERY, 30), (900, 45), (EVERY, 60), (NONE, 60)]
+        ]
+        assert len(plist) >= _GROUP_MIN
+        ask = lambda: [
+            rs.to_dicts()[0]
+            for rs in db.query_batch([sql] * len(plist), plist, engine="tpu", strict=True)
+        ]
+        want = [{"n": _msg_count(snap, p)} for p in plist]
+        got, lowered = _ends(ask)
+        assert got == want and len({w["n"] for w in want}) == len(plist)
+        assert lowered[0] > 0 and lowered[1] == 0
+        before = _rerecords()
+        TL.recorder.reset()
+        # the group's program, traced here if not before, slices too
+        got, lowered = _ends(ask)
+        assert [r["path"] for r in TL.recorder.records()] == ["group"]
+        assert got == want and lowered[1] == 0 and _rerecords() == before
+
+    def test_a_many_to_many_hop_evaluates_its_mask_at_its_ends(self, snb_counts):
+        from orientdb_tpu.storage import bigshape as B
+
+        db, snap, _ = snb_counts
+        sql = SNB_COUNTS["knows_1hop"][0].replace("AS n", "AS ends")
+        age = snap.v_columns["age"]
+        sweep = [{"minAge": 40, "maxAge": 30}, {"minAge": 25, "maxAge": 60}]
+        numpy = lambda p: B.numpy_1hop_count(
+            snap,
+            (age.values > p["minAge"]) & age.present,
+            (age.values < p["maxAge"]) & age.present,
+        )
+        got, lowered = _ends(
+            lambda: [db.query(sql, p, engine="tpu", strict=True).to_dicts() for p in sweep]
+        )
+        assert got == [[{"ends": numpy(p)}] for p in sweep]
+        assert lowered[0] == 0 and lowered[1] > 0

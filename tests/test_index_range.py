@@ -298,12 +298,15 @@ class TestLowering:
             plan._arg_subset(), plan._dyn_args(params)
         ).jaxpr
         lengths = _gather_index_lengths(jaxpr)
-        assert lengths, "a plan with no gather at all proves nothing"
         assert hull not in lengths, sorted(set(lengths))
         if shape == "friends":
             # a rooted row read: nothing in it is sized by the class
+            assert lengths, "a plan with no gather at all proves nothing"
             assert max(lengths) < hull // 8, sorted(set(lengths))
         else:
+            # the creators' ages are the plan's, in message order: the
+            # COUNT gathers nothing at all
+            assert lengths == [], sorted(set(lengths))
             from orientdb_tpu.exec.tpu_engine import _cap_of
 
             length = snap.v_columns["length"].values[lo:hi]
@@ -438,7 +441,9 @@ class TestACountsRootIsFolded:
         admitted = int((snap.v_columns[column].values[lo:hi] > params[bound]).sum())
         assert admitted > (hi - lo) // 2, "the widest parameters admit most roots"
         lengths = _gather_index_lengths(jaxpr)
-        assert lengths
+        # creator_1hop reads its creators' ages from the plan's copies
+        # (TestAUnitHopReadsItsEndsInEdgeOrder) and gathers nothing
+        assert bool(lengths) == (shape != "creator_1hop"), lengths
         assert not {K.bucket(hi - lo), _cap_of(admitted)} & set(lengths), sorted(
             set(lengths)
         )
@@ -502,8 +507,8 @@ class TestSegmentSumsOverTheHull:
     def test_a_hull_of_one_edge_a_vertex_is_sliced(self, snb):
         """creator_1hop walks hasCreator from the messages, one edge a
         message: its pass sums nothing, the replay reads the edges' values
-        where they lie, and gathers only age and its presence at every
-        edge's creator."""
+        where they lie, and reads age and its presence at every edge's
+        creator from the plan's copies in message order: no gather."""
         db, snap = snb
         p = {"minLen": 900, "maxAge": 30}
         before = _segsums()
@@ -519,8 +524,7 @@ class TestSegmentSumsOverTheHull:
         hull, full, unit = np.subtract(_segsums(), before)
         assert (hull, full) == (0, 0) and unit >= 3
         assert _segment_sum_gathers(jaxpr) == [[]]
-        E = dec.num_edges
-        assert _gather_index_lengths(jaxpr) == [E, E]
+        assert _gather_index_lengths(jaxpr) == []
         assert "cumsum" not in {e.primitive.name for e in _eqns(jaxpr)}
 
     def test_a_class_that_spans_the_universe_counts_as_full(self):
@@ -592,11 +596,11 @@ SHORTEST_PATH_LEN = (
 #: 3000, 3000] at its head: the reorder of an all-true edge mask, v_class
 #: at every creator edge's message, and the two boundary gathers of the
 #: pass's segment sum; creator_1hop's lost its two boundary gathers (one
-#: edge a message: the segment sum is a slice) and keeps age and its
-#: presence at every edge's creator
+#: edge a message: the segment sum is a slice), then age and its presence
+#: at every edge's creator (the plan keeps both in message order)
 KEPT = {
     "config5": (*SCAN_4S["config5"][:2], (1, 1), [17927, 17927, 3000, 3000]),
-    "creator_1hop": (*SCAN_4S["creator_1hop"][:2], (0, 1), [9000] * 2),
+    "creator_1hop": (*SCAN_4S["creator_1hop"][:2], (0, 1), []),
     "knows_2hop": (
         *SCAN_4S["knows_2hop"][:2],
         (0, 2),
@@ -648,7 +652,8 @@ class TestConstantPassesAreThePlans:
         # warm-up and this jaxpr share
         assert tuple(_passes() - before) == tuple(2 * n for n in passes)
         assert _gather_index_lengths(jaxpr) == gathers
-        assert sorted(plan.consts) == (["plan:count_w"] if passes[0] else [])
+        ends = [f"{ENDS}:{n}" for n in ("p", "v")] if shape == "creator_1hop" else []
+        assert sorted(plan.consts) == (["plan:count_w"] if passes[0] else ends)
         if shape != "config5":
             return
         # the kept weights are as long as the pass's hull, the persons,
@@ -688,6 +693,66 @@ class TestConstantPassesAreThePlans:
             for e in _eqns(closed.jaxpr)
             if any(hc in getattr(v.aval, "shape", ()) for v in (*e.invars, *e.outvars))
         ]
+
+
+# -- a unit hop's far end is read from the plan's copies, in edge order ---------
+
+#: the copies' prefix in creator_1hop's plan: its destination, the class
+#: and the direction of the hop
+ENDS = "plan:ends:p:hasCreator:out:age"
+
+
+class TestAUnitHopReadsItsEndsInEdgeOrder:
+    def test_the_copies_are_unbatched_arguments_of_the_group_replay(self, snb):
+        """Lanes differ in their parameters alone: the copies stay one
+        array for every lane, each read a slice, and the lanes' compare
+        is what is batched."""
+        db, snap = snb
+        sql, params = SCAN_4S["creator_1hop"][:2]
+        rows, plan, _reads_counted = _record(
+            db, snap, sql.replace(" AS n", " AS ends"), params
+        )
+        E = snap.edge_classes["hasCreator"].num_edges
+        args = plan._arg_subset()
+        dyn = {k: jnp.stack([v] * 4) for k, v in plan._dyn_args(params).items()}
+        closed = jax.make_jaxpr(jax.vmap(plan._replay, in_axes=(None, 0)))(args, dyn)
+        for name, dtype in (("v", jnp.int32), ("p", jnp.bool_)):
+            kept = closed.jaxpr.invars[sorted(args).index(f"{ENDS}:{name}")]
+            assert kept.aval.shape == (E,) and kept.aval.dtype == dtype
+            readers = [e for e in _eqns(closed.jaxpr) if kept in e.invars]
+            assert readers and all(
+                v.aval.shape == (E,) for e in readers for v in e.outvars
+            ), [e.primitive.name for e in readers]
+        assert _gather_index_lengths(closed.jaxpr) == []
+
+    def test_the_copies_are_device_bytes_while_the_plan_lives(self):
+        """`memory_report` (the benchmark's ``hbm_state_gb``) counts the
+        copies under ``plan_consts`` while their plan lives, and no
+        longer once it is collected."""
+        import gc
+
+        from orientdb_tpu.exec.tpu_engine import drain_warmups
+        from orientdb_tpu.ops.device_graph import device_graph
+        from orientdb_tpu.storage.bigshape import build_snb_shape
+
+        db, snap = build_snb_shape(300, msgs_per_person=3, avg_knows=4, seed=2)
+        try:
+            dg = device_graph(snap)
+            kept = lambda: dg.memory_report()["per_device"]["plan_consts"]
+            before = kept()
+            sql, params = SCAN_4S["creator_1hop"][:2]
+            db.query(sql, params, engine="tpu", strict=True)
+            drain_warmups()
+            E = snap.edge_classes["hasCreator"].num_edges
+            assert E == 900
+            # an int32 and a bool a message
+            assert kept() - before == 5 * E
+            snap._plan_cache.clear()
+            gc.collect()
+            assert kept() == before
+        finally:
+            drain_warmups()
+            db.detach_snapshot()
 
 
 # -- who materialises: a mesh-sharded graph --------------------------------------
