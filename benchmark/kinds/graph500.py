@@ -58,6 +58,11 @@ QUESTION_SCALE = 8
 GEN_CHUNK = 1 << 22
 #: the share of vertices the planted fault leaves without edges
 STALE_SHARE = 0.1
+#: a scale at which ``make_raw`` and the measure take well under a second
+#: on a CPU: the dataset's generator at scale 8 (``QUESTION_SCALE``); the
+#: benchmark's own tests plan every cell of this module at it
+#: (``benchmark/kinds/README.md``)
+SMALL = {"scale": QUESTION_SCALE, "edge_factor": 16, "a": 0.57, "b": 0.19, "c": 0.19, "graph_seed": 2022}
 
 
 @dataclass

@@ -46,6 +46,11 @@ from dataclasses import dataclass
 
 import numpy as np
 
+#: a scale at which ``make_raw`` and the measures take well under a
+#: second on a CPU: the benchmark's own tests plan every cell of this
+#: module at it (``benchmark/kinds/README.md``)
+SMALL = {"persons": 200, "avg_knows": 6, "msgs_per_person": 12, "supernodes": 2, "supernode_degree": 40}
+
 
 @dataclass
 class Raw:

@@ -105,6 +105,10 @@ PAIR_SOURCES = 256
 PAIR_TARGETS = 128
 #: the share of persons the planted fault leaves without friendships
 STALE_SHARE = 0.1
+#: a scale at which ``make_raw`` and ``pair_distance`` take well under a
+#: second on a CPU: the benchmark's own tests plan every cell of this
+#: module at it (``benchmark/kinds/README.md``); no messages, as IC13 reads none
+SMALL = {"persons": 200, "avg_knows": 6, "msgs_per_person": 0, "supernodes": 2, "supernode_degree": 40}
 
 
 def stale(raw: Raw, seed: int, share: float = STALE_SHARE) -> Raw:

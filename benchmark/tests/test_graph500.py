@@ -33,8 +33,8 @@ def kinds(cfg):
 
 
 @pytest.fixture(scope="module")
-def small(cfg):
-    return {**cfg["scale"], "scale": 8}
+def small(kinds):
+    return kinds.SMALL
 
 
 @pytest.fixture(scope="module")
@@ -135,8 +135,8 @@ def test_the_planted_fault_moves_counts(kinds, raw):
     assert moved == len(range(0, raw.V, 7))
 
 
-# -- what test_benchmark_harness.py and test_snb_paths.py hold for SNB-shaped scales
-# (tests/test_benchmark_suite.STALE_ASSUMPTIONS), held here for this cell ----------
+# -- the configuration and the cell (what every cell is held to, planned at the
+# module's SMALL, is in test_each_cell.py) ------------------------------------------
 
 
 def test_the_configuration_is_the_dataset_whole(cfg):
@@ -148,6 +148,8 @@ def test_the_configuration_is_the_dataset_whole(cfg):
     assert cfg["architecture"] is None and cfg["kinds"] == "graph500"
     want = {"scale": 22, "edge_factor": 16, "a": 0.57, "b": 0.19, "c": 0.19}
     assert {k: cfg["scale"][k] for k in want} == want
+    # the module's small scale is the dataset's own generator at scale 8
+    assert run.load_kinds(cfg).SMALL == {**cfg["scale"], "scale": 8}
     assert cfg["published"]["dataset"] == "graph500-22"
     (cell,) = [w for w in bench["workloads"] if w["config"] == cfg["name"]]
     assert (cell["name"], cell["traffic"], cell["chips"]) == ("g500_s22_bfs_1s", "bfs_1s", 1)
@@ -155,25 +157,6 @@ def test_the_configuration_is_the_dataset_whole(cfg):
     (shape,) = mix["shapes"]
     assert (mix["sessions"], mix["think_ms"], mix["pool_size"]) == (1, 0, 64)
     assert shape["sql"] == run.load_kinds(cfg).STATEMENT and shape["ordered"] is False
-
-
-def test_every_reference_kind_of_the_cell_has_a_byte_count(kinds, raw):
-    for shape in traffic.load_json("traffic", "bfs_1s")["shapes"]:
-        assert kinds.least_bytes(shape["reference"], raw) > 0
-    with pytest.raises(KeyError):
-        kinds.least_bytes("no_such_kind", raw)
-
-
-def test_two_seeds_give_the_cell_the_same_shape_order_and_other_roots(kinds, small):
-    mix = traffic.load_json("traffic", "bfs_1s")
-    plans = []
-    for seed in (7, 2**31 + 12345):
-        ref = kinds.Reference(kinds.make_raw(small, seed))
-        plans.append(traffic.build_plan(mix, kinds.Measures(ref), seed, 64))
-    a, b = plans
-    assert a["block"] == b["block"] == [0] and a["offsets"] == b["offsets"] == [0]
-    assert [s["sql"] for s in a["shapes"]] == [s["sql"] for s in b["shapes"]]
-    assert a["shapes"][0]["pool"]["rows"] != b["shapes"][0]["pool"]["rows"]
 
 
 # -- through run.run_cell, from the real files and a small configuration ---------

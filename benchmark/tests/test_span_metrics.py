@@ -133,6 +133,26 @@ def test_the_new_entries_are_in_benchmark_json_without_a_workloads_list():
         assert {entries[n]["moves"] for n in NAMES} <= e2e
 
 
+def test_the_span_entries_are_found_by_name_and_list_no_workloads():
+    """What the test above holds, with the six entries found by name
+    wherever they stand in the list (``tests/test_benchmark_suite.py``
+    expects the test above to fail since entries came after them)."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    entries = {m["name"]: m for m in bench["per_layer"]}
+    assert set(NAMES) <= set(entries)
+    other_layers = {m["layer"] for m in bench["per_layer"] if m["name"] not in NAMES}
+    for name in NAMES:
+        m = entries[name]
+        assert "workloads" not in m and m["source"] == "program_span"
+        assert m["better"] == "lower"
+    assert {entries[n]["layer"] for n in NAMES[2:]} <= other_layers
+    # every cell reports the metric they move
+    for w in bench["workloads"]:
+        e2e = {m["name"] for m in run.metrics_for(bench, "end_to_end", w["name"])}
+        assert {entries[n]["moves"] for n in NAMES} <= e2e
+
+
 # -- one driven run: a served window moves the counters together ------------------------------
 
 TINY = {"persons": 200, "avg_knows": 6, "msgs_per_person": 12, "supernodes": 2, "supernode_degree": 40}

@@ -16,7 +16,7 @@ so that one template's run time is a narrow distribution.
 
 A parameter of a shape is one of
 
-- ``{"const": 30}``
+- ``{"const": 30}`` or ``{"const": "Jan"}``
 - ``{"int": [lo, hi]}`` uniform whole numbers, both ends included;
   with ``"lead": v`` the pool's first tuple carries ``v`` (the value of
   the largest answer), see ``draw_pool``
@@ -26,8 +26,13 @@ A parameter of a shape is one of
   ``Measures``. A measure returns one value per candidate, and the
   candidate's index is the parameter (a person); or it returns
   ``(values, candidates)``, one row of parameter values per candidate
-  (a pair of persons, curated by their distance), and the parameter's
-  key is the row's names joined by commas (``"person1Id,person2Id"``).
+  (a pair of persons, curated by their distance; a person and a first
+  name), and the parameter's key is the row's names joined by commas
+  (``"person1Id,person2Id"``). The candidates are a 2-D integer array,
+  a sequence of tuples, or a sequence of numpy columns, one per name.
+
+Every parameter keeps its type from draw to statement to reference: an
+integer column gives Python ``int``, a string column Python ``str``.
 
 No (statement, parameters) pair is drawn twice while the domain lasts.
 
@@ -98,12 +103,61 @@ def band_members(values: np.ndarray, band) -> np.ndarray:
     return np.flatnonzero((values >= lo) & (values <= hi))
 
 
+def _value_kind(v) -> str:
+    if isinstance(v, str):
+        return "str"
+    if isinstance(v, (int, np.integer)) and not isinstance(v, (bool, np.bool_)):
+        return "int"
+    return type(v).__name__
+
+
+def _typed_column(values, where: str) -> np.ndarray:
+    """One parameter column as an array whose ``tolist()`` gives Python
+    ``int`` (an integer column) or Python ``str`` (a string column), and
+    nothing else: a column keeps its own type beside the others."""
+    if not (isinstance(values, np.ndarray) and values.dtype.kind in "iuU"):
+        # numpy would read a mix of numbers and strings as strings
+        kinds = {_value_kind(v) for v in values}
+        if kinds not in ({"int"}, {"str"}):
+            raise TypeError(f"{where}: a parameter column holds integers or strings: {kinds}")
+        values = np.asarray(values, np.int64 if kinds == {"int"} else str)
+    if values.ndim != 1:
+        raise ValueError(f"{where}: a parameter column is one-dimensional")
+    return values
+
+
+def _candidate_columns(candidates, names: List[str], n: int, where: str) -> List[np.ndarray]:
+    """A tuple measure's candidates as one typed column per name, ``n``
+    values each. Three forms: a 2-D integer array (a row per candidate),
+    a sequence of numpy arrays (a column per name), or a sequence of
+    tuples (a row per candidate, integers and strings side by side)."""
+    if isinstance(candidates, np.ndarray):
+        if candidates.ndim != 2 or candidates.dtype.kind not in "iu":
+            raise TypeError(
+                f"{where}: an array of candidates is 2-D and of integers; "
+                "give strings as columns or as tuples"
+            )
+        cols = list(candidates.T)
+    elif all(isinstance(c, np.ndarray) for c in candidates):
+        cols = list(candidates)
+    else:
+        if any(len(row) != len(names) for row in candidates):
+            raise ValueError(f"{where}: a candidate holds one value per name {names}")
+        cols = list(zip(*candidates))
+    if len(cols) != len(names) or any(len(c) != n for c in cols):
+        raise ValueError(f"{where}: one column per name {names}, one value per candidate ({n})")
+    return [_typed_column(c, where) for c in cols]
+
+
 def draw_pool(shape: dict, measures, seed: int, want: int) -> Dict:
     """Up to ``want`` distinct parameter tuples for one shape, as
     ``{"names": [...], "rows": [[...], ...]}``. ``measures`` is the kinds
     module's ``Measures`` over the reference. Seeded by ``seed`` and the
     shape's name, so that adding a shape to a mix moves no other shape's
     parameters.
+
+    Each parameter keeps its type: a value in ``rows`` is a Python
+    ``int`` or ``str``, as its column holds it (``_candidate_columns``).
 
     The first tuple is the one warm-up records the shape's plan with, and
     a plan keeps the buffer sizes of the answer it was recorded on
@@ -118,7 +172,11 @@ def draw_pool(shape: dict, measures, seed: int, want: int) -> Dict:
     cols = []
     for name, spec in shape["params"].items():
         if "const" in spec:
-            cols.append(np.full(want, int(spec["const"]), np.int64))
+            value = spec["const"]
+            if isinstance(value, str):
+                cols.append(np.full(want, value))
+            else:
+                cols.append(np.full(want, int(value), np.int64))
         elif "int" in spec:
             lo, hi = spec["int"]
             col = rng.integers(int(lo), int(hi) + 1, want)
@@ -144,14 +202,14 @@ def draw_pool(shape: dict, measures, seed: int, want: int) -> Dict:
             if candidates is None:
                 cols.append(col)
             else:
-                cols.extend(np.asarray(candidates)[col].T)
+                where = f"{shape['name']}.{name}"
+                columns = _candidate_columns(candidates, name.split(","), len(values), where)
+                cols.extend(c[col] for c in columns)
         else:
             raise ValueError(f"{shape['name']}.{name}: unknown draw {spec}")
-    rows = np.stack(cols, axis=1) if cols else np.zeros((want, 0), np.int64)
     # keep the first occurrence of every tuple, in drawn order
-    _, first = np.unique(rows, axis=0, return_index=True)
-    rows = rows[np.sort(first)]
-    return {"names": names, "rows": rows.tolist()}
+    rows = dict.fromkeys(zip(*(c.tolist() for c in cols)) if cols else [()] * want)
+    return {"names": names, "rows": [list(r) for r in rows]}
 
 
 def build_plan(mix: dict, measures, seed: int, pool_size: int) -> dict:
